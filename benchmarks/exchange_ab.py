@@ -8,7 +8,7 @@ to
 
 The collective itself is identical, so this measures the map-side shard
 program only — the part the restructuring changes — as plain jit on one
-device (the real mesh's per-shard work). Run on TPU for BENCH_NOTES.
+device (the real mesh's per-shard work).
 
 Usage: python benchmarks/exchange_ab.py [rows] [n_keys] [n_shards]
 """

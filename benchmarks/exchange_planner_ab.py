@@ -19,8 +19,8 @@ commutative, so reduction order cannot show — and a unique-key sort):
 duplicate-key ties keep exchange ARRIVAL order, which differs between
 collective programs by design (documented since the ring exchange).
 
-Runs wherever jax lands (CPU proxy mesh locally; the tpu_jobs queue runs
-it on the real chip). One JSON line.
+Runs wherever jax lands (CPU proxy mesh locally, the real mesh on a TPU
+machine). One JSON line.
 Usage: python benchmarks/exchange_planner_ab.py [rows]
 """
 
@@ -64,8 +64,7 @@ def main():
 
     if mesh_lib.default_mesh().size == 1:
         # A 1-device mesh takes the n_shards==1 passthrough — there is
-        # no exchange to plan. Emit the one JSON line (never crash a
-        # rare TPU window) and bail.
+        # no exchange to plan. Emit the one JSON line and bail.
         result["note"] = "single-device mesh: no exchange to plan"
         result["accept"] = {"skipped_single_device": True}
         ctx.stop()

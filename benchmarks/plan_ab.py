@@ -16,8 +16,8 @@ Two measurements per plan:
      dominates and therefore whether Pallas kernel work should target
      the sort (verdict item 4).
 
-Runs wherever jax lands (CPU mesh locally; the tpu_jobs queue runs it on
-the real chip). One JSON line. Usage: python benchmarks/plan_ab.py [rows]
+Runs wherever jax lands (CPU mesh locally, the real chip on a TPU
+machine). One JSON line. Usage: python benchmarks/plan_ab.py [rows]
 """
 
 import json

@@ -36,7 +36,7 @@ def _rules(result):
 
 
 # ---------------------------------------------------------------- VG001
-def test_vg001_fires_on_raw_jax_spellings(tmp_path):
+def test_vg001_fires_on_raw_shard_map_spellings(tmp_path):
     res = _lint(tmp_path, "vega_tpu/tpu/newop.py", """\
         import jax
         from jax import lax
@@ -44,11 +44,11 @@ def test_vg001_fires_on_raw_jax_spellings(tmp_path):
 
         def f(fn, mesh):
             g = jax.shard_map(fn, mesh=mesh)
-            with jax.enable_x64():
+            with jax.enable_x64():  # fine: no wrapper to go through
                 pass
             return lax.platform_dependent(tpu=fn, default=fn)
         """, select=["VG001"])
-    assert _rules(res).count("VG001") >= 4  # import + 3 uses
+    assert _rules(res).count("VG001") == 2  # the import + jax.shard_map
     assert all(f.path.endswith("newop.py") for f in res.findings)
 
 

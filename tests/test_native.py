@@ -1,6 +1,8 @@
 """Native C++ runtime tests: hash parity, codec round-trips, and
 host-shuffle fast-path equivalence with the pure-Python path."""
 
+import os
+
 import numpy as np
 import pytest
 
@@ -212,3 +214,51 @@ def test_int64_overflow_rejects_to_exact_python(ctx):
     gm = dict(ctx.parallelize([(1, 2**62), (1, 2**62)], 2)
               .reduce_by_key(lambda a, b: a + b, 1).collect())
     assert gm == {1: 2**63} and isinstance(gm[1], int)
+
+
+def test_loader_rebuilds_a_stale_shared_object(tmp_path, monkeypatch):
+    """A binary absent, or older than native/vega_native.cpp (copied from
+    another machine, left by an older checkout), is rebuilt, not imported.
+    Runs on a copy of native/ so the library other processes have open is
+    never touched."""
+    import shutil
+
+    real = native._native_dir()
+    (tmp_path / "vega_tpu").mkdir()
+    shutil.copytree(real, tmp_path / "native")
+    monkeypatch.setattr(native, "_native_dir",
+                        lambda: str(tmp_path / "native"))
+    so = native._built_path()
+    assert so.startswith(str(tmp_path))
+    src = str(tmp_path / "native" / "vega_native.cpp")
+    assert native._stale()  # absent
+    assert native._try_build() and os.path.isfile(so)
+    assert not native._stale()
+    old = os.path.getmtime(src) - 60
+    os.utime(so, (old, old))
+    assert native._stale()  # older than its source: make rebuilds it
+
+
+def test_loader_builds_before_importing_when_stale(monkeypatch):
+    calls = []
+    monkeypatch.setattr(native, "_native", None)
+    monkeypatch.setattr(native, "_load_attempted", False)
+    monkeypatch.setattr(native, "_stale", lambda: True)
+    monkeypatch.setattr(native, "_try_build",
+                        lambda: calls.append("build") or True)
+    assert native.get() is not None
+    assert calls == ["build"]
+
+
+def test_failed_build_is_a_warning_with_the_compilers_words(monkeypatch,
+                                                            caplog):
+    import subprocess
+
+    def boom(cmd, **kw):
+        raise subprocess.CalledProcessError(2, cmd, stderr="error: boom")
+
+    monkeypatch.setattr(subprocess, "run", boom)
+    with caplog.at_level("WARNING", logger="vega_tpu"):
+        assert native._try_build() is False
+    assert any(r.levelname == "WARNING" and "error: boom" in r.getMessage()
+               for r in caplog.records)

@@ -240,11 +240,9 @@ class Configuration:
     # when the combine shrinks data a lot (high key duplication) and the
     # sort dominates. "auto" (round-5 default) resolves per backend from
     # the measured evidence: sort_partition on CPU (won the A/B at both
-    # 2M and 5M bench shapes, 10-20% faster warm end-to-end —
-    # docs/BENCH_NOTES.md round 5), fused_sort on TPU until the queued
-    # on-chip A/B (benchmarks/tpu_jobs/02_plan_ab.sh) decides: the only
-    # hardware number ever captured used fused_sort, and the headline
-    # bench must not gamble on a plan with no on-chip measurement.
+    # 2M and 5M bench shapes in round 5, an XLA:CPU finding), fused_sort
+    # on TPU until an on-chip A/B (benchmarks/plan_ab.py; ROADMAP
+    # Speed 3) decides: no plan has a chip measurement yet (PERF.md).
     dense_rbk_plan: str = "auto"
     # Key-sort implementation inside exchange programs: "xla" = lax.sort
     # comparator network; "packed" = (key, perm) packed into one 63-bit
@@ -256,9 +254,8 @@ class Configuration:
     # kernels on TPU) for int32/float32/wide-int64 keys — other dtypes
     # keep lax.sort. "auto" (round-5 default) resolves per backend:
     # packed on CPU (measured 3.8x on the dominant reduce sort at the 5M
-    # bench shape — docs/BENCH_NOTES.md round 5), xla on TPU until the
-    # queued on-chip A/B (benchmarks/tpu_jobs/03_radix_ab.sh, which
-    # also measures packed) decides.
+    # bench shape in round 5, an XLA:CPU finding), xla on TPU until an
+    # on-chip A/B (ROADMAP Speed 3) decides.
     dense_sort_impl: str = "auto"
     # --- elastic serving plane (scheduler/elastic.py; distributed mode) ---
     # Master switch for the autoscaler control loop: the driver samples
@@ -310,11 +307,9 @@ class Configuration:
     # Speculative dense-key table plan for warm named reduces (scatter
     # table + psum + hash-mask compact; dense_rdd.py). "auto" (default)
     # activates it on CPU only — measured 3-4x on the bench reduce there
-    # — and keeps TPU on the standard exchange until the queued on-chip
-    # A/B (benchmarks/tpu_jobs/02_plan_ab.sh table leg) decides: the
-    # only hardware number ever captured ran the exchange path, and the
-    # headline bench must not gamble on an unmeasured plan. "on"/"off"
-    # force it per run (the A/B job sets "on").
+    # — and keeps TPU on the standard exchange until an on-chip A/B
+    # (benchmarks/plan_ab.py table leg; ROADMAP Speed 3) decides.
+    # "on"/"off" force it per run (the A/B sets "on").
     dense_table_plan: str = "auto"
     # --- device-tier string columns (tpu/dict_encoding.py) ---
     # Master switch for dictionary-encoded string columns on the device

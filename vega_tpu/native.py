@@ -1,8 +1,9 @@
 """Loader for the native C++ shuffle runtime (native/vega_native.cpp).
 
 Builds on demand with the in-tree Makefile if the shared object is missing
-(g++ is part of the toolchain); every caller has a pure-Python fallback, so
-absence of a compiler degrades performance, not correctness.
+or older than its source (g++ is part of the toolchain); every caller has a
+pure-Python fallback, so absence of a compiler degrades performance, not
+correctness — and says so at WARNING with the compiler's output.
 
 Named ops shared with the device tier's segment fast paths.
 """
@@ -104,19 +105,46 @@ _native = None
 _load_attempted = False
 
 
+def _native_dir() -> str:
+    return os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "native")
+
+
+def _built_path() -> str:
+    """Where native/Makefile puts the shared object (its OUT)."""
+    import sysconfig
+
+    return os.path.join(os.path.dirname(_native_dir()), "vega_tpu",
+                        "_vega_native" + sysconfig.get_config_var(
+                            "EXT_SUFFIX"))
+
+
+def _stale() -> bool:
+    """True when the shared object is absent or older than its source —
+    a binary copied from another machine, or left behind by an older
+    checkout, must not be what this one imports."""
+    src = os.path.join(_native_dir(), "vega_native.cpp")
+    if not os.path.isfile(src):
+        return False  # no source to rebuild from: take what is there
+    built = _built_path()
+    return (not os.path.isfile(built)
+            or os.path.getmtime(built) < os.path.getmtime(src))
+
+
 def _try_build() -> bool:
-    makefile_dir = os.path.join(os.path.dirname(os.path.dirname(__file__)),
-                                "native")
+    makefile_dir = _native_dir()
     if not os.path.isfile(os.path.join(makefile_dir, "Makefile")):
         return False
     try:
         subprocess.run(
             ["make", "-C", makefile_dir],
-            check=True, capture_output=True, timeout=120,
+            check=True, capture_output=True, text=True, timeout=120,
         )
         return True
     except (subprocess.SubprocessError, OSError) as e:
-        log.info("native build failed (pure-Python fallback in use): %s", e)
+        said = getattr(e, "stderr", None) or getattr(e, "stdout", None) or ""
+        log.warning("native build failed (pure-Python fallback in use): "
+                    "%s\n%s", e, said.strip())
         return False
 
 
@@ -136,17 +164,13 @@ def get():
         # perf loss the push plan's pre-merge accounting surfaced).
         # Callers racing the import now block on _lock and get the module.
         try:
-            try:
-                from vega_tpu import _vega_native  # type: ignore[attr-defined]
+            if not _stale() or _try_build():
+                try:
+                    from vega_tpu import _vega_native  # type: ignore[attr-defined]
 
-                _native = _vega_native
-            except ImportError:
-                if _try_build():
-                    try:
-                        from vega_tpu import _vega_native  # type: ignore
-                        _native = _vega_native
-                    except ImportError:
-                        _native = None
+                    _native = _vega_native
+                except ImportError:
+                    _native = None
         finally:
             # finally: a CORRUPT .so whose module init raises something
             # other than ImportError must still conclude the attempt —

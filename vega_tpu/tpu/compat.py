@@ -1,54 +1,19 @@
-"""jax API compatibility for the dense tier.
+"""The dense tier's one shard_map spelling.
 
-The device tier targets the current jax surface (`jax.shard_map` with
-`check_vma`, `jax.enable_x64`); older jaxlibs (< 0.5) expose the same
-functionality under `jax.experimental` with different keyword names
-(`check_rep`). These wrappers resolve the right entry point once at import
-so the SPMD programs compile on either — the container's baked-in
-toolchain decides which branch runs, never a pip install.
+Every device program is `jax.jit(compat.shard_map(fn, ...))` with
+`check_vma=False`: the exchange and sort programs call Pallas kernels whose
+`out_shape` carries no varying-manual-axes annotation, and with the checker
+on jax refuses them at trace time ("`vma` on `jax.ShapeDtypeStruct` must not
+be `None`"). Pinning the flag here keeps a new program from being written
+with the checker on; vegalint VG001 points every other `jax.shard_map`
+spelling at this wrapper.
 """
 
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):
 
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-
-else:  # jax < 0.5: experimental module, check_rep keyword
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, mesh=None, in_specs=None, out_specs=None):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-
-
-if hasattr(jax, "enable_x64"):
-    enable_x64 = jax.enable_x64
-else:  # jax < 0.5
-    from jax.experimental import enable_x64  # noqa: F401
-
-
-def jax_export(f, platforms=None):
-    """`jax.export.export` lives at `jax.export` only on current jax; the
-    module itself (same API) imports as `from jax import export` on 0.4.x
-    too — the attribute is just not re-exported there."""
-    from jax import export as export_mod
-
-    return export_mod.export(f, platforms=platforms)
-
-
-def platform_dependent(*operands, tpu, default):
-    """`jax.lax.platform_dependent` on jax < 0.5 lowers EVERY branch for
-    the current platform — a Pallas TPU kernel branch then fails to lower
-    on the CPU backend. On old jax pick the branch at trace time from the
-    initialized backend instead (safe: these run inside materialization,
-    long after backend init — never on an import path)."""
-    if hasattr(jax, "shard_map"):  # current jax: true lowering-time select
-        return jax.lax.platform_dependent(*operands, tpu=tpu, default=default)
-    if jax.default_backend() == "tpu":
-        return tpu(*operands)
-    return default(*operands)
+def shard_map(f, mesh=None, in_specs=None, out_specs=None):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -379,7 +379,7 @@ def packed_sort_perm(words, count: jax.Array,
 
     XLA:CPU's multi-operand comparator sort is 4-8x slower than its
     single-operand sort at bench shapes (5M rows: sort_key_val 2.01s,
-    3-operand 2.69s, packed 0.53s — docs/BENCH_NOTES.md round 5), so
+    3-operand 2.69s, packed 0.53s on this sandbox's CPU in round 5), so
     packing the key and the permutation into one 63-bit word turns the
     sort+permutation problem into the fast single-column case. The
     position in the low 31 bits is also the stability tie-break. Words
@@ -397,9 +397,7 @@ def packed_sort_perm(words, count: jax.Array,
         raise ValueError("packed_sort_perm: capacity must fit 31 bits")
     mask = valid_mask(capacity, count)
     order = None
-    from vega_tpu.tpu import compat
-
-    with compat.enable_x64():
+    with jax.enable_x64():
         idx0 = lax.iota(jnp.int64, capacity)
         for wi, w in enumerate(words):  # LSD -> MSD: one stable pass/word
             if descending:

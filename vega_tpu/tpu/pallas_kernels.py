@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from vega_tpu.tpu import compat
-
 _LANES = 128
 _SUBLANES = 8
 _TILE = _LANES * _SUBLANES
@@ -236,7 +234,7 @@ def bucket_hist(bucket: jax.Array, n_bins: int) -> jax.Array:
     unrolls a per-bin step, same bound as the rank kernel's gate."""
     if n_bins > 65:
         return jnp.bincount(bucket, length=n_bins).astype(jnp.int32)
-    return compat.platform_dependent(
+    return jax.lax.platform_dependent(
         bucket,
         tpu=lambda b: digit_hist_pallas(b, n_bins),
         default=lambda b: jnp.bincount(b, length=n_bins).astype(jnp.int32),
@@ -248,7 +246,7 @@ def radix_hist(digits: jax.Array, n_bins: int = 256) -> jax.Array:
     the Pallas streaming kernel on TPU, bincount elsewhere. n_bins = 2^bits
     (8-bit digits -> fewer passes, 4-bit -> 16x less per-tile unroll; the
     hardware A/B decides)."""
-    return compat.platform_dependent(
+    return jax.lax.platform_dependent(
         digits,
         tpu=lambda d: digit_hist_pallas(d, n_bins),
         default=lambda d: jnp.bincount(d, length=n_bins).astype(jnp.int32),
@@ -259,7 +257,7 @@ def radix_pos(digits: jax.Array, starts: jax.Array,
               n_bins: int = 256) -> jax.Array:
     """Stable counting-partition positions for one radix pass,
     platform-selected at lowering (Pallas rank kernel on TPU)."""
-    return compat.platform_dependent(
+    return jax.lax.platform_dependent(
         digits, starts,
         tpu=lambda d, s: partition_pos_pallas(d, n_bins, s),
         default=lambda d, s: _xla_onehot_pos(d, s, n_bins),
@@ -279,7 +277,7 @@ def partition_pos(bucket: jax.Array, n_bins: int, starts: jax.Array,
     if n_bins > 65 or bucket.dtype != jnp.int32:
         return None
     fallback = _xla_argsort_pos if prefer_low_memory else _xla_onehot_pos
-    return compat.platform_dependent(
+    return jax.lax.platform_dependent(
         bucket, starts,
         tpu=lambda b, s: partition_pos_pallas(b, n_bins, s),
         default=lambda b, s: fallback(b, s, n_bins),
