@@ -38,7 +38,10 @@ from vega_tpu.lint.sync_witness import assert_role, named_lock
 def _profile_trace(log_dir: str):
     import jax
 
+    from vega_tpu.tpu import spans
+
     jax.profiler.start_trace(log_dir)
+    spans.new_session()
     try:
         yield
     finally:
@@ -306,6 +309,16 @@ class Context:
 
             with ctx.profiler("/tmp/trace"):
                 rdd.reduce_by_key(op="add").collect()
+            ctx.metrics_summary()["dense_spans"]["session"]
+
+        While the session runs, the dense tier names its host work in the
+        profile, on the clock of the device's own operations, and tallies
+        it by the same names (vega_tpu/tpu/spans.py): `vega:launch <kind>`
+        (dispatch of one shard program), `vega:fetch` (a blocking
+        device->host round trip), `vega:put` (host->device), `vega:decode`
+        (slicing and decoding a fetched block), `vega:pivot` (columns to
+        Python rows) and `vega:fingerprint` (pickling a closure for a
+        program-cache key). Off, they cost one flag check each.
         """
         return _profile_trace(log_dir)
 
@@ -454,9 +467,19 @@ class Context:
         return max(2, self._backend.parallelism)
 
     def metrics_summary(self) -> dict:
+        """The event bus's counters, and under "dense_spans" what the
+        dense tier's host side did: "session", the spans tallied under the
+        newest profiler session (see `profiler`), and "programs", the shard
+        programs minted by kind with the host seconds of their first
+        calls."""
         if not self.bus.flush():
             log.warning("event bus flush timed out; metrics may lag")
-        return self.metrics.summary()
+        from vega_tpu.tpu import spans  # imports no jax
+
+        summary = self.metrics.summary()
+        summary["dense_spans"] = {"session": spans.session(),
+                                  "programs": spans.programs()}
+        return summary
 
     def fleet_status(self) -> dict:
         """One view of the serving plane: fleet membership/occupancy

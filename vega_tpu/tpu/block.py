@@ -21,6 +21,7 @@ import numpy as np
 
 from vega_tpu.lint.sync_witness import named_lock
 from vega_tpu.tpu import mesh as mesh_lib
+from vega_tpu.tpu import spans
 
 _host_cache_lock = named_lock("tpu.block._host_cache_lock")  # serializes Block.host_cols fills
 
@@ -189,15 +190,19 @@ class Block:
         else:
             host_cols = {name: np.asarray(c) for name, c in
                          mesh_lib.host_get(dict(self.cols)).items()}
-        out: Dict[str, List[np.ndarray]] = {n: [] for n in self.cols}
-        for s in range(self.n_shards):
-            lo = s * self.capacity
-            c = int(counts[s])
-            for name in self.cols:
-                out[name].append(host_cols[name][lo:lo + c])
-        gathered = {n: np.concatenate(parts) if parts else np.empty((0,))
-                    for n, parts in out.items()}
-        return _decode_dict_cols(_decode_key_cols(gathered), self.dicts)
+        with spans.span("decode") as sp:
+            out: Dict[str, List[np.ndarray]] = {n: [] for n in self.cols}
+            for s in range(self.n_shards):
+                lo = s * self.capacity
+                c = int(counts[s])
+                for name in self.cols:
+                    out[name].append(host_cols[name][lo:lo + c])
+            gathered = {n: np.concatenate(parts) if parts else np.empty((0,))
+                        for n, parts in out.items()}
+            decoded = _decode_dict_cols(_decode_key_cols(gathered),
+                                        self.dicts)
+            sp.nbytes = sum(c.nbytes for c in decoded.values())
+            return decoded
 
     def shard_rows(self, shard: int) -> Dict[str, np.ndarray]:
         counts = self.counts_np
@@ -223,17 +228,22 @@ class Block:
             # another inside device_get, 0% CPU). One lock here costs
             # nothing — the path is host-bound anyway — and removes the
             # interleaving entirely.
-            with _host_cache_lock, mesh_lib.device_door():
+            with _host_cache_lock, mesh_lib.device_door(), \
+                    spans.span("fetch") as sp:
                 # vegalint: ignore[VG003] — serializing this device_get IS the fix: concurrent slice+device_get from two task threads deadlocks old XLA:CPU on 1 core (CLAUDE.md)
                 sliced = jax.device_get(
                     {name: col[lo:lo + c] for name, col in self.cols.items()}
                 )  # one transfer for all columns
-        return _decode_dict_cols(
-            _decode_key_cols(
-                {name: np.asarray(col) for name, col in sliced.items()}
-            ),
-            self.dicts,
-        )
+                sp.nbytes = sum(a.nbytes for a in sliced.values())
+        with spans.span("decode") as sp:
+            decoded = _decode_dict_cols(
+                _decode_key_cols(
+                    {name: np.asarray(col) for name, col in sliced.items()}
+                ),
+                self.dicts,
+            )
+            sp.nbytes = sum(c.nbytes for c in decoded.values())
+            return decoded
 
 
 def _round_capacity(c: int) -> int:

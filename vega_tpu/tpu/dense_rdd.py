@@ -31,6 +31,8 @@ from __future__ import annotations
 
 import logging
 import math
+import threading
+import time
 import weakref
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -48,6 +50,7 @@ from vega_tpu.tpu import dict_encoding
 from vega_tpu.tpu import kernels
 from vega_tpu.tpu import pallas_kernels
 from vega_tpu.tpu import mesh as mesh_lib
+from vega_tpu.tpu import spans
 from vega_tpu.tpu.block import KEY, KEY_LO, VALUE, Block
 
 log = logging.getLogger("vega_tpu")
@@ -95,36 +98,55 @@ def _shard_program(mesh, fn, in_specs, out_specs):
 # portability story (SURVEY.md §2.1): here the *fingerprint* of the traced
 # function is the identity, and XLA's own jit cache handles shape changes.
 _PROGRAM_CACHE: dict = {}
-# Programs minted (built, not served from the cache) since process start.
-# The frame planner's whole-stage-fusion acceptance test reads this to
-# prove a select->filter->with_column chain compiled to ONE program.
-_PROGRAM_MINTS: int = 0
 
 
 def program_mints() -> int:
-    """Count of shard programs BUILT so far (cache hits excluded)."""
-    return _PROGRAM_MINTS
+    """Count of shard programs BUILT so far (cache hits excluded). The
+    frame planner's whole-stage-fusion acceptance test reads this to
+    prove a select->filter->with_column chain compiled to ONE program;
+    spans.programs() has the same count by kind."""
+    return spans.program_mints()
 
 
 def _fp(obj) -> str:
     """Stable fingerprint of a callable/closure for program-cache keys."""
     import hashlib
 
-    try:
-        import cloudpickle
+    with spans.span("fingerprint"):
+        try:
+            import cloudpickle
 
-        return hashlib.sha1(cloudpickle.dumps(obj)).hexdigest()[:16]
-    except Exception:  # noqa: BLE001 — unpicklable: identity-cached only
-        return f"id:{id(obj)}"
+            return hashlib.sha1(cloudpickle.dumps(obj)).hexdigest()[:16]
+        except Exception:  # noqa: BLE001 — unpicklable: identity-cached only
+            return f"id:{id(obj)}"
+
+
+def _spanned_program(kind: str, prog):
+    """`prog` under a `launch <kind>` span: the host's dispatch of one shard
+    program. The first call (trace, lower, compile or persistent-cache
+    load, dispatch) is timed into spans.programs() whether or not a
+    profiler session runs: it happens once a program."""
+    first = threading.Lock()  # taken by the first call, never released
+
+    def launch(*args):
+        if not first.locked() and first.acquire(blocking=False):
+            t0 = time.perf_counter()
+            try:
+                return launch(*args)  # `first` is spent: the plain path
+            finally:
+                spans.program_first_call(kind, time.perf_counter() - t0)
+        with spans.span("launch", kind):
+            return prog(*args)
+
+    return launch
 
 
 def _cached_program(key, build):
-    global _PROGRAM_MINTS
     prog = _PROGRAM_CACHE.get(key)
     if prog is None:
-        prog = build()
+        prog = _spanned_program(key[0], build())
         _PROGRAM_CACHE[key] = prog
-        _PROGRAM_MINTS += 1
+        spans.program_minted(key[0])
     return prog
 
 
@@ -1449,11 +1471,12 @@ class DenseRDD(RDD):
     def collect(self) -> list:
         cols = self.block().to_numpy()
         names = list(cols)
-        if names == [VALUE]:
-            return cols[VALUE].tolist()
-        if set(names) == {KEY, VALUE}:
-            return list(zip(cols[KEY].tolist(), cols[VALUE].tolist()))
-        return list(zip(*[cols[n].tolist() for n in names]))
+        with spans.span("pivot"):
+            if names == [VALUE]:
+                return cols[VALUE].tolist()
+            if set(names) == {KEY, VALUE}:
+                return list(zip(cols[KEY].tolist(), cols[VALUE].tolist()))
+            return list(zip(*[cols[n].tolist() for n in names]))
 
     def collect_arrays(self) -> dict:
         """Columnar collect — no per-row Python objects."""
@@ -1697,20 +1720,22 @@ class DenseRDD(RDD):
         )
         best, n_valid = prog(blk.cols[VALUE], blk.counts)
         best, n_valid = mesh_lib.host_get((best, n_valid))  # one RTT
-        best = np.asarray(best).reshape(blk.n_shards, k)
-        n_valid = np.asarray(n_valid)
-        candidates = np.concatenate(
-            [best[s, : n_valid[s]] for s in range(blk.n_shards)]
-        ) if blk.n_shards else np.empty((0,))
-        candidates = np.sort(candidates)
-        if largest:
-            candidates = candidates[::-1]
         vdict = self._dicts().get(VALUE)
-        if vdict is not None:
-            # Rank codes ordered == strings ordered; decode the survivors
-            # at this collect boundary.
-            candidates = vdict[candidates.astype(np.int64)]
-        return candidates[:n].tolist()
+        with spans.span("pivot"):
+            best = np.asarray(best).reshape(blk.n_shards, k)
+            n_valid = np.asarray(n_valid)
+            candidates = np.concatenate(
+                [best[s, : n_valid[s]] for s in range(blk.n_shards)]
+            ) if blk.n_shards else np.empty((0,))
+            candidates = np.sort(candidates)
+            if largest:
+                candidates = candidates[::-1]
+            if vdict is not None:
+                # Rank codes ordered == strings ordered; decode the
+                # survivors at this collect boundary.
+                candidates = vdict[candidates.astype(np.int64)]
+            out = candidates[:n].tolist()
+            return out
 
     def _device_topk_rows(self, n: int, largest: bool) -> list:
         """First/last n ROWS in natural element order — the order of the
@@ -1782,46 +1807,47 @@ class DenseRDD(RDD):
         )
         outs = prog(blk.counts, *[blk.cols[nm] for nm in names])
         outs = mesh_lib.host_get(outs)  # one RTT
-        n_valid = np.asarray(outs[0]).reshape(-1)
-        per_col = [np.asarray(o).reshape(blk.n_shards, k)
-                   for o in outs[1:]]
-        keep = []
-        for s in range(blk.n_shards):
-            c = int(n_valid[s])
-            if c:
-                keep.append([col[s, :c] for col in per_col])
-        if not keep:
-            return []
-        merged = {nm: np.concatenate([rows[i] for rows in keep])
-                  for i, nm in enumerate(names)}
-        if largest and not use_radix:
-            # un-flip (the lax.sort path returned flipped sort operands;
-            # the radix path gathers original values)
-            for nm in names:
-                col = merged[nm]
-                merged[nm] = -col if np.issubdtype(col.dtype, np.floating) \
-                    else ~col
-        merged = block_lib._decode_key_cols(merged)  # schema order kept
-        order_cols = list(merged.values())
-        # np.lexsort: last key is primary -> reverse; stable like the
-        # device sort. Dictionary-encoded columns order by their RANK
-        # codes here — identical to string order — and decode below.
-        order = np.lexsort([c if not largest else
-                            (-c if np.issubdtype(c.dtype, np.floating)
-                             else ~c)
-                            for c in reversed(order_cols)])
-        out_names = [nm for nm in names if not block_lib.is_lo(nm)]
         dicts = self._dicts()
-        for nm in out_names:
-            if nm in dicts:  # collect boundary: codes -> strings
-                merged[nm] = dicts[nm][merged[nm]]
-        rows = [tuple(merged[nm][i] for nm in out_names)
-                for i in order[:n]]
-        if out_names == [KEY, VALUE]:
-            return [(k_.item(), v_.item()) for k_, v_ in rows]
-        if len(out_names) == 1:  # keyless single column: scalars, not
-            return [row[0].item() for row in rows]  # 1-tuples
-        return [tuple(x.item() for x in row) for row in rows]
+        with spans.span("pivot"):  # the driver's merge, to rows
+            n_valid = np.asarray(outs[0]).reshape(-1)
+            per_col = [np.asarray(o).reshape(blk.n_shards, k)
+                       for o in outs[1:]]
+            keep = []
+            for s in range(blk.n_shards):
+                c = int(n_valid[s])
+                if c:
+                    keep.append([col[s, :c] for col in per_col])
+            if not keep:
+                return []
+            merged = {nm: np.concatenate([rows[i] for rows in keep])
+                      for i, nm in enumerate(names)}
+            if largest and not use_radix:
+                # un-flip (the lax.sort path returned flipped sort operands;
+                # the radix path gathers original values)
+                for nm in names:
+                    col = merged[nm]
+                    merged[nm] = -col if np.issubdtype(col.dtype, np.floating) \
+                        else ~col
+            merged = block_lib._decode_key_cols(merged)  # schema order kept
+            order_cols = list(merged.values())
+            # np.lexsort: last key is primary -> reverse; stable like the
+            # device sort. Dictionary-encoded columns order by their RANK
+            # codes here — identical to string order — and decode below.
+            order = np.lexsort([c if not largest else
+                                (-c if np.issubdtype(c.dtype, np.floating)
+                                 else ~c)
+                                for c in reversed(order_cols)])
+            out_names = [nm for nm in names if not block_lib.is_lo(nm)]
+            for nm in out_names:
+                if nm in dicts:  # collect boundary: codes -> strings
+                    merged[nm] = dicts[nm][merged[nm]]
+            rows = [tuple(merged[nm][i] for nm in out_names)
+                    for i in order[:n]]
+            if out_names == [KEY, VALUE]:
+                return [(k_.item(), v_.item()) for k_, v_ in rows]
+            if len(out_names) == 1:  # keyless single column: scalars, not
+                return [row[0].item() for row in rows]  # 1-tuples
+            return [tuple(x.item() for x in row) for row in rows]
 
     def stats(self) -> dict:
         """count/mean/stdev/min/max in one device pass (host analogue:
@@ -3502,8 +3528,6 @@ class _ExchangeRDD(DenseRDD):
         pending entry for _attach_pending/_settle_pending to verify at
         the next genuine host read. `validate`/`on_success` ride the
         entry (join product checks / node bookkeeping)."""
-        import time as _time
-
         from vega_tpu.scheduler import events as ev
 
         n = self.mesh.size
@@ -3513,7 +3537,7 @@ class _ExchangeRDD(DenseRDD):
         hint_store = ctx.__dict__.setdefault("_dense_capacity_hints", {})
         hinted = hint_key is not None and hint_key in hint_store
         bus = getattr(ctx, "bus", None)
-        t_start = _time.time()
+        t_start = time.perf_counter()
         if ((fixed_caps is not None or hinted)
                 and not ctx.__dict__.get("_dense_no_defer")):
             slot, out_cap = (fixed_caps if fixed_caps is not None
@@ -3535,7 +3559,7 @@ class _ExchangeRDD(DenseRDD):
                     # may still be executing — this timing is dispatch-only.
                     bus.post(ev.StageCompleted(
                         stage_id=-self.rdd_id,
-                        duration_s=_time.time() - t_start,
+                        duration_s=time.perf_counter() - t_start,
                         speculative=True,
                     ))
             self._last_attempts = 1
@@ -3629,7 +3653,7 @@ class _ExchangeRDD(DenseRDD):
         finally:
             if bus is not None:
                 bus.post(ev.StageCompleted(
-                    stage_id=-self.rdd_id, duration_s=_time.time() - t_start,
+                    stage_id=-self.rdd_id, duration_s=time.perf_counter() - t_start,
                 ))
 
 
@@ -4363,7 +4387,8 @@ class _GroupByKeyRDD(_ExchangeRDD):
         # keys are sorted within each shard; shards don't overlap (hash
         # partitioned), so grouping is a single pass per shard run.
         cols = self.block().to_numpy()
-        return list(_sorted_runs(cols[KEY], cols[VALUE]))
+        with spans.span("pivot"):
+            return list(_sorted_runs(cols[KEY], cols[VALUE]))
 
     def compute(self, split: Split, task_context=None):
         rows = self.block().shard_rows(split.index)
@@ -4631,7 +4656,9 @@ class _JoinRDD(_ExchangeRDD):
         )
 
     def collect(self) -> list:
-        return list(self._rows(self.block().to_numpy()))
+        cols = self.block().to_numpy()
+        with spans.span("pivot"):
+            return list(self._rows(cols))
 
     def count(self) -> int:
         return self.block().num_rows
@@ -4952,8 +4979,12 @@ class _DenseCoGroupRDD(RDD):
         # is two vectorized searchsorted passes; Python cost is per GROUP
         # (the unavoidable host-facing (k, ([lvs], [rvs])) assembly), never
         # per row.
-        lrows = self.left_grouped.block().shard_rows(split.index)
-        rrows = self.right_grouped.block().shard_rows(split.index)
+        yield from self._group_rows(
+            self.left_grouped.block().shard_rows(split.index),
+            self.right_grouped.block().shard_rows(split.index))
+
+    @staticmethod
+    def _group_rows(lrows: dict, rrows: dict):
         lk, loff, lv = _grouped_columnar(lrows[KEY], lrows[VALUE])
         rk, roff, rv = _grouped_columnar(rrows[KEY], rrows[VALUE])
 
@@ -4972,7 +5003,10 @@ class _DenseCoGroupRDD(RDD):
     def collect(self) -> list:
         out = []
         for s in range(self.num_partitions):
-            out.extend(self.compute(Split(s)))
+            lrows = self.left_grouped.block().shard_rows(s)
+            rrows = self.right_grouped.block().shard_rows(s)
+            with spans.span("pivot"):
+                out.extend(self._group_rows(lrows, rrows))
         return out
 
     def collect_grouped(self):

@@ -18,6 +18,7 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from vega_tpu.lint.sync_witness import named_lock
+from vega_tpu.tpu import spans
 
 SHARD_AXIS = "shards"
 
@@ -261,29 +262,36 @@ def host_get(tree):
     if not any(isinstance(x, jax.Array) for x in leaves):
         # vegalint: ignore[VG016] — numpy passthrough: no device touched
         return jax.device_get(tree)  # numpy passthrough, backend-free
-    if jax.process_count() > 1:
-        by_mesh: dict = {}
-        for i, x in enumerate(leaves):
-            if isinstance(x, jax.Array) and not x.is_fully_addressable:
-                by_mesh.setdefault(x.sharding.mesh, []).append(i)
-        for m, idx in by_mesh.items():
-            prog = _gather_jit_cache.get(m)
-            if prog is None:
-                prog = jax.jit(_identity_outputs,
-                               out_shardings=NamedSharding(m, P()))
-                _gather_jit_cache[m] = prog
-            with device_door():
-                gathered = prog(*[leaves[i] for i in idx])
-            for i, g in zip(idx, gathered):
-                leaves[i] = g  # fully replicated: locally readable
-    # The dense tier's stage-launch transfer itself: DenseRDD.splits
-    # materializes on the per-job drive thread BY DESIGN (one SPMD
-    # program per stage), so the round trip is that job's own work,
-    # bounded by device compute — it cannot park other tenants'
-    # scheduling.
-    with device_door():
-        # vegalint: ignore[VG016] — stage-launch transfer on the job's own drive thread (see above)
-        return jax.tree_util.tree_unflatten(treedef, jax.device_get(leaves))
+    # One blocking device->host round trip: the wait for the device's own
+    # work, then the copy.
+    with spans.span("fetch") as sp:
+        if sp.on:
+            sp.nbytes = sum(x.nbytes for x in leaves
+                            if isinstance(x, jax.Array))
+        if jax.process_count() > 1:
+            by_mesh: dict = {}
+            for i, x in enumerate(leaves):
+                if isinstance(x, jax.Array) and not x.is_fully_addressable:
+                    by_mesh.setdefault(x.sharding.mesh, []).append(i)
+            for m, idx in by_mesh.items():
+                prog = _gather_jit_cache.get(m)
+                if prog is None:
+                    prog = jax.jit(_identity_outputs,
+                                   out_shardings=NamedSharding(m, P()))
+                    _gather_jit_cache[m] = prog
+                with device_door():
+                    gathered = prog(*[leaves[i] for i in idx])
+                for i, g in zip(idx, gathered):
+                    leaves[i] = g  # fully replicated: locally readable
+        # The dense tier's stage-launch transfer itself: DenseRDD.splits
+        # materializes on the per-job drive thread BY DESIGN (one SPMD
+        # program per stage), so the round trip is that job's own work,
+        # bounded by device compute — it cannot park other tenants'
+        # scheduling.
+        with device_door():
+            # vegalint: ignore[VG016] — stage-launch transfer on the job's own drive thread (see above)
+            fetched = jax.device_get(leaves)
+        return jax.tree_util.tree_unflatten(treedef, fetched)
 
 
 def host_put(value, spec: NamedSharding) -> jax.Array:
@@ -291,8 +299,11 @@ def host_put(value, spec: NamedSharding) -> jax.Array:
     holds identically (the SPMD driver model guarantees it): each process
     materializes only its addressable shards via make_array_from_callback;
     single-process falls through to plain device_put."""
-    if jax.process_count() == 1:
-        return jax.device_put(value, spec)
-    arr = np.asarray(value)
-    return jax.make_array_from_callback(arr.shape, spec,
-                                        lambda idx: arr[idx])
+    with spans.span("put") as sp:
+        if sp.on:
+            sp.nbytes = int(getattr(value, "nbytes", 0))
+        if jax.process_count() == 1:
+            return jax.device_put(value, spec)
+        arr = np.asarray(value)
+        return jax.make_array_from_callback(arr.shape, spec,
+                                            lambda idx: arr[idx])
