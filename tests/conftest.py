@@ -114,3 +114,35 @@ def ctx():
     context = v.Context("local", num_workers=4)
     yield context
     context.stop()
+
+
+@pytest.fixture()
+def session(tmp_path):
+    """start() opens a real jax profiler session (host tracer only: the
+    Python tracer would slow every call), stop() ends it; whatever is left
+    open is stopped at teardown."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    running = []
+
+    class Session:
+        def start(self):
+            jax.profiler.start_trace(str(tmp_path / f"t{len(running)}"),
+                                     profiler_options=opts)
+            running.append(True)
+
+        def stop(self):
+            running.pop()
+            jax.profiler.stop_trace()
+
+        def __enter__(self):
+            self.start()
+
+        def __exit__(self, *exc):
+            self.stop()
+
+    yield Session()
+    if running:
+        jax.profiler.stop_trace()

@@ -1841,13 +1841,11 @@ class DenseRDD(RDD):
             for nm in out_names:
                 if nm in dicts:  # collect boundary: codes -> strings
                     merged[nm] = dicts[nm][merged[nm]]
-            rows = [tuple(merged[nm][i] for nm in out_names)
-                    for i in order[:n]]
-            if out_names == [KEY, VALUE]:
-                return [(k_.item(), v_.item()) for k_, v_ in rows]
-            if len(out_names) == 1:  # keyless single column: scalars, not
-                return [row[0].item() for row in rows]  # 1-tuples
-            return [tuple(x.item() for x in row) for row in rows]
+            first = order[:n]
+            picked = [merged[nm][first].tolist() for nm in out_names]
+            if len(out_names) == 1:  # keyless single column: scalars,
+                return picked[0]     # not 1-tuples
+            return list(zip(*picked))
 
     def stats(self) -> dict:
         """count/mean/stdev/min/max in one device pass (host analogue:
@@ -4647,13 +4645,13 @@ class _JoinRDD(_ExchangeRDD):
     @staticmethod
     def _rows(cols: dict):
         # to_numpy/shard_rows decode wide (lv, lv.lo) pairs to int64
-        # before this zip, so lv/rv are single columns again.
-        return (
-            (k, (lv, rv))
-            for k, lv, rv in zip(
-                cols[KEY].tolist(), cols["lv"].tolist(), cols["rv"].tolist()
-            )
-        )
+        # before this zip, so lv/rv are single columns again. Nested zips
+        # and no generator expression: a Python frame a row gives every
+        # collection CPython 3.12+ schedules an eval breaker to run at
+        # (19,118 in a 6.7M-row collect(), half its time: PERF.md PR 29);
+        # list() over C-level iterators pays one, after the last row.
+        return zip(cols[KEY].tolist(),
+                   zip(cols["lv"].tolist(), cols["rv"].tolist()))
 
     def collect(self) -> list:
         cols = self.block().to_numpy()
