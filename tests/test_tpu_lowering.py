@@ -86,6 +86,22 @@ def test_lowering_rbk_sort_partition():
     _export_sharded(prog, 3, 4, _pair_args())
 
 
+def test_lowering_float_add_long_run():
+    """A shard that can hold a run over LONG_RUN_ROWS: the float add's
+    `lax.cond` and its blocked pairwise branch lower under shard_map."""
+    cap = 2 * kernels.LONG_RUN_ROWS
+
+    def prog(counts, keys, vals):
+        out, n = kernels.segment_reduce_named(
+            {KEY: keys, VALUE: vals}, counts[0], KEY, "add")
+        return out[KEY], out[VALUE], n.reshape(1)
+
+    m = _export_sharded(prog, 3, 3, (
+        jnp.full((N,), cap - 5, jnp.int32), jnp.zeros(N * cap, jnp.int32),
+        jnp.ones(N * cap, jnp.float32)))
+    assert "stablehlo.case" in m or "stablehlo.if" in m
+
+
 def test_lowering_ring_exchange():
     from vega_tpu.tpu.ring import ring_exchange
 

@@ -3301,6 +3301,7 @@ def _settle_pending(ctx) -> None:
     try:
         for e in failed:
             rdd = e["rdd"]
+            spans.count("exchange_repair")
             fresh = rdd.block()  # blocking path: sized, fetched, verified
             old = e["block"]
             old.cols = fresh.cols
@@ -3536,6 +3537,7 @@ class _ExchangeRDD(DenseRDD):
         hinted = hint_key is not None and hint_key in hint_store
         bus = getattr(ctx, "bus", None)
         t_start = time.perf_counter()
+        spans.count("exchange")
         if ((fixed_caps is not None or hinted)
                 and not ctx.__dict__.get("_dense_no_defer")):
             slot, out_cap = (fixed_caps if fixed_caps is not None
@@ -3546,6 +3548,7 @@ class _ExchangeRDD(DenseRDD):
                 ))
             try:
                 prog, args = build_program(slot, out_cap)
+                spans.count("exchange_round")
                 # Launch under the CPU dispatch door: a concurrent
                 # device_get on another task thread (shard_rows /
                 # host_get) deadlocks old XLA:CPU (mesh.device_door).
@@ -3611,6 +3614,7 @@ class _ExchangeRDD(DenseRDD):
                                                              attempt)
                     attempt += 1
                 prog, args = build_program(slot, out_cap)
+                spans.count("exchange_round")
                 with mesh_lib.device_door():  # see the deferred launch
                     *outs, overflow = prog(*args)
                 self._last_attempts = round_i + 1

@@ -26,6 +26,12 @@ slicing, concatenation, the int64 and dictionary decodes of a fetched block),
 `pivot` (columns -> Python row objects), `fingerprint` (pickling a closure
 for a program-cache key).
 
+Counters: `count(name)` adds one to a count-only entry of the same tally
+(seconds and bytes stay 0; no annotation, nothing to nest). `exchange` (one a
+call of `_run_exchange`), `exchange_round` (one a launch of its program: an
+overflow launches again with grown capacities) and `exchange_repair` (one a
+block `_settle_pending` rebuilt after a speculative launch overflowed).
+
 Programs are rare (2-3 a run), so `programs()` is always recorded:
 {kind: {"mints", "first_call_s"}}, the second the host seconds of each minted
 program's first call (trace, lower, compile or persistent-cache load,
@@ -64,6 +70,14 @@ def _add(acc: dict, seconds: float, nbytes: int) -> None:
 
 def _zero() -> dict:
     return {"count": 0, "seconds": 0.0, "bytes": 0}
+
+
+def _entry(name: str) -> dict:
+    """The session tally's entry `name`, made if absent; under `_lock`."""
+    acc = _session.get(name)
+    if acc is None:
+        acc = _session[name] = dict(_zero(), by_kind={})
+    return acc
 
 
 class span:
@@ -109,14 +123,27 @@ class span:
         self._ann.__exit__(*exc)
         _depth.n -= 1
         with _lock:
-            acc = _session.get(self.name)
-            if acc is None:
-                acc = _session[self.name] = dict(_zero(), by_kind={})
+            acc = _entry(self.name)
             _add(acc, seconds, self.nbytes)
             if self.kind:
                 _add(acc["by_kind"].setdefault(self.kind, _zero()),
                      seconds, self.nbytes)
         return False
+
+
+def count(name: str) -> None:
+    """Add one to the count-only entry `name` of the session tally; off, one
+    `_enabled()` check and nothing recorded."""
+    global _live
+    if not _enabled():
+        if _live:
+            _live = False
+        return
+    with _lock:
+        if not _live:
+            _session.clear()
+            _live = True
+        _entry(name)["count"] += 1
 
 
 def new_session() -> None:
