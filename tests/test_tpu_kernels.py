@@ -495,3 +495,272 @@ def test_packed_sort_perm_matches_argsort():
     got_all_ghost = np.asarray(kernels.packed_sort_perm(
         [u], jnp.int32(0), False))
     assert got_all_ghost.tolist() == list(range(n))
+
+
+# ---------------------------------------------------------------------------
+# merge join: ranks from one merge, slot owners from a scatter and a scan
+# ---------------------------------------------------------------------------
+
+I32_MAX = 2**31 - 1
+
+
+def _padded(keys, capacity, fill=I32_MAX):
+    out = np.full(capacity, fill, dtype=np.asarray(keys).dtype)
+    out[:len(keys)] = keys
+    return out
+
+
+def _rank_case(name):
+    """(left keys, lcap, right keys, rcap, wide) — valid rows only, sorted;
+    the rest of each capacity is padding, as merge_join_expand hands it."""
+    rng = np.random.RandomState(31)
+    if name == "duplicates_both_sides":
+        return (np.sort(rng.randint(0, 40, 200)), 256,
+                np.sort(rng.randint(0, 40, 180)), 256, False)
+    if name == "two_word_duplicates":
+        pool = rng.randint(-2**62, 2**62, size=30, dtype=np.int64)
+        # same high word, different low words too
+        pool = np.concatenate([pool, pool[:10] + 3, pool[:10] - 2**31])
+        return (np.sort(pool[rng.randint(0, 50, 220)]), 256,
+                np.sort(pool[rng.randint(0, 50, 150)]), 256, True)
+    if name == "empty_left":
+        return (np.zeros(0, np.int64), 128,
+                np.sort(rng.randint(0, 9, 100)), 128, False)
+    if name == "empty_right":
+        return (np.sort(rng.randint(0, 9, 100)), 128,
+                np.zeros(0, np.int64), 128, False)
+    if name == "two_word_empty_right":
+        return (np.sort(rng.randint(-2**62, 2**62, 90, dtype=np.int64)), 128,
+                np.zeros(0, np.int64), 128, True)
+    if name == "counts_below_capacity":
+        return (np.sort(rng.randint(-50, 50, 17)), 512,
+                np.sort(rng.randint(-50, 50, 5)), 512, False)
+    if name == "valid_key_equals_sentinel":
+        return (np.array([3, 3, I32_MAX, I32_MAX]), 128,
+                np.array([1, 3, I32_MAX, I32_MAX, I32_MAX]), 128, False)
+    if name == "two_word_key_equals_sentinel":
+        top = np.iinfo(np.int64).max
+        return (np.array([5, top, top], np.int64), 128,
+                np.array([5, 5, top], np.int64), 128, True)
+    if name == "lcap_below_rcap":
+        return (np.sort(rng.randint(0, 300, 100)), 128,
+                np.sort(rng.randint(0, 300, 900)), 1024, False)
+    if name == "lcap_above_rcap":
+        return (np.sort(rng.randint(0, 300, 2000)), 2048,
+                np.sort(rng.randint(0, 300, 100)), 128, False)
+    raise KeyError(name)
+
+
+_RANK_CASES = [
+    "duplicates_both_sides", "two_word_duplicates", "empty_left",
+    "empty_right", "two_word_empty_right", "counts_below_capacity",
+    "valid_key_equals_sentinel", "two_word_key_equals_sentinel",
+    "lcap_below_rcap", "lcap_above_rcap",
+]
+
+
+def _encode_side(keys, capacity, wide):
+    """Device columns of one side, padded with the largest key."""
+    from vega_tpu.tpu import block as block_lib
+
+    if not wide:
+        return (jnp.asarray(_padded(keys.astype(np.int32), capacity)),)
+    hi, lo = block_lib.encode_i64(np.asarray(keys, np.int64))
+    return (jnp.asarray(_padded(hi, capacity)),
+            jnp.asarray(_padded(lo, capacity)))
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+@pytest.mark.parametrize("case", _RANK_CASES)
+def test_merge_ranks_match_numpy_searchsorted(case, side):
+    """One merge of the two sorted columns gives what a binary search per
+    left row gave: np.searchsorted over the valid right keys, clipped by
+    the right count as merge_join_expand clips it."""
+    lk, lcap, rk, rcap, wide = _rank_case(case)
+    lw = _encode_side(lk, lcap, wide)
+    rw = _encode_side(rk, rcap, wide)
+    lo, hi = kernels.merge_ranks(lw, rw)
+    got = np.minimum(np.asarray(lo if side == "left" else hi), len(rk))
+    assert got.shape == (lcap,) and got.dtype == np.int32
+    want = np.searchsorted(rk, lk, side=side)
+    np.testing.assert_array_equal(got[:len(lk)], want)
+    # padding rows rank as the largest key does
+    top = np.iinfo(np.int64).max if wide else I32_MAX
+    assert (got[len(lk):] == np.searchsorted(rk, top, side=side)
+            if side == "left" else got[len(lk):] == len(rk)).all()
+
+
+def test_merge_ranks_float_keys_zero_signs():
+    """-0.0 and 0.0 are one key to lax.sort and to jnp.searchsorted."""
+    lk = np.array([-1.5, -0.0, 0.0, 2.0], np.float32)
+    rk = np.array([-0.0, 0.0, 0.0, 2.0, 7.0], np.float32)
+    lo, hi = kernels.merge_ranks(
+        [jnp.asarray(_padded(lk, 128, np.inf))],
+        [jnp.asarray(_padded(rk, 128, np.inf))])
+    np.testing.assert_array_equal(np.asarray(lo)[:4], [0, 0, 0, 3])
+    np.testing.assert_array_equal(np.asarray(hi)[:4], [0, 3, 3, 4])
+
+
+_RAGGED_CASES = {
+    "zeros_leading": ([0, 0, 3, 1, 2], 128),
+    "zeros_interior": ([2, 0, 0, 1, 0, 4], 128),
+    "zeros_trailing": ([1, 5, 0, 0, 0], 128),
+    "all_zeros": ([0, 0, 0, 0], 128),
+    "single_row": ([7], 128),
+    "total_fills_capacity": ([64, 0, 64], 128),
+    "total_above_capacity": ([100, 0, 100, 3], 128),
+    "more_rows_than_slots": ([1, 0] * 300, 128),
+}
+
+
+@pytest.mark.parametrize("case", list(_RAGGED_CASES))
+def test_ragged_expand_matches_numpy_repeat(case):
+    counts, out_capacity = _RAGGED_CASES[case]
+    counts = np.asarray(counts, np.int32)
+    owner, offset, total = kernels.ragged_expand(
+        jnp.asarray(counts), out_capacity)
+    owner, offset = np.asarray(owner), np.asarray(offset)
+    assert int(total) == counts.sum()  # exact, even past the capacity
+    want_owner = np.repeat(np.arange(len(counts)), counts)
+    starts = np.cumsum(counts) - counts
+    want_offset = np.arange(len(want_owner)) - starts[want_owner]
+    n = min(len(want_owner), out_capacity)
+    np.testing.assert_array_equal(owner[:n], want_owner[:n])
+    np.testing.assert_array_equal(offset[:n], want_offset[:n])
+    # slots that are not output still name a row that exists
+    assert owner.shape == (out_capacity,)
+    assert ((owner >= 0) & (owner < len(counts))).all()
+
+
+@pytest.mark.parametrize("counts", [
+    [2**30, 2**30, 2**30],          # the running sum wraps at row 2
+    [2**31 - 1, 1, 0, 5],           # the total itself wraps
+], ids=["starts_wrap", "total_wraps"])
+def test_ragged_expand_wrapped_sum_saturates(counts):
+    owner, _, total = kernels.ragged_expand(
+        jnp.asarray(np.asarray(counts, np.int32)), 128)
+    assert int(total) == I32_MAX
+    owner = np.asarray(owner)
+    assert ((owner >= 0) & (owner < len(counts))).all()
+
+
+def _numpy_join(lk, lv, rk, rv, outer, fill):
+    """dup x dup reference: left rows in stable key order, each against its
+    right matches in stable key order."""
+    lorder = np.argsort(lk, kind="stable")
+    rorder = np.argsort(rk, kind="stable")
+    rks = rk[rorder]
+    rows = []
+    for i in lorder:
+        a = np.searchsorted(rks, lk[i], "left")
+        b = np.searchsorted(rks, lk[i], "right")
+        for j in rorder[a:b]:
+            rows.append((lk[i], lv[i], rv[j]))
+        if outer and a == b:
+            rows.append((lk[i], lv[i], fill))
+    return rows
+
+
+def _join_case(name):
+    """(left keys, lcap, right keys, rcap, out_capacity, wide)."""
+    rng = np.random.RandomState(131)
+    if name == "duplicates_both_sides":
+        return (rng.randint(0, 30, 60), 128, rng.randint(0, 40, 50), 128,
+                512, False)
+    if name == "two_word_keys":
+        pool = rng.randint(-2**62, 2**62, size=25, dtype=np.int64)
+        pool = np.concatenate([pool, pool[:8] + 1])
+        return (pool[rng.randint(0, 33, 70)], 128,
+                pool[rng.randint(0, 30, 40)], 128, 1024, True)
+    if name == "product_overflows_capacity":
+        return (rng.randint(0, 4, 100), 128, rng.randint(0, 4, 90), 128,
+                256, False)
+    if name == "two_word_overflow":
+        pool = rng.randint(-2**62, 2**62, size=3, dtype=np.int64)
+        return (pool[rng.randint(0, 3, 64)], 128,
+                pool[rng.randint(0, 3, 64)], 128, 128, True)
+    if name == "empty_left":
+        return (np.zeros(0, np.int64), 128, rng.randint(0, 9, 50), 128,
+                128, False)
+    if name == "empty_right":
+        return (rng.randint(0, 9, 50), 128, np.zeros(0, np.int64), 128,
+                128, False)
+    if name == "valid_key_equals_sentinel":
+        return (np.array([I32_MAX, 7, I32_MAX, 2]), 128,
+                np.array([I32_MAX, 7, 7, I32_MAX, 1]), 128, 128, False)
+    if name == "lcap_below_rcap":
+        return (rng.randint(0, 200, 90), 128, rng.randint(0, 200, 700),
+                1024, 2048, False)
+    if name == "lcap_above_rcap":
+        return (rng.randint(0, 60, 900), 1024, rng.randint(0, 60, 100), 128,
+                2048, False)
+    raise KeyError(name)
+
+
+_JOIN_CASES = [
+    "duplicates_both_sides", "two_word_keys", "product_overflows_capacity",
+    "two_word_overflow", "empty_left", "empty_right",
+    "valid_key_equals_sentinel", "lcap_below_rcap", "lcap_above_rcap",
+]
+
+
+@pytest.mark.parametrize("outer", [False, True], ids=["inner", "outer"])
+@pytest.mark.parametrize("case", _JOIN_CASES)
+def test_merge_join_expand_matches_numpy_product(case, outer):
+    """Rows, their order (left sort order), count and the exact total —
+    also where the product is larger than out_capacity, which is what the
+    driver sizes its one retry from."""
+    from vega_tpu.tpu import block as block_lib
+
+    lk, lcap, rk, rcap, out_capacity, wide = _join_case(case)
+    rng = np.random.RandomState(7)
+    lv = rng.randint(0, 1000, len(lk)).astype(np.float32)
+    rv = rng.randint(0, 1000, len(rk)).astype(np.float32)
+
+    def side(keys, vals, capacity):
+        # rows past the count hold garbage, as a block's padding may
+        if wide:
+            hi, lo = block_lib.encode_i64(np.asarray(keys, np.int64))
+            cols = {"k": _padded(hi, capacity, -5),
+                    "k.lo": _padded(lo, capacity, -5)}
+        else:
+            cols = {"k": _padded(keys.astype(np.int32), capacity, -5)}
+        cols["v"] = _padded(vals, capacity, -1.0)
+        return {n: jnp.asarray(c) for n, c in cols.items()}
+
+    out, count, total = kernels.merge_join_expand(
+        side(lk, lv, lcap), jnp.int32(len(lk)),
+        side(rk, rv, rcap), jnp.int32(len(rk)),
+        "k", out_capacity, outer=outer, fill_value=-7.0,
+        lo_name="k.lo" if wide else None)
+    want = _numpy_join(lk, lv, rk, rv, outer, np.float32(-7.0))
+    assert int(total) == len(want)
+    assert int(count) == min(len(want), out_capacity)
+    n = int(count)
+    keys = np.asarray(out["k"])[:n]
+    if wide:
+        keys = block_lib.decode_i64(keys, np.asarray(out["k.lo"])[:n])
+    got = list(zip(keys.tolist(), np.asarray(out["v"])[:n].tolist(),
+                   np.asarray(out["r_v"])[:n].tolist()))
+    assert got == [(int(k), float(a), float(b)) for k, a, b in want[:n]]
+
+
+def test_merge_join_expand_presorted_sides_agree():
+    """left_sorted / right_sorted skip the sorts and nothing else."""
+    rng = np.random.RandomState(5)
+    lk = np.sort(rng.randint(0, 25, 80)).astype(np.int32)
+    rk = np.sort(rng.randint(0, 25, 70)).astype(np.int32)
+    left = {"k": jnp.asarray(_padded(lk, 128, 0)),
+            "v": jnp.asarray(_padded(np.arange(80, dtype=np.float32), 128))}
+    right = {"k": jnp.asarray(_padded(rk, 128, 0)),
+             "v": jnp.asarray(_padded(np.arange(70, dtype=np.float32), 128))}
+    a = kernels.merge_join_expand(left, jnp.int32(80), right, jnp.int32(70),
+                                  "k", 512)
+    b = kernels.merge_join_expand(left, jnp.int32(80), right, jnp.int32(70),
+                                  "k", 512, left_sorted=True,
+                                  right_sorted=True)
+    assert int(a[1]) == int(b[1]) and int(a[2]) == int(b[2])
+    n = int(a[1])
+    for name in a[0]:
+        np.testing.assert_array_equal(np.asarray(a[0][name])[:n],
+                                      np.asarray(b[0][name])[:n])

@@ -152,6 +152,46 @@ def test_lowering_merge_join_expand():
     _export_sharded(prog, 3, 5, _pair_args())
 
 
+def _gathers(jaxpr, times=1):
+    """Gather operations a jaxpr runs: one inside a scan counts once a
+    step (jnp.searchsorted is a scan of log2(n) steps, a gather each)."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            n += times
+        inner = times * eqn.params.get("length", 1) \
+            if eqn.primitive.name == "scan" else times
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _gathers(sub, inner)
+    return n
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["one_word", "two_word"])
+@pytest.mark.parametrize("outer", [False, True], ids=["inner", "outer"])
+def test_merge_join_expand_gathers_do_not_grow_with_capacity(wide, outer):
+    """No chip needed: positions in sorted data come from a merge and
+    scans, so the join holds as many gathers at 2^16 rows as at 2^10. A
+    binary search per row holds log2(capacity) more each time."""
+    def count(cap):
+        def prog(n, keys, lo, vals):
+            cols = {KEY: keys, VALUE: vals}
+            if wide:
+                cols[KEY_LO] = lo
+            return kernels.merge_join_expand(
+                cols, n, dict(cols), n, KEY, cap, outer=outer,
+                lo_name=KEY_LO if wide else None)
+
+        col = jax.ShapeDtypeStruct((cap,), jnp.int32)
+        closed = jax.make_jaxpr(prog)(
+            jax.ShapeDtypeStruct((), jnp.int32), col, col, col)
+        return _gathers(closed.jaxpr)
+
+    small, large = count(2**10), count(2**16)
+    assert small == large
+    # the sorts' permutations and the output columns: a handful, not 3 x 16
+    assert large <= 16
+
+
 def test_lowering_range_sort():
     def prog(bounds, counts, keys, vals):
         count = counts[0]

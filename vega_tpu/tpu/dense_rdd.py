@@ -2317,9 +2317,10 @@ class _FlatMapRaggedRDD(_NarrowRDD):
     (key, value) and n_valid is how many lead entries are real.
 
     The XLA-compatible general flat_map (reference rdd.rs:207-214 is fully
-    dynamic): per-row counts -> exclusive prefix sums -> each output slot
-    finds its owning row by binary search (same ragged-expansion pattern as
-    merge_join_expand). Output capacity is the static bound
+    dynamic): per-row counts -> exclusive prefix sums -> each row marks its
+    first output slot and a running max carries the mark over the row's run
+    (kernels.ragged_expand, the pattern merge_join_expand shares). Output
+    capacity is the static bound
     capacity * max_out, so no overflow is possible."""
 
     _chainable = False  # overrides _materialize (capacity changes)

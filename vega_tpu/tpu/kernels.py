@@ -866,20 +866,63 @@ def ragged_expand(counts_per_row: jax.Array, out_capacity: int):
     slot j belongs to row owner[j] at position offset[j] within that row's
     run, and total is the exact output size (saturated to INT32_MAX if the
     int32 prefix sums wrapped — the caller must fail loudly, not truncate).
-    Rows with count 0 never own a slot: the next row shares their start
-    and wins the 'right'-side binary search. Shared by merge_join_expand
-    and the device flat_map."""
+    The starts are a running sum, so no slot searches for its row: every
+    row that emits something marks its first slot (one scatter of the
+    rows), and two running maxima over the slots carry the mark and the
+    marked slot's own number over the row's run. Rows with count 0 mark
+    nothing and never own a slot. Slots at or past total are not output;
+    their owner stays in range. Shared by merge_join_expand, the device
+    flat_map and the cartesian product."""
     n_rows = counts_per_row.shape[0]
     m = counts_per_row
     starts = jnp.cumsum(m) - m
     total = jnp.sum(m).astype(jnp.int32)
     wrapped = (total < 0) | jnp.any(starts < 0)
     total = jnp.where(wrapped, jnp.int32(2**31 - 1), total)
+    # Emitting rows have distinct starts (a wrapped, negative one must not
+    # index from the end: it is dropped with the rows that emit nothing).
+    head = jnp.where((m > 0) & (starts >= 0), starts, out_capacity)
+    mark = jnp.zeros(out_capacity, jnp.int32).at[head].set(
+        lax.iota(jnp.int32, n_rows) + 1, mode="drop")
     j = lax.iota(jnp.int32, out_capacity)
-    owner = jnp.clip(jnp.searchsorted(starts, j, side="right") - 1,
-                     0, n_rows - 1)
-    offset = j - jnp.take(starts, owner)
+    owner = jnp.maximum(lax.cummax(mark) - 1, 0)
+    offset = j - lax.cummax(jnp.where(mark > 0, j, 0))
     return owner, offset, total
+
+
+def merge_ranks(lwords, rwords):
+    """(lo, hi) for every row of a sorted left key column: how many rows of
+    the sorted right key column hold a smaller key, and how many a key not
+    larger — np.searchsorted(rkeys, lkeys, "left") and (…, "right") — from
+    ONE merge of the two columns and a constant number of passes, where a
+    binary search is log2(n) gathers of the whole query column, each
+    waiting for the last. A side is a list of key words, major first: one
+    word, or the (key, lo) pair of a two-word int64 key.
+
+    Both columns are sorted over their whole length (padding included).
+    They are concatenated and sorted by key, stably: on equal keys the
+    left rows, which come first, stay first and keep their order.
+    In merged order the running count of right rows is lo at a left row;
+    hi is that count where the key's run ends, carried back over the run
+    by a reverse running min. The left rows' position says where each
+    reading belongs."""
+    lcap = lwords[0].shape[0]
+    n = lcap + rwords[0].shape[0]
+    words = [jnp.concatenate(pair) for pair in zip(lwords, rwords)]
+    *words, src = lax.sort((*words, lax.iota(jnp.int32, n)),
+                           num_keys=len(words), is_stable=True)
+    below = jnp.cumsum((src >= lcap).astype(jnp.int32))
+    run_end = words[0][1:] != words[0][:-1]
+    for w in words[1:]:
+        run_end = run_end | (w[1:] != w[:-1])
+    run_end = jnp.concatenate([run_end, jnp.ones(1, bool)])
+    not_above = lax.cummin(
+        jnp.where(run_end, below, jnp.int32(2**31 - 1)), reverse=True)
+    # a right row's position is lcap or more: out of range, dropped
+    zeros = jnp.zeros(lcap, jnp.int32)
+    lo = zeros.at[src].set(below, mode="drop")
+    hi = zeros.at[src].set(not_above, mode="drop")
+    return lo, hi
 
 
 def merge_join_expand(
@@ -899,9 +942,11 @@ def merge_join_expand(
     Reference semantics (pair_rdd.rs:104-121 via cogroup): inner join emits
     the full dup x dup product per key; left outer emits every valid left
     row, with fill_value in right columns when unmatched. Static shapes:
-    output rows are assigned by ragged expansion — per-left-row match
-    counts -> exclusive prefix sums -> each output slot finds its owning
-    left row by binary search — so the product materializes into a fixed
+    each left row's match range in the sorted right block comes from one
+    merge of the two key columns (merge_ranks), and output rows are
+    assigned by ragged expansion — per-left-row match counts -> exclusive
+    prefix sums -> each output slot takes the last left row that started
+    at or before it (ragged_expand) — so the product materializes into a fixed
     out_capacity with an overflow flag (the exchange capacity-factor
     pattern; driver retries with a larger capacity). Output rows are
     key-sorted (left sort order), deterministic across capacities.
@@ -920,31 +965,18 @@ def merge_join_expand(
     if not right_sorted:
         right = sort_by_column(right, right_count, key_name,
                                lo_name=lo_name, impl=sort_impl)
-    lkeys = left[key_name]
-    rkeys = right[key_name]
-    rmask = valid_mask(rcap, right_count)
-    rkeys = jnp.where(rmask, rkeys, _orderable_max(rkeys))
     lmask = valid_mask(lcap, left_count)
-
+    rmask = valid_mask(rcap, right_count)
+    lkeys = left[key_name]
+    # Both sides padded with the largest key, so each whole column is sorted.
+    names = [key_name] if lo_name is None else [key_name, lo_name]
+    lo, hi = merge_ranks(
+        [jnp.where(lmask, left[n], _orderable_max(left[n])) for n in names],
+        [jnp.where(rmask, right[n], _orderable_max(right[n])) for n in names])
     # Per-left-row match range in the sorted right block. The min() guards
     # clip sentinel-padded rows out when a valid key equals the sentinel.
-    if lo_name is None:
-        lo = jnp.minimum(jnp.searchsorted(rkeys, lkeys, side="left"),
-                         right_count)
-        hi = jnp.minimum(jnp.searchsorted(rkeys, lkeys, side="right"),
-                         right_count)
-    else:
-        lkeys_lo = left[lo_name]
-        rkeys_lo = jnp.where(rmask, right[lo_name],
-                             _orderable_max(right[lo_name]))
-        lo = jnp.minimum(
-            searchsorted2(rkeys, rkeys_lo, lkeys, lkeys_lo, "left"),
-            right_count,
-        )
-        hi = jnp.minimum(
-            searchsorted2(rkeys, rkeys_lo, lkeys, lkeys_lo, "right"),
-            right_count,
-        )
+    lo = jnp.minimum(lo, right_count)
+    hi = jnp.minimum(hi, right_count)
     n_match = hi - lo
     if outer:
         m = jnp.where(lmask, jnp.maximum(n_match, 1), 0)
