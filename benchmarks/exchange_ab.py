@@ -59,7 +59,7 @@ def main():
         mask = kernels.valid_mask(rows, count)
         bucket = hash_bucket(keys, n_shards)
         bucket = jnp.where(mask, bucket, n_shards)
-        cols, bucket = kernels.bucket_key_sort(cols, count, bucket, KEY)
+        cols, bucket = kernels.bucket_key_sort(cols, bucket, KEY)
         cols, c = kernels.segment_reduce_named(cols, count, KEY, "add",
                                                presorted=True)
         bucket = hash_bucket(cols[KEY], n_shards)

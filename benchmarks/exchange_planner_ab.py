@@ -70,11 +70,7 @@ def main():
         ctx.stop()
         print(json.dumps(result))
         return
-    saved = (conf.dense_exchange, conf.dense_hbm_budget,
-             conf.dense_table_plan)
-    # The warm table plan would elide the reduce exchange entirely —
-    # keep every leg measuring the planned exchange program.
-    conf.dense_table_plan = "off"
+    saved = (conf.dense_exchange, conf.dense_hbm_budget)
     try:
         def pipeline():
             red = (ctx.dense_from_numpy(keys, vals)
@@ -184,8 +180,7 @@ def main():
                 0 < planned_passes < legacy_passes),
         }
     finally:
-        (conf.dense_exchange, conf.dense_hbm_budget,
-         conf.dense_table_plan) = saved
+        conf.dense_exchange, conf.dense_hbm_budget = saved
         ctx.stop()
 
     print(json.dumps(result))

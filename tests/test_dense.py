@@ -1590,10 +1590,6 @@ def test_failed_speculation_repairs_downstream_consumers(dctx):
 
     red1, j1 = build()
     expected = sorted(j1.collect())  # cold run = oracle, seeds hints
-    # The warm table plan ignores capacity hints (it sizes from the key
-    # range); drop the range hint so the STANDARD speculative path —
-    # the machinery under test — runs.
-    dctx.__dict__.get("_dense_key_range_hints", {}).clear()
     red2, j2 = build()
     # Poison the reduce's capacities so its speculative launch overflows.
     dctx._dense_capacity_hints[red2._hint_key()] = (128, 128)
@@ -1621,8 +1617,6 @@ def test_settlement_midway_error_requeues_failed_entries(dctx):
 
     exp_a = dict(build_a().collect())  # cold oracles, seed hints
     exp_b = dict(build_b().collect())
-    # Standard speculative path under test (see the repair test above).
-    dctx.__dict__.get("_dense_key_range_hints", {}).clear()
     a2, b2 = build_a(), build_b()
     assert a2._hint_key() != b2._hint_key()
     # Poison A so its warm (speculative) launch overflows.
@@ -1785,138 +1779,65 @@ def test_values_dense_keeps_wide_pair_on_device(dctx):
     assert vals.max() == 2**41
 
 
-@pytest.mark.parametrize("plan", ["fused_sort", "sort_partition"])
-def test_rbk_sort_partition_plan_parity(dctx, plan):
-    """Both reduce exchange plans (fused multi-key sort; key-only sort ->
-    combine -> counting partition, Configuration.dense_rbk_plan) compute
-    identical results across named ops, traced combiners, wide int64
-    values, and downstream joins. Parametrized explicitly since the
-    round-5 'auto' default resolves per backend — neither plan may lose
-    coverage to the default."""
-    from vega_tpu.env import Env
-
-    old = Env.get().conf.dense_rbk_plan
-    Env.get().conf.dense_rbk_plan = plan
-    try:
-        r = (dctx.dense_range(50_000).map(lambda x: (x % 997, x))
-             .reduce_by_key(op="add"))
-        got = dict(r.collect())
-        exp = {}
-        for x in range(50_000):
-            exp[x % 997] = exp.get(x % 997, 0) + x
-        assert got == exp
-        assert r.hash_placed and r.key_sorted
-
-        # traced-combiner path
-        got2 = dict(dctx.dense_range(10_000)
-                    .map(lambda x: (x % 53, x * 1.0))
-                    .reduce_by_key(lambda a, b: a + b).collect())
-        assert got2[0] == sum(float(x) for x in range(10_000) if x % 53 == 0)
-
-        # wide int64 values ride the plan (sovf column partitions too)
+@pytest.mark.parametrize("area", ["named_op", "traced_combiner",
+                                  "wide_int64_values", "downstream_join"])
+def test_rbk_plan_matches_python(dctx, area):
+    """The reduce exchange's one plan (fused (bucket, key) sort ->
+    presorted combine -> pregrouped exchange -> merge) against a Python
+    fold, over the four areas it serves: named ops, traced combiners,
+    wide int64 values, and a downstream join over its output."""
+    if area == "traced_combiner":
+        got = dict(dctx.dense_range(10_000)
+                   .map(lambda x: (x % 53, x * 1.0))
+                   .reduce_by_key(lambda a, b: a + b).collect())
+        assert got == {k: sum(float(x) for x in range(k, 10_000, 53))
+                       for k in range(53)}
+        return
+    if area == "wide_int64_values":  # the sovf column rides the plan too
         wide = dctx.dense_from_numpy(
             np.array([1, 1, 2], dtype=np.int64),
             np.array([2**40, 2**41, 7], dtype=np.int64))
         assert dict(wide.reduce_by_key(op="add").collect()) == {
             1: 2**40 + 2**41, 2: 7}
-
-        # downstream join over the plan's hash-placed output elides
-        table = dctx.dense_from_numpy(np.arange(997, dtype=np.int32),
-                                      np.arange(997, dtype=np.int32))
-        j = dict(r.join(table).collect())
-        assert j[5] == (exp[5], 5)
-    finally:
-        Env.get().conf.dense_rbk_plan = old
-
-
-def test_rbk_plan_typo_raises(dctx):
-    from vega_tpu.env import Env
-
-    old = Env.get().conf.dense_rbk_plan
-    Env.get().conf.dense_rbk_plan = "sort-partition"  # typo'd
-    try:
-        with pytest.raises(v.VegaError, match="dense_rbk_plan"):
-            (dctx.dense_range(1_000).map(lambda x: (x % 7, x))
-             .reduce_by_key(op="add").collect())
-    finally:
-        Env.get().conf.dense_rbk_plan = old
+        return
+    exp = {k: sum(range(k, 50_000, 997)) for k in range(997)}
+    r = (dctx.dense_range(50_000).map(lambda x: (x % 997, x))
+         .reduce_by_key(op="add"))
+    if area == "named_op":
+        assert dict(r.collect()) == exp
+        assert r.hash_placed and r.key_sorted
+        return
+    # the plan's hash-placed output lets the join elide its left exchange
+    table = dctx.dense_from_numpy(np.arange(997, dtype=np.int32),
+                                  np.arange(997, dtype=np.int32))
+    j = r.join(table)
+    assert dict(j.collect()) == {k: (exp[k], k) for k in range(997)}
+    assert j._elided == (True, False)
 
 
-def test_rbk_plan_with_pallas_partition_ranks(dctx, monkeypatch):
-    """The sort_partition plan computes identical results when the
-    counting partition's ranks come from the Pallas kernel (interpret
-    mode here; on TPU the dispatcher enables it automatically)."""
-    from vega_tpu.env import Env
+def test_exchange_grouping_with_pallas_partition_ranks(dctx, monkeypatch):
+    """An exchange that groups its rows by bucket (group_by_key: no
+    pre-combine, so _group_by_bucket runs) computes identical results
+    when the counting partition's ranks come from the Pallas kernel
+    (interpret mode here; on TPU the dispatcher selects it at
+    lowering)."""
     from vega_tpu.tpu import dense_rdd as dr
     from vega_tpu.tpu import pallas_kernels
 
+    calls = []
+
+    def pallas_ranks(bucket, n_bins, starts, prefer_low_memory=False):
+        calls.append(n_bins)
+        return pallas_kernels.partition_pos_pallas(bucket, n_bins, starts,
+                                                   True)
+
     monkeypatch.setattr(dr, "_PROGRAM_CACHE", {})  # force re-trace
-    monkeypatch.setattr(
-        pallas_kernels, "partition_pos",
-        lambda bucket, n_bins, starts, prefer_low_memory=False:
-        pallas_kernels.partition_pos_pallas(bucket, n_bins, starts, True))
-    old = Env.get().conf.dense_rbk_plan
-    Env.get().conf.dense_rbk_plan = "sort_partition"
-    try:
-        r = (dctx.dense_range(30_000).map(lambda x: (x % 433, x))
-             .reduce_by_key(op="add"))
-        got = dict(r.collect())
-        exp = {}
-        for x in range(30_000):
-            exp[x % 433] = exp.get(x % 433, 0) + x
-        assert got == exp
-    finally:
-        Env.get().conf.dense_rbk_plan = old
-
-
-@pytest.mark.parametrize("impl", ["radix", "packed"])
-def test_dense_sort_impl_radix_parity(dctx, impl):
-    """Alternative dense_sort_impls ('radix' LSD digits; 'packed'
-    single-operand 63-bit word sort) compute identical results through
-    the whole dense surface: sort_by_key (asc/desc), reduce_by_key (both
-    plans), group_by_key, and int64 wide keys."""
-    from vega_tpu.env import Env
-
-    old = Env.get().conf.dense_sort_impl
-    Env.get().conf.dense_sort_impl = impl
-    try:
-        n = 20_000
-        kv = dctx.dense_range(n).map(lambda x: ((x * 2654435761) % n, x))
-        keys = [k for k, _ in kv.sort_by_key().collect()]
-        assert keys == sorted((x * 2654435761) % n for x in range(n))
-        keys_d = [k for k, _ in kv.sort_by_key(ascending=False).collect()]
-        assert keys_d == sorted(((x * 2654435761) % n for x in range(n)),
-                                reverse=True)
-
-        got = dict(dctx.dense_range(n).map(lambda x: (x % 211, x))
-                   .reduce_by_key(op="add").collect())
-        assert got[0] == sum(x for x in range(n) if x % 211 == 0)
-
-        g = (dctx.dense_range(5_000).map(lambda x: (x % 7, x))
-             .group_by_key())
-        ks, offs, vals = g.collect_grouped()
-        assert sorted(ks.tolist()) == list(range(7))
-
-        wide = dctx.dense_from_numpy(
-            np.array([2**40, 5, 2**40, 5], dtype=np.int64),
-            np.array([1, 2, 3, 4], dtype=np.int64))
-        srt = wide.sort_by_key().collect()
-        assert [k for k, _ in srt] == [5, 5, 2**40, 2**40]
-    finally:
-        Env.get().conf.dense_sort_impl = old
-
-
-def test_dense_sort_impl_typo_raises(dctx):
-    from vega_tpu.env import Env
-
-    old = Env.get().conf.dense_sort_impl
-    Env.get().conf.dense_sort_impl = "Radix"
-    try:
-        with pytest.raises(v.VegaError, match="dense_sort_impl"):
-            (dctx.dense_range(1_000).map(lambda x: (x % 7, x))
-             .reduce_by_key(op="add").collect())
-    finally:
-        Env.get().conf.dense_sort_impl = old
+    monkeypatch.setattr(pallas_kernels, "partition_pos", pallas_ranks)
+    g = (dctx.dense_range(30_000).map(lambda x: (x % 433, x))
+         .group_by_key())
+    got = {k: sorted(vs) for k, vs in g.collect()}
+    assert calls, "the grouping never asked for partition ranks"
+    assert got == {k: list(range(k, 30_000, 433)) for k in range(433)}
 
 
 def test_sort_by_key_descending_int_min(dctx):
@@ -1932,120 +1853,27 @@ def test_sort_by_key_descending_int_min(dctx):
     assert got_asc == [-2**31, -3, 0, 5, 7]
 
 
-def test_take_ordered_top_radix_parity(dctx):
-    """take_ordered/top row sorts under dense_sort_impl=radix match the
-    lax.sort path across value-only, pair, wide-int64, and float blocks
-    (both directions)."""
-    from vega_tpu.env import Env
-
+@pytest.mark.parametrize("action", ["take_ordered", "top"])
+@pytest.mark.parametrize("kind", ["scalar", "pair", "float", "wide-pair"])
+def test_take_ordered_top_match_sorted(dctx, kind, action):
+    """take_ordered / top (the per-shard row sort + the driver's merge)
+    against sorted() of the same rows, over value-only, pair, float and
+    wide-int64 pair blocks."""
     rng = np.random.RandomState(12)
     vals32 = rng.randint(-10**6, 10**6, 5_000).astype(np.int32)
     keys32 = rng.randint(-500, 500, 5_000).astype(np.int32)
     flo = (rng.randn(5_000) * 100).astype(np.float32)
     wide = rng.randint(-2**50, 2**50, 3_000).astype(np.int64)
     wkeys = rng.randint(0, 100, 3_000).astype(np.int64)
-
-    cases = [
-        ("scalar", dctx.dense_from_numpy(vals32)),
-        ("pair", dctx.dense_from_numpy(keys32, vals32)),
-        ("float", dctx.dense_from_numpy(flo)),
-        ("wide-pair", dctx.dense_from_numpy(wkeys, wide)),
-    ]
-    old = Env.get().conf.dense_sort_impl
-    try:
-        # baseline PINNED to the lax.sort path — comparing radix to the
-        # ambient default could degenerate into radix vs itself
-        Env.get().conf.dense_sort_impl = "xla"
-        exp = {name: (r.take_ordered(9), r.top(9)) for name, r in cases}
-        Env.get().conf.dense_sort_impl = "radix"
-        for name, r in cases:
-            assert r.take_ordered(9) == exp[name][0], name
-            assert r.top(9) == exp[name][1], name
-    finally:
-        Env.get().conf.dense_sort_impl = old
-
-
-def test_table_plan_warm_reduce_and_repair(dctx):
-    """The speculative dense-key table plan (round 5): a warm rerun of a
-    named reduce whose key range was observed small collapses to
-    scatter-table + psum + hash-mask compact (no sort, no exchange) with
-    hash-placed, key-sorted output — and a STALE range hint (data now
-    outside the hinted range) flags on device and settles through the
-    standard repair, never serving wrong results."""
-    def build():
-        return (dctx.dense_range(20_000).map(lambda x: (x % 1_000, x))
-                .reduce_by_key(op="add"))
-
-    r1 = build()
-    exp = dict(r1.collect())  # cold: standard plan, learns [0, 999]
-    assert r1._table_plan is False
-    r2 = build()
-    got2 = dict(r2.collect())  # warm: table plan
-    assert r2._table_plan is True
-    assert got2 == exp
-    assert r2.hash_placed and r2.key_sorted
-    # Downstream elision still applies over the table output.
-    import numpy as np
-    table = dctx.dense_from_numpy(np.arange(1_000, dtype=np.int32),
-                                  np.arange(1_000, dtype=np.int32) * 2)
-    j = r2.join(table)
-    assert dict(j.collect())[7] == (exp[7], 14)
-    assert j._elided == (True, False)
-
-    # Poisoned (too-small) range: the table launch must flag + repair.
-    hints = dctx.__dict__["_dense_key_range_hints"]
-    r3 = build()
-    hints[r3._hint_key()] = (0, 99)  # claims keys fit [0, 100)
-    blk = r3.block_spec()
-    assert r3._table_plan is True  # speculative launch happened
-    assert blk.settle is not None
-    got3 = dict(r3.collect())  # settle -> flag -> standard-plan repair
-    assert got3 == exp
-    assert not dctx.__dict__.get("_dense_pending")
-    # Repair re-learned the true range; the next warm run tables again.
-    r4 = build()
-    assert dict(r4.collect()) == exp
-    assert r4._table_plan is True
-
-
-def test_table_plan_concurrent_no_defer_falls_through(dctx):
-    """Regression: a settlement repair that sets
-    _dense_no_defer AFTER the table-plan gate but BEFORE its launch must
-    make the reduce fall through to the standard plan — not feed the
-    fixed-caps table program into _run_exchange's blocking retry loop,
-    whose grown capacities the table build ignores (six identical
-    launches ending in a spurious VegaError). Simulated by flipping the
-    flag from inside the table program's cache lookup — the worst-timed
-    interleaving."""
-    from vega_tpu.tpu import dense_rdd as dr
-
-    def build():
-        return (dctx.dense_range(20_000).map(lambda x: (x % 1_000, x))
-                .reduce_by_key(op="add"))
-
-    exp = dict(build().collect())  # cold: learns the range
-    warm = build()
-    assert dict(warm.collect()) == exp
-    assert warm._table_plan is True  # hint active: table plan armed
-
-    real = dr._cached_program
-
-    def racing(key, build_fn):
-        prog = real(key, build_fn)
-        if isinstance(key, tuple) and key and key[0] == "rbk_table":
-            # The concurrent repair lands exactly here.
-            dctx.__dict__["_dense_no_defer"] = True
-        return prog
-
-    dr._cached_program = racing
-    try:
-        r = build()
-        got = dict(r.collect())  # must NOT raise VegaError
-        assert got == exp
-        assert r._table_plan is False  # fell through to the standard plan
-    finally:
-        dr._cached_program = real
-        dctx.__dict__["_dense_no_defer"] = False
+    cols = {"scalar": (vals32,), "pair": (keys32, vals32),
+            "float": (flo,), "wide-pair": (wkeys, wide)}[kind]
+    rows = (cols[0].tolist() if len(cols) == 1
+            else list(zip(*(c.tolist() for c in cols))))
+    r = dctx.dense_from_numpy(*cols)
+    if action == "take_ordered":
+        assert r.take_ordered(9) == sorted(rows)[:9]
+    else:
+        assert r.top(9) == sorted(rows, reverse=True)[:9]
 
 
 def test_multiproc_memo_resets_on_multihost_init(monkeypatch):
@@ -2205,10 +2033,6 @@ def test_exchange_planner_program_parity(dctx):
     # Leg A: forced one-shot all_to_all at the default budget.
     old_mode = conf.dense_exchange
     conf.dense_exchange = "all_to_all"
-    # The warm table plan would elide the rbk exchange entirely on rerun
-    # — keep the planner exercised on every leg.
-    old_table = conf.dense_table_plan
-    conf.dense_table_plan = "off"
     try:
         nodes_a, leg_a = pipelines()
     finally:
@@ -2229,7 +2053,6 @@ def test_exchange_planner_program_parity(dctx):
         nodes_b, leg_b = pipelines()
     finally:
         conf2.dense_hbm_budget = old
-        conf.dense_table_plan = old_table
     assert leg_b == leg_a  # bit-identical across programs
     counters = exchange_plan.plan_counters()
     assert counters.get("staged", 0) >= 4, counters

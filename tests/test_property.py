@@ -181,44 +181,34 @@ def test_random_cartesian_parity(ctx, seed):
 
 
 @pytest.mark.parametrize("seed", [30, 31, 32])
-def test_random_alternative_stack_parity(ctx, seed):
-    """The full alternative execution stack — sort_partition reduce plan
-    + radix sorts — matches the host tier on random keyed data across
-    reduce, group, join, and sort (the same parity oracle the default
-    stack answers to)."""
-    from vega_tpu.env import Env
+def test_random_reduce_sort_join_stack_parity(ctx, seed):
+    """One block through the whole keyed stack — reduce, sort, and a
+    reduce feeding a join — matches the host tier on random keyed data
+    (negative keys included)."""
+    rng = np.random.RandomState(seed)
+    n = int(rng.randint(2_000, 20_000))
+    keys = rng.randint(-500, 500, n).astype(np.int32)
+    vals = rng.randint(-10**6, 10**6, n).astype(np.int32)
+    dev = ctx.dense_from_numpy(keys, vals)
+    host = ctx.parallelize(list(zip(keys.tolist(), vals.tolist())), 4)
 
-    conf = Env.get().conf
-    old = (conf.dense_rbk_plan, conf.dense_sort_impl)
-    conf.dense_rbk_plan = "sort_partition"
-    conf.dense_sort_impl = ("radix4", "radix", "packed")[seed % 3]
-    try:
-        rng = np.random.RandomState(seed)
-        n = int(rng.randint(2_000, 20_000))
-        keys = rng.randint(-500, 500, n).astype(np.int32)
-        vals = rng.randint(-10**6, 10**6, n).astype(np.int32)
-        dev = ctx.dense_from_numpy(keys, vals)
-        host = ctx.parallelize(list(zip(keys.tolist(), vals.tolist())), 4)
+    red = dev.reduce_by_key(op="add").collect()
+    host_red = host.reduce_by_key(lambda a, b: a + b).collect()
+    # length asserted too: dict() would mask a key surviving in two
+    # shards with partial sums (the plan's most plausible failure)
+    assert len(red) == len(host_red)
+    assert dict(red) == dict(host_red)
+    srt = dev.sort_by_key().collect()
+    assert sorted(srt) == sorted(host.collect())
+    assert [k for k, _ in srt] == sorted(keys.tolist())
 
-        red = dev.reduce_by_key(op="add").collect()
-        host_red = host.reduce_by_key(lambda a, b: a + b).collect()
-        # length asserted too: dict() would mask a key surviving in two
-        # shards with partial sums (the plan's most plausible failure)
-        assert len(red) == len(host_red)
-        assert dict(red) == dict(host_red)
-        srt = dev.sort_by_key().collect()
-        assert sorted(srt) == sorted(host.collect())
-        assert [k for k, _ in srt] == sorted(keys.tolist())
-
-        table_k = np.unique(keys)[:200].astype(np.int32)
-        table_v = (table_k * 3).astype(np.int32)
-        dj = (dev.reduce_by_key(op="min")
-              .join(ctx.dense_from_numpy(table_k, table_v)).collect())
-        hj = (host.reduce_by_key(lambda a, b: min(a, b))
-              .join(ctx.parallelize(
-                  list(zip(table_k.tolist(), table_v.tolist())), 3))
-              .collect())
-        assert len(dj) == len(hj)
-        assert dict(dj) == dict(hj)
-    finally:
-        conf.dense_rbk_plan, conf.dense_sort_impl = old
+    table_k = np.unique(keys)[:200].astype(np.int32)
+    table_v = (table_k * 3).astype(np.int32)
+    dj = (dev.reduce_by_key(op="min")
+          .join(ctx.dense_from_numpy(table_k, table_v)).collect())
+    hj = (host.reduce_by_key(lambda a, b: min(a, b))
+          .join(ctx.parallelize(
+              list(zip(table_k.tolist(), table_v.tolist())), 3))
+          .collect())
+    assert len(dj) == len(hj)
+    assert dict(dj) == dict(hj)

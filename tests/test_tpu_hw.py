@@ -206,7 +206,7 @@ def test_hw_partition_rank_kernel(hw_ctx, n_bins):
     """The Pallas streaming histogram and counting-partition rank kernels
     compute XLA-identical counts and positions on the real chip (compiled
     Mosaic, not interpret mode), at the exchange's bucket range (mesh
-    size + 1), the 65-bin gate, and the 16/256-bin radix variants."""
+    size + 1), the 65-bin gate, and 16 and 256 bins."""
     import jax.numpy as jnp
 
     from vega_tpu.tpu.pallas_kernels import (digit_hist_pallas,
@@ -225,58 +225,3 @@ def test_hw_partition_rank_kernel(hw_ctx, n_bins):
     got = partition_pos_pallas(jnp.asarray(bucket), n_bins,
                                jnp.asarray(starts))
     np.testing.assert_array_equal(np.asarray(got), exp)
-
-
-def test_hw_radix_sort_parity(hw_ctx):
-    """The radix sort path (Pallas digit histogram + rank kernels,
-    compiled Mosaic) matches lax.sort results on the real chip."""
-    from vega_tpu.env import Env
-
-    n = 300_000
-    kv = hw_ctx.dense_range(n).map(lambda x: ((x * 2654435761) % n, x))
-    exp = kv.sort_by_key().collect()
-    old = Env.get().conf.dense_sort_impl
-    Env.get().conf.dense_sort_impl = "radix"
-    try:
-        kv2 = hw_ctx.dense_range(n).map(
-            lambda x: ((x * 2654435761) % n, x))
-        got = kv2.sort_by_key().collect()
-        assert got == exp
-    finally:
-        Env.get().conf.dense_sort_impl = old
-
-
-def test_hw_table_plan_parity(hw_ctx):
-    """The speculative dense-key table plan (round 5: scatter table +
-    psum + hash-mask compact) computes the exact answer ON CHIP with
-    dense_table_plan='on' — TPU scatters and the psum collective behave
-    differently from the CPU mesh, and the headline bench will not flip
-    to this plan on TPU until this passes plus the 02_plan_ab table leg
-    measures a win."""
-    from vega_tpu.env import Env
-
-    old = Env.get().conf.dense_table_plan
-    Env.get().conf.dense_table_plan = "on"
-    try:
-        def build():
-            return (hw_ctx.dense_range(150_000)
-                    .map(lambda x: (x % 700, x))
-                    .reduce_by_key(op="add"))
-
-        r1 = build()
-        exp = dict(r1.collect())  # cold: learns the range
-        r2 = build()
-        got = dict(r2.collect())  # warm: table plan on chip
-        assert r2._table_plan is True
-        oracle = {}
-        for x in range(150_000):
-            oracle[x % 700] = oracle.get(x % 700, 0) + x
-        assert got == oracle == exp
-        assert r2.hash_placed and r2.key_sorted
-        # stale-range repair fires on hardware too
-        hints = hw_ctx.__dict__["_dense_key_range_hints"]
-        r3 = build()
-        hints[r3._hint_key()] = (0, 9)
-        assert dict(r3.collect()) == oracle
-    finally:
-        Env.get().conf.dense_table_plan = old

@@ -60,45 +60,6 @@ def test_ring_sort_and_join(ring_ctx):
     assert left.join(right).count() == 1000
 
 
-def test_sort_impl_flip_mints_fresh_programs(ring_ctx):
-    """Regression: an in-process dense_sort_impl flip must
-    re-trace every cached program that can reach _group_by_bucket's
-    escape hatch — the resolved impl is read at trace time, so a stale
-    cached program would silently A/B the wrong implementation. The ring
-    exchange on CPU takes the escape hatch (prefer_low_memory with no
-    Pallas path), and the sort node exercises the exchange keys. Results
-    must also be identical under either impl (both groupings are
-    stable)."""
-    from vega_tpu.env import Env
-    from vega_tpu.tpu import dense_rdd as dr
-
-    def run():
-        return sorted(
-            (k, sorted(vs)) for k, vs in
-            ring_ctx.dense_range(8_000).map(lambda x: (x % 97, x))
-            .group_by_key().collect()
-        )
-
-    conf = Env.get().conf
-    old = conf.dense_sort_impl
-
-    def gbk_keys():
-        return {k for k in dr._PROGRAM_CACHE if k[0] == "gbk"}
-
-    try:
-        conf.dense_sort_impl = "xla"
-        first = run()
-        keys_xla = gbk_keys()
-        assert any("xla" in k for k in keys_xla)
-        conf.dense_sort_impl = "packed"
-        assert run() == first  # bit-identical across impls
-        fresh = gbk_keys() - keys_xla
-        assert fresh and all("packed" in k for k in fresh), \
-            "the flipped impl must mint fresh programs, not reuse stale"
-    finally:
-        conf.dense_sort_impl = old
-
-
 def test_ring_skew_overflow(ring_ctx):
     got = dict(
         ring_ctx.dense_range(4096).map(lambda x: (x * 0, x))
@@ -165,7 +126,7 @@ def test_bucket_key_sort_groups_and_sorts():
     bucket = jnp.where(iota < count, keys % n_shards, n_shards)
     cols = {"k": keys, "v": vals}
 
-    out, sb = kernels.bucket_key_sort(cols, jnp.int32(count), bucket, "k")
+    out, sb = kernels.bucket_key_sort(cols, bucket, "k")
 
     sb = np.asarray(sb)
     ok = np.asarray(out["k"])
@@ -297,204 +258,150 @@ def test_partition_pos_pallas_lowers_for_tpu():
     assert "tpu_custom_call" in exp.mlir_module()
 
 
-def test_radix_sort_perm_matches_argsort():
-    """The LSD radix permutation is bit-identical to a stable argsort for
-    int32, float32, and wide int64 keys, ascending and descending, with
-    ghost rows sinking last."""
-    import jax
-    from vega_tpu.tpu import block as block_lib
-    from vega_tpu.tpu import pallas_kernels as pk
-
-    rng = np.random.RandomState(9)
-    n, count = 5_000, 4_321
-
-    def run(words, descending):
-        return np.asarray(kernels.radix_sort_perm(
-            [jnp.asarray(w) for w in words], jnp.int32(count), descending))
-
-    # int32 (duplicates included: stability check)
-    ints = rng.randint(-2**31, 2**31 - 1, size=n).astype(np.int32)
-    ints[: n // 4] = rng.randint(-50, 50, size=n // 4)
-    u = kernels._orderable_u32(jnp.asarray(ints), False)
-    for desc in (False, True):
-        got = run([u], desc)
-        key = ints[:count] if not desc else None
-        order = np.argsort(ints[:count] if not desc else -ints[:count].astype(np.int64),
-                           kind="stable")
-        np.testing.assert_array_equal(got[:count], order)
-        assert sorted(got[count:].tolist()) == list(range(count, n))
-
-    # float32 incl. negatives
-    fl = (rng.randn(n) * 100).astype(np.float32)
-    uf = kernels._orderable_u32(jnp.asarray(fl), True)
-    got = run([uf], False)
-    np.testing.assert_array_equal(got[:count],
-                                  np.argsort(fl[:count], kind="stable"))
-
-    # wide int64: (hi, stored-lo) words, LSD order [lo, hi]
-    big = rng.randint(-2**62, 2**62, size=n).astype(np.int64)
-    hi, lo = block_lib.encode_i64(big)
-    wl = kernels._orderable_u32(jnp.asarray(lo), False)
-    wh = kernels._orderable_u32(jnp.asarray(hi), False)
-    got = run([wl, wh], False)
-    np.testing.assert_array_equal(got[:count],
-                                  np.argsort(big[:count], kind="stable"))
-
-
-def test_sort_by_column_radix_impl_parity():
-    """sort_by_column(impl='radix') returns exactly what the lax.sort
-    path returns for supported dtypes (int32, float32, wide)."""
+def _sort_case(keyset, rng, n):
+    """(cols, lo_name, host keys) for one key layout, duplicate keys
+    included (the values then pin the stable order)."""
     from vega_tpu.tpu import block as block_lib
     from vega_tpu.tpu.block import KEY, KEY_LO, VALUE
 
-    rng = np.random.RandomState(4)
-    n, count = 3_000, 2_700
     vals = rng.randint(0, 10**6, size=n).astype(np.int32)
+    if keyset == "int32":
+        host = rng.randint(-100, 100, size=n).astype(np.int32)
+        return {KEY: host, VALUE: vals}, None, host
+    if keyset == "float32":
+        host = (rng.randn(n) * 10).astype(np.float32)
+        return {KEY: host, VALUE: vals}, None, host
+    host = rng.randint(-2**50, 2**50, size=n).astype(np.int64)
+    host[: n // 4] = host[0] + np.arange(n // 4) % 7  # duplicates too
+    hi, lo = block_lib.encode_i64(host)
+    return {KEY: hi, KEY_LO: lo, VALUE: vals}, KEY_LO, host
 
-    for keyset in ("int32", "float32", "wide"):
-        if keyset == "int32":
-            cols = {KEY: jnp.asarray(
-                rng.randint(-100, 100, size=n).astype(np.int32)),
-                VALUE: jnp.asarray(vals)}
-            lo_name = None
-        elif keyset == "float32":
-            cols = {KEY: jnp.asarray((rng.randn(n) * 10).astype(np.float32)),
-                    VALUE: jnp.asarray(vals)}
-            lo_name = None
-        else:
-            big = rng.randint(-2**50, 2**50, size=n).astype(np.int64)
-            hi, lo = block_lib.encode_i64(big)
-            cols = {KEY: jnp.asarray(hi), KEY_LO: jnp.asarray(lo),
-                    VALUE: jnp.asarray(vals)}
-            lo_name = KEY_LO
-        for desc in (False, True):
-            a = kernels.sort_by_column(dict(cols), jnp.int32(count), KEY,
-                                       descending=desc, lo_name=lo_name)
-            for impl in ("radix", "radix4"):
-                b = kernels.sort_by_column(dict(cols), jnp.int32(count),
-                                           KEY, descending=desc,
-                                           lo_name=lo_name, impl=impl)
-                for nm in cols:
-                    np.testing.assert_array_equal(
-                        np.asarray(a[nm])[:count],
-                        np.asarray(b[nm])[:count],
-                        err_msg=f"{keyset} {impl} desc={desc} col={nm}")
+
+def _stable_order(host, descending):
+    """numpy's stable order of the valid keys; descending flips the key
+    without overflow (bitwise-not for ints, negation for floats), so ties
+    keep their source order either way."""
+    if descending:
+        host = -host if host.dtype.kind == "f" else ~host
+    return np.argsort(host, kind="stable")
+
+
+def _assert_sorted_like(cols, out, order, count):
+    for nm, col in cols.items():
+        np.testing.assert_array_equal(
+            np.asarray(out[nm])[:count], np.asarray(col)[:count][order],
+            err_msg=nm)
+
+
+@pytest.mark.parametrize("descending", [False, True],
+                         ids=["ascending", "descending"])
+@pytest.mark.parametrize("keyset", ["int32", "float32", "wide"])
+def test_sort_by_column_matches_numpy_stable_argsort(keyset, descending):
+    """sort_by_column against numpy on the valid prefix: every column
+    follows the key's stable order (duplicates included), and the ghost
+    rows sink behind it."""
+    from vega_tpu.tpu.block import KEY
+
+    n, count = 3_000, 2_700
+    cols, lo_name, host = _sort_case(keyset, np.random.RandomState(4), n)
+    out = kernels.sort_by_column(
+        {nm: jnp.asarray(c) for nm, c in cols.items()}, jnp.int32(count),
+        KEY, descending=descending, lo_name=lo_name)
+    _assert_sorted_like(cols, out, _stable_order(host[:count], descending),
+                        count)
+
+
+def _edge_rows(kind):
+    """(host keys, count, lo?) for the rows the permutation tests kept:
+    the int32 and float extremes among the valid rows (they tie with the
+    padding the ghosts are forced to), ghost rows holding keys that would
+    sort first, a constant high word, and no valid row at all."""
+    rng = np.random.RandomState(11)
+    n, count = 5_000, 4_321
+    if kind == "int32_extremes":
+        host = rng.randint(-2**31, 2**31 - 1, size=n).astype(np.int32)
+        host[: n // 4] = rng.randint(-50, 50, size=n // 4)  # duplicates
+        host[0], host[1] = -2**31, 2**31 - 1
+        host[7], host[9] = 2**31 - 1, -2**31
+        host[count:] = -2**31  # ghosts must not lead an ascending sort
+    elif kind == "float32_infinities":
+        host = (rng.randn(n) * 100).astype(np.float32)
+        host[3], host[4], host[5] = np.inf, -np.inf, np.inf
+        host[count:] = -np.inf
+    elif kind == "wide_constant_high_word":
+        host = (2**40 + rng.randint(0, 1_000, size=n)).astype(np.int64)
+    elif kind == "wide_full_range":
+        host = rng.randint(-2**62, 2**62, size=n).astype(np.int64)
+        host[0], host[1] = np.iinfo(np.int64).min, np.iinfo(np.int64).max
+    else:
+        assert kind == "all_ghost"
+        host = rng.randint(-50, 50, size=n).astype(np.int32)
+        count = 0
+    return host, count
+
+
+@pytest.mark.parametrize("descending", [False, True],
+                         ids=["ascending", "descending"])
+@pytest.mark.parametrize("kind", [
+    "int32_extremes", "float32_infinities", "wide_constant_high_word",
+    "wide_full_range", "all_ghost"])
+def test_sort_by_column_edge_rows(kind, descending):
+    """Extreme keys tie with the ghosts' padding and must still come
+    first (stability: valid rows sit at lower positions); the ghosts keep
+    their own order behind them, so an all-ghost shard is the identity."""
+    from vega_tpu.tpu import block as block_lib
+    from vega_tpu.tpu.block import KEY, KEY_LO, VALUE
+
+    host, count = _edge_rows(kind)
+    n = host.shape[0]
+    tag = np.arange(n, dtype=np.int32)  # source position of every row
+    if host.dtype == np.int64:
+        hi, lo = block_lib.encode_i64(host)
+        cols, lo_name = {KEY: hi, KEY_LO: lo, VALUE: tag}, KEY_LO
+    else:
+        cols, lo_name = {KEY: host, VALUE: tag}, None
+    out = kernels.sort_by_column(
+        {nm: jnp.asarray(c) for nm, c in cols.items()}, jnp.int32(count),
+        KEY, descending=descending, lo_name=lo_name)
+    _assert_sorted_like(cols, out, _stable_order(host[:count], descending),
+                        count)
+    assert np.asarray(out[VALUE])[count:].tolist() == list(range(count, n))
 
 
 def test_sort_by_column_descending_int_min():
     """Regression: descending int sorts must not negate the key —
     negation wraps INT32_MIN onto itself and sorts it FIRST instead of
-    last. Both impls agree on the fixed behavior."""
+    last."""
     from vega_tpu.tpu.block import KEY
 
     keys = np.array([5, -2**31, 7, 0], dtype=np.int32)
-    for impl in ("xla", "radix"):
-        out = kernels.sort_by_column({KEY: jnp.asarray(keys)},
-                                     jnp.int32(4), KEY, descending=True,
-                                     impl=impl)
-        assert np.asarray(out[KEY]).tolist() == [7, 5, 0, -2**31], impl
+    out = kernels.sort_by_column({KEY: jnp.asarray(keys)},
+                                 jnp.int32(4), KEY, descending=True)
+    assert np.asarray(out[KEY]).tolist() == [7, 5, 0, -2**31]
 
 
-def test_bucket_key_sort_radix_parity():
-    """The radix form of the fused (bucket major, key minor) sort — key
-    word passes + one narrow 8-bit bucket pass — matches the lax.sort
-    form for int32 and wide int64 keys, ghosts included."""
-    from vega_tpu.tpu import block as block_lib
-    from vega_tpu.tpu.block import KEY, KEY_LO, VALUE
+@pytest.mark.parametrize("keyset", ["int32", "float32", "wide"])
+def test_bucket_key_sort_matches_numpy_lexsort(keyset):
+    """The fused (bucket major, key minor) sort against
+    np.lexsort((key, bucket)) over every row, ghosts (bucket = n_shards)
+    included: lexsort is stable, so the whole permutation is pinned and
+    each column must follow it."""
+    from vega_tpu.tpu.block import KEY
 
-    rng = np.random.RandomState(6)
     n, count, n_shards = 4_000, 3_500, 8
-
-    for keyset in ("int32", "wide"):
-        if keyset == "int32":
-            cols = {KEY: jnp.asarray(
-                rng.randint(-1000, 1000, size=n).astype(np.int32)),
-                VALUE: jnp.asarray(np.arange(n, dtype=np.int32))}
-            lo_name = None
-            bucket_src = cols[KEY]
-        else:
-            big = rng.randint(-2**50, 2**50, size=n).astype(np.int64)
-            hi, lo = block_lib.encode_i64(big)
-            cols = {KEY: jnp.asarray(hi), KEY_LO: jnp.asarray(lo),
-                    VALUE: jnp.asarray(np.arange(n, dtype=np.int32))}
-            lo_name = KEY_LO
-            bucket_src = cols[KEY]
-        bucket = (kernels.hash32(bucket_src)
-                  % jnp.uint32(n_shards)).astype(jnp.int32)
-        bucket = jnp.where(kernels.valid_mask(n, jnp.int32(count)),
-                           bucket, n_shards)
-        a_cols, a_bucket = kernels.bucket_key_sort(
-            dict(cols), jnp.int32(count), bucket, KEY, lo_name=lo_name)
-        for impl in ("radix", "radix4"):
-            b_cols, b_bucket = kernels.bucket_key_sort(
-                dict(cols), jnp.int32(count), bucket, KEY,
-                lo_name=lo_name, impl=impl, n_shards=n_shards)
-            np.testing.assert_array_equal(
-                np.asarray(a_bucket)[:count], np.asarray(b_bucket)[:count])
-            for nm in cols:
-                np.testing.assert_array_equal(
-                    np.asarray(a_cols[nm])[:count],
-                    np.asarray(b_cols[nm])[:count],
-                    err_msg=f"{keyset} {impl} {nm}")
-
-
-def test_packed_sort_perm_matches_argsort():
-    """The single-operand packed permutation (round 5) is bit-identical
-    to a stable argsort for int32 (INT32_MIN/MAX included), float32, and
-    wide int64 keys, ascending and descending, with ghost rows sinking
-    last — the same oracle the radix path answers to."""
-    from vega_tpu.tpu import block as block_lib
-
-    rng = np.random.RandomState(11)
-    n, count = 5_000, 4_321
-
-    def run(words, descending):
-        return np.asarray(kernels.packed_sort_perm(
-            [jnp.asarray(w) for w in words], jnp.int32(count), descending))
-
-    ints = rng.randint(-2**31, 2**31 - 1, size=n).astype(np.int32)
-    ints[: n // 4] = rng.randint(-50, 50, size=n // 4)  # dup stability
-    ints[0], ints[1] = np.int32(-2**31), np.int32(2**31 - 1)  # edges
-    u = kernels._orderable_u32(jnp.asarray(ints), False)
-    for desc in (False, True):
-        got = run([u], desc)
-        order = np.argsort(
-            ints[:count] if not desc else -ints[:count].astype(np.int64),
-            kind="stable")
-        np.testing.assert_array_equal(got[:count], order)
-        # invalid rows keep their relative order at the end (stable)
-        assert got[count:].tolist() == list(range(count, n))
-
-    fl = (rng.randn(n) * 100).astype(np.float32)
-    uf = kernels._orderable_u32(jnp.asarray(fl), True)
-    got = run([uf], False)
-    np.testing.assert_array_equal(got[:count],
-                                  np.argsort(fl[:count], kind="stable"))
-
-    big = rng.randint(-2**62, 2**62, size=n).astype(np.int64)
-    hi, lo = block_lib.encode_i64(big)
-    wl = kernels._orderable_u32(jnp.asarray(lo), False)
-    wh = kernels._orderable_u32(jnp.asarray(hi), False)
-    got = run([wl, wh], False)
-    np.testing.assert_array_equal(got[:count],
-                                  np.argsort(big[:count], kind="stable"))
-
-    # CONSTANT hi word (wide ids in a narrow band — the runtime
-    # constant-word skip's target shape): the cond's skip branch must
-    # produce the same stable order the full pass would.
-    band = (2**40 + rng.randint(0, 1_000, size=n)).astype(np.int64)
-    bhi, blo = block_lib.encode_i64(band)
-    assert np.unique(np.asarray(bhi)[:count]).size == 1  # skip fires
-    got = run([kernels._orderable_u32(jnp.asarray(blo), False),
-               kernels._orderable_u32(jnp.asarray(bhi), False)], False)
-    np.testing.assert_array_equal(got[:count],
-                                  np.argsort(band[:count], kind="stable"))
-    assert got[count:].tolist() == list(range(count, n))
-
-    # empty-valid edge: every row is a ghost, order is the identity
-    got_all_ghost = np.asarray(kernels.packed_sort_perm(
-        [u], jnp.int32(0), False))
-    assert got_all_ghost.tolist() == list(range(n))
+    cols, lo_name, host = _sort_case(keyset, np.random.RandomState(6), n)
+    bucket = np.asarray(
+        kernels.hash32(jnp.asarray(cols[KEY])) % jnp.uint32(n_shards)
+    ).astype(np.int32)
+    bucket[count:] = n_shards
+    out, out_bucket = kernels.bucket_key_sort(
+        {nm: jnp.asarray(c) for nm, c in cols.items()},
+        jnp.asarray(bucket), KEY, lo_name=lo_name)
+    order = np.lexsort((host, bucket))
+    np.testing.assert_array_equal(np.asarray(out_bucket), bucket[order])
+    for nm, col in cols.items():
+        np.testing.assert_array_equal(np.asarray(out[nm]), col[order],
+                                      err_msg=nm)
 
 
 # ---------------------------------------------------------------------------

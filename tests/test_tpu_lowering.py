@@ -56,29 +56,11 @@ def test_lowering_rbk_fused_sort():
         count = counts[0]
         bucket = (kernels.hash32(keys) % jnp.uint32(N)).astype(jnp.int32)
         bucket = jnp.where(kernels.valid_mask(CAP, count), bucket, N)
-        cols, bucket = kernels.bucket_key_sort(cols, count, bucket, KEY)
+        cols, bucket = kernels.bucket_key_sort(cols, bucket, KEY)
         cols, count = kernels.segment_reduce_named(
             cols, count, KEY, "add", presorted=True)
         bucket = (kernels.hash32(cols[KEY])
                   % jnp.uint32(N)).astype(jnp.int32)
-        out, n2, ovf = kernels.bucket_exchange(
-            cols, count, bucket, N, 256, CAP, pregrouped=True)
-        return out[KEY], out[VALUE], n2.reshape(1), ovf.reshape(1)
-
-    _export_sharded(prog, 3, 4, _pair_args())
-
-
-def test_lowering_rbk_sort_partition():
-    def prog(counts, keys, vals):
-        cols = {KEY: keys, VALUE: vals}
-        count = counts[0]
-        cols = kernels.sort_by_column(cols, count, KEY)
-        cols, count = kernels.segment_reduce_named(
-            cols, count, KEY, "add", presorted=True)
-        bucket = (kernels.hash32(cols[KEY])
-                  % jnp.uint32(N)).astype(jnp.int32)
-        bucket = jnp.where(kernels.valid_mask(CAP, count), bucket, N)
-        cols, bucket = kernels.partition_by_bucket(cols, bucket, N)
         out, n2, ovf = kernels.bucket_exchange(
             cols, count, bucket, N, 256, CAP, pregrouped=True)
         return out[KEY], out[VALUE], n2.reshape(1), ovf.reshape(1)
@@ -227,8 +209,8 @@ def test_lowering_composed_partition_carries_mosaic_kernel():
         count = counts[0]
         bucket = (kernels.hash32(keys) % jnp.uint32(N)).astype(jnp.int32)
         bucket = jnp.where(kernels.valid_mask(CAP, count), bucket, N)
-        out, b2 = kernels.partition_by_bucket(cols, bucket, N)
-        return out[KEY], out[VALUE], b2
+        out, counts_to, _ = kernels._group_by_bucket(cols, bucket, N)
+        return out[KEY], out[VALUE], counts_to
 
     m = _export_sharded(prog, 3, 3, _pair_args())
     assert "tpu_custom_call" in m
@@ -239,9 +221,9 @@ def test_lowering_composed_partition_carries_mosaic_kernel():
         count = counts[0]
         bucket = (kernels.hash32(keys) % jnp.uint32(N)).astype(jnp.int32)
         bucket = jnp.where(kernels.valid_mask(CAP, count), bucket, N)
-        out, b2 = kernels.partition_by_bucket(cols, bucket, N,
-                                              prefer_low_memory=True)
-        return out[KEY], out[VALUE], b2
+        out, counts_to, _ = kernels._group_by_bucket(
+            cols, bucket, N, prefer_low_memory=True)
+        return out[KEY], out[VALUE], counts_to
 
     m = _export_sharded(prog_lm, 3, 3, _pair_args())
     assert "tpu_custom_call" in m
@@ -269,69 +251,6 @@ def test_lowering_wide_key_join_search():
     _export_sharded(prog, 3, 1, _pair_args())
 
 
-def test_lowering_radix_sort_carries_mosaic_kernels():
-    """The radix sort path exported for tpu must carry the Pallas digit
-    histogram + 256-bin rank kernels (platform_dependent selects them at
-    lowering) and pass Mosaic compilation, composed under shard_map."""
-    def prog(counts, keys, vals):
-        cols = {KEY: keys, VALUE: vals}
-        out = kernels.sort_by_column(cols, counts[0], KEY, impl="radix")
-        return out[KEY], out[VALUE]
-
-    m = _export_sharded(prog, 3, 2, _pair_args())
-    assert "tpu_custom_call" in m
-
-
-def test_lowering_radix_reduce_pipeline():
-    """Full reduce exchange with radix map-side + reduce-side sorts
-    lowers for tpu."""
-    def prog(counts, keys, vals):
-        cols = {KEY: keys, VALUE: vals}
-        count = counts[0]
-        cols = kernels.sort_by_column(cols, count, KEY, impl="radix")
-        cols, count = kernels.segment_reduce_named(
-            cols, count, KEY, "add", presorted=True)
-        bucket = (kernels.hash32(cols[KEY])
-                  % jnp.uint32(N)).astype(jnp.int32)
-        bucket = jnp.where(kernels.valid_mask(CAP, count), bucket, N)
-        cols, bucket = kernels.partition_by_bucket(cols, bucket, N)
-        out, n2, ovf = kernels.bucket_exchange(
-            cols, count, bucket, N, 256, CAP, pregrouped=True)
-        out, n3 = kernels.segment_reduce_named(
-            out, n2, KEY, "add", sort_impl="radix")
-        return out[KEY], out[VALUE], n3.reshape(1), ovf.reshape(1)
-
-    m = _export_sharded(prog, 3, 4, _pair_args())
-    assert "tpu_custom_call" in m
-
-
-def test_lowering_radix4_sort():
-    """The 4-bit digit variant (16-bin kernels, 8 passes/word) lowers."""
-    def prog(counts, keys, vals):
-        cols = {KEY: keys, VALUE: vals}
-        out = kernels.sort_by_column(cols, counts[0], KEY, impl="radix4")
-        return out[KEY], out[VALUE]
-
-    m = _export_sharded(prog, 3, 2, _pair_args())
-    assert "tpu_custom_call" in m
-
-
-def test_lowering_fused_radix_bucket_key_sort():
-    """The radix form of the fused (bucket, key) sort — with its narrow
-    8-bit bucket word — lowers for tpu with the Mosaic kernels."""
-    def prog(counts, keys, vals):
-        cols = {KEY: keys, VALUE: vals}
-        count = counts[0]
-        bucket = (kernels.hash32(keys) % jnp.uint32(N)).astype(jnp.int32)
-        bucket = jnp.where(kernels.valid_mask(CAP, count), bucket, N)
-        out, b2 = kernels.bucket_key_sort(cols, count, bucket, KEY,
-                                          impl="radix", n_shards=N)
-        return out[KEY], out[VALUE], b2
-
-    m = _export_sharded(prog, 3, 3, _pair_args())
-    assert "tpu_custom_call" in m
-
-
 @pytest.mark.skipif(
     os.environ.get("VEGA_LOWERING_INPROC") != "1",
     reason="runs via test_lowering_real_pipeline_programs_isolated (an "
@@ -347,7 +266,6 @@ def test_lowering_real_pipeline_programs(monkeypatch):
     production runs (fused chains, segment reduces, histograms, deferred
     exchanges, topk, zip, union — whatever the pipelines built)."""
     import vega_tpu as v
-    from vega_tpu.env import Env
     from vega_tpu.tpu import dense_rdd as dr
 
     recorded = []
@@ -369,39 +287,22 @@ def test_lowering_real_pipeline_programs(monkeypatch):
     monkeypatch.setattr(dr, "_PROGRAM_CACHE", {})
 
     ctx = v.Context("local", num_workers=2)
-    conf = Env.get().conf
-    old = (conf.dense_rbk_plan, conf.dense_sort_impl)
     try:
-        for plan, impl in (("fused_sort", "xla"),
-                           ("sort_partition", "radix"),
-                           ("sort_partition", "packed")):
-            conf.dense_rbk_plan, conf.dense_sort_impl = plan, impl
-            # A range hint banked by the previous config would send this
-            # config's cold reduce to the table plan — which ignores
-            # plan/impl — so the standard program under test would never
-            # compile (round-5 review finding). Capacity hints likewise.
-            ctx.__dict__.get("_dense_key_range_hints", {}).clear()
-            ctx.__dict__.get("_dense_capacity_hints", {}).clear()
+        def reduce_once():
+            kv = ctx.dense_range(20_000).map(lambda x: (x % 211, x * 1.0))
+            return kv, kv.reduce_by_key(op="add")
 
-            def reduce_once():
-                kv = ctx.dense_range(20_000).map(
-                    lambda x: (x % 211, x * 1.0))
-                return kv, kv.reduce_by_key(op="add")
-
-            kv, red = reduce_once()
-            table = ctx.dense_from_numpy(np.arange(211, dtype=np.int32),
-                                         np.arange(211, dtype=np.float32))
-            assert red.join(table).count() == 211
-            # Warm rerun: the speculative dense-key TABLE plan program
-            # (scatter table + psum + hash-mask compact) must lower too.
-            _, red_warm = reduce_once()
-            assert dict(red_warm.collect())
-            assert red_warm._table_plan is True
-            assert len(kv.sort_by_key(ascending=False).take(5)) == 5
-            kv.group_by_key().collect_grouped()
-            assert len(kv.take_ordered(5)) == 5
+        kv, red = reduce_once()
+        table = ctx.dense_from_numpy(np.arange(211, dtype=np.int32),
+                                     np.arange(211, dtype=np.float32))
+        assert red.join(table).count() == 211
+        # Warm rerun: the deferred (hinted-capacity) launch lowers too.
+        _, red_warm = reduce_once()
+        assert dict(red_warm.collect())
+        assert len(kv.sort_by_key(ascending=False).take(5)) == 5
+        kv.group_by_key().collect_grouped()
+        assert len(kv.take_ordered(5)) == 5
         # wide int64 values + overflow tracking
-        conf.dense_rbk_plan, conf.dense_sort_impl = old
         wide = ctx.dense_from_numpy(
             np.array([1, 1, 2], dtype=np.int64),
             np.array([2**40, 2**41, 7], dtype=np.int64))
@@ -409,7 +310,6 @@ def test_lowering_real_pipeline_programs(monkeypatch):
         bare = ctx.dense_from_numpy(np.array([2**40, 5], dtype=np.int64))
         bare.sum()
     finally:
-        conf.dense_rbk_plan, conf.dense_sort_impl = old
         ctx.stop()
 
     assert len(recorded) >= 12, len(recorded)

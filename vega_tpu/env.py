@@ -232,31 +232,6 @@ class Configuration:
     # conservative for a 16 GiB v5e chip once XLA workspace and a second
     # live block are accounted for.
     dense_hbm_budget: int = 4 << 30
-    # reduce_by_key exchange plan: "fused_sort" = ONE multi-key
-    # (bucket, key) lax.sort feeds the presorted combine AND a pregrouped
-    # exchange; "sort_partition" = key-only lax.sort -> combine -> stable
-    # counting partition by bucket (kernels.partition_by_bucket) — the
-    # partition is cheap VPU work over the POST-combine rows, so it wins
-    # when the combine shrinks data a lot (high key duplication) and the
-    # sort dominates. "auto" (round-5 default) resolves per backend from
-    # the measured evidence: sort_partition on CPU (won the A/B at both
-    # 2M and 5M bench shapes in round 5, an XLA:CPU finding), fused_sort
-    # on TPU until an on-chip A/B (benchmarks/plan_ab.py; ROADMAP
-    # Speed 3) decides: no plan has a chip measurement yet (PERF.md).
-    dense_rbk_plan: str = "auto"
-    # Key-sort implementation inside exchange programs: "xla" = lax.sort
-    # comparator network; "packed" = (key, perm) packed into one 63-bit
-    # word so the sort is XLA's fast SINGLE-operand case (its
-    # multi-operand sort is 4-8x slower at bench shapes on CPU);
-    # "radix" / "radix4" = LSD radix over orderable-uint32 words (8-bit
-    # digits / 4 passes per word, or 4-bit digits / 8 passes with 16x
-    # less per-tile kernel unroll; Pallas-streamed histogram + rank
-    # kernels on TPU) for int32/float32/wide-int64 keys — other dtypes
-    # keep lax.sort. "auto" (round-5 default) resolves per backend:
-    # packed on CPU (measured 3.8x on the dominant reduce sort at the 5M
-    # bench shape in round 5, an XLA:CPU finding), xla on TPU until an
-    # on-chip A/B (ROADMAP Speed 3) decides.
-    dense_sort_impl: str = "auto"
     # --- elastic serving plane (scheduler/elastic.py; distributed mode) ---
     # Master switch for the autoscaler control loop: the driver samples
     # load signals (arbiter queue depth, per-pool backlog, per-executor
@@ -304,13 +279,6 @@ class Configuration:
     # executor rejoins _pick_executor rotation instead of staying
     # advisory-deprioritized forever. 0 disables decay (legacy).
     blacklist_decay_s: float = 60.0
-    # Speculative dense-key table plan for warm named reduces (scatter
-    # table + psum + hash-mask compact; dense_rdd.py). "auto" (default)
-    # activates it on CPU only — measured 3-4x on the bench reduce there
-    # — and keeps TPU on the standard exchange until an on-chip A/B
-    # (benchmarks/plan_ab.py table leg; ROADMAP Speed 3) decides.
-    # "on"/"off" force it per run (the A/B sets "on").
-    dense_table_plan: str = "auto"
     # --- device-tier string columns (tpu/dict_encoding.py) ---
     # Master switch for dictionary-encoded string columns on the device
     # tier: string columns become int32 code columns plus a sorted
@@ -374,8 +342,7 @@ class Configuration:
         if env.get(pref + "DEPLOYMENT_MODE"):
             cfg.deployment_mode = DeploymentMode(env[pref + "DEPLOYMENT_MODE"])
         for name in ("LOCAL_IP", "LOCAL_DIR", "LOG_LEVEL", "DENSE_EXCHANGE",
-                     "DENSE_RBK_PLAN", "DENSE_SORT_IMPL",
-                     "DENSE_TABLE_PLAN", "HOSTS_FILE", "SPILL_DIR",
+                     "HOSTS_FILE", "SPILL_DIR",
                      "SCHEDULER_MODE", "SHUFFLE_PLAN", "SHUFFLE_CODING",
                      "ADMISSION_MODE",
                      "STREAM_BACKPRESSURE_MODE", "STREAM_POOL",

@@ -236,7 +236,7 @@ class PairOpsMixin:
         partitioner = RangePartitioner(bounds, ascending)
         shuffled = ShuffledRDD(self, Aggregator.default(), partitioner)
 
-        def sort_partition(_idx, it):
+        def sort_rows(_idx, it):
             rows = []
             for k, vs in it:
                 for v in vs:
@@ -244,7 +244,7 @@ class PairOpsMixin:
             rows.sort(key=lambda kv: kv[0], reverse=not ascending)
             return iter(rows)
 
-        return MapPartitionsRDD(shuffled, sort_partition,
+        return MapPartitionsRDD(shuffled, sort_rows,
                                 preserves_partitioning=True)
 
     # --- driver-side helpers ------------------------------------------------------

@@ -1754,27 +1754,6 @@ class DenseRDD(RDD):
         # adjacent (KEY=hi, KEY_LO=lo) columns, so lexicographic schema
         # order IS int64 order in place.
         k = min(max(n, 1), blk.capacity)
-        impl = _sort_impl()
-        # radix/packed need every column as an orderable-uint32 word
-        use_radix = impl in ("radix", "radix4", "packed") and all(
-            jnp.dtype(dt) in (jnp.dtype(jnp.int32), jnp.dtype(jnp.float32))
-            for _, dt in self._schema())
-
-        def shard_sorted_radix(counts, *cols):
-            count = counts[0]
-            # LSD = last schema column
-            words = kernels.orderable_words(list(reversed(cols)))
-            if impl == "packed":
-                order = kernels.packed_sort_perm(words, count,
-                                                 descending=largest)
-            else:
-                order = kernels.radix_sort_perm(
-                    words, count, descending=largest,
-                    bits=4 if impl == "radix4" else 8)
-            n_valid = jnp.minimum(count, k).reshape(1)
-            # original (unflipped) values, gathered once
-            return (n_valid,) + tuple(jnp.take(c, order[:k]) for c in cols)
-
         def shard_sorted(counts, *cols):
             capacity = cols[0].shape[0]
             invalid = ~kernels.valid_mask(capacity, counts[0])
@@ -1796,12 +1775,9 @@ class DenseRDD(RDD):
 
         prog = _cached_program(
             ("topk_rows", self.mesh, tuple(names), k, largest,
-             tuple(str(dt) for _, dt in self._schema()),
-             impl if use_radix else "xla"),
+             tuple(str(dt) for _, dt in self._schema())),
             lambda: _shard_program(
-                self.mesh,
-                shard_sorted_radix if use_radix else shard_sorted,
-                1 + len(names),
+                self.mesh, shard_sorted, 1 + len(names),
                 (_SPEC,) * (1 + len(names)),
             ),
         )
@@ -1821,9 +1797,8 @@ class DenseRDD(RDD):
                 return []
             merged = {nm: np.concatenate([rows[i] for rows in keep])
                       for i, nm in enumerate(names)}
-            if largest and not use_radix:
-                # un-flip (the lax.sort path returned flipped sort operands;
-                # the radix path gathers original values)
+            if largest:
+                # un-flip (the sort returned its flipped operands)
                 for nm in names:
                     col = merged[nm]
                     merged[nm] = -col if np.issubdtype(col.dtype, np.floating) \
@@ -2673,11 +2648,10 @@ class _DictUnifyRDD(_NarrowRDD):
     unified column. The staged table capacity is a REAL capacity
     (Configuration.dense_dict_capacity): a valid code at or past the
     staged prefix sets the device overflow flag — checked on the RAW
-    codes, like the dense-key table plan — and the driver retries with
-    the capacity doubled. Monotonic remap (sorted dicts in, sorted merge
-    out), so per-shard key order survives; hash placement does NOT (the
-    codes hashed into buckets changed), hence the default hash_placed
-    False."""
+    codes — and the driver retries with the capacity doubled. Monotonic
+    remap (sorted dicts in, sorted merge out), so per-shard key order
+    survives; hash placement does NOT (the codes hashed into buckets
+    changed), hence the default hash_placed False."""
 
     _chainable = False  # own program (replicated table operands)
 
@@ -3163,15 +3137,6 @@ def _apply_chain(chain, cols, count):
 
 def _chain_fp(chain) -> tuple:
     return tuple(nd._node_fp() for nd in chain)
-
-
-def _sort_impl() -> str:
-    """kernels.resolve_sort_impl — 'radix' routes the key sorts in the
-    exchange programs through the LSD radix path (Pallas-streamed passes
-    on TPU), 'packed' packs (key, perm) into one 63-bit word for XLA's
-    fast single-operand sort, 'auto' resolves per backend from measured
-    evidence (env.py dense_sort_impl note)."""
-    return kernels.resolve_sort_impl()
 
 
 def _bucket_cols(cols, n: int) -> jax.Array:
@@ -3807,7 +3772,7 @@ class _ReduceByKeyRDD(_ExchangeRDD):
     def _fp_extra(self):
         return (self._op or _fp(self._func), self.exchange_mode)
 
-    def _segment_reduce(self, cols, count, presorted, sort_impl="xla"):
+    def _segment_reduce(self, cols, count, presorted):
         lo_name = _lo_of(cols)
         if self._op is not None:
             wide = block_lib.wide_value_pairs(cols)
@@ -3823,12 +3788,10 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                     ovf_name=_SOVF if _SOVF in cols else None)
                 return kernels.segment_reduce_sorted(
                     cols, count, KEY, combine, presorted=presorted,
-                    lo_name=lo_name, sort_impl=sort_impl,
-                )
+                    lo_name=lo_name)
             return kernels.segment_reduce_named(
                 cols, count, KEY, self._op, presorted=presorted,
-                lo_name=lo_name, sort_impl=sort_impl,
-            )
+                lo_name=lo_name)
         f = self._func
         names = self._value_names
         if len(names) == 1:
@@ -3843,9 +3806,7 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                 return dict(zip(names, out))
 
         return kernels.segment_reduce_sorted(
-            cols, count, KEY, combine, presorted=presorted, lo_name=lo_name,
-            sort_impl=sort_impl,
-        )
+            cols, count, KEY, combine, presorted=presorted, lo_name=lo_name)
 
     def _host_exact_fold(self) -> Block:
         """Host-tier takeover after the device flagged a possible wide
@@ -3947,7 +3908,6 @@ class _ReduceByKeyRDD(_ExchangeRDD):
         blk = root.block_spec()  # we register our own pending entry
         in_names = list(blk.cols)
         names = [nm for nm, _ in self.parent._schema()]
-        sort_impl = _sort_impl()
         this = _detach(self)  # _segment_reduce state without the node
         # Wide int64 adds track signed overflow through the whole exchange
         # (the capacity-flag pattern applied to arithmetic): an injected
@@ -3956,164 +3916,6 @@ class _ReduceByKeyRDD(_ExchangeRDD):
         # routes to the host-exact fold (see _host_exact_fold).
         track_sovf = self._op == "add" and bool(
             block_lib.wide_value_pairs(names))
-        from vega_tpu.env import Env as _Env
-
-        # Per-backend resolution (env.py notes). A typo'd value raising
-        # (rather than silently running the default) keeps A/Bs honest —
-        # a budgeted chip run must never measure fused vs fused.
-        plan = kernels.resolve_backend_mode(
-            "dense_rbk_plan",
-            getattr(_Env.get().conf, "dense_rbk_plan", "auto"),
-            ("auto", "fused_sort", "sort_partition"),
-            "sort_partition", "fused_sort")
-
-        # ---- speculative dense-key TABLE plan (round 5) --------------
-        # When a prior run of this lineage+sizes OBSERVED a small key
-        # range [kmin, kmax] (learned for free off the standard
-        # program's output keys, riding the counts fetch), the whole
-        # reduce collapses to a per-shard scatter into a dense table +
-        # ONE psum + a per-shard hash-mask compact: no sort, no row
-        # exchange, and the output arrives hash-placed AND key-sorted.
-        # Entirely speculative and SOUND (CLAUDE.md: no value probing
-        # may select a fast path unguarded): the program flags any valid
-        # key outside the hinted range — checked on the raw key values,
-        # never via wrap-prone subtraction — or an output-capacity
-        # overflow, and a set flag settles through the normal
-        # _settle_pending repair, which re-runs under _dense_no_defer
-        # where this plan is gated off. Gated to named add/min/max over
-        # ONE narrow 32-bit value column with a single int32 key.
-        schema_d = dict(self._schema())
-        vname = (self._value_names[0]
-                 if len(self._value_names) == 1 else None)
-        # CPU-only until the on-chip A/B decides (env.py note).
-        table_mode = kernels.resolve_backend_mode(
-            "dense_table_plan",
-            getattr(_Env.get().conf, "dense_table_plan", "auto"),
-            ("auto", "on", "off"), "on", "off")
-        # Learning is gated on the mode too: with the plan off, the
-        # extra kmin/kmax outputs and their fetch would be pure dead
-        # work on every eligible reduce (cache-safe: learn_range is in
-        # the program-cache key).
-        learn_range = (
-            table_mode == "on"
-            and self._op in ("add", "min", "max") and vname is not None
-            and not track_sovf and KEY_LO not in schema_d
-            and jnp.dtype(schema_d[vname]) in (jnp.dtype(jnp.int32),
-                                               jnp.dtype(jnp.float32)))
-        range_hints = self.context.__dict__.setdefault(
-            "_dense_key_range_hints", {})
-        table_range = None
-        if learn_range and not elide \
-                and not self.context.__dict__.get("_dense_no_defer"):
-            rh = range_hints.get(self._hint_key())
-            if rh is not None:
-                kmin_h, kmax_h = rh
-                # Bucket the range so drifting hints (streamed chunks
-                # whose keys slide run to run) reuse one compiled
-                # program instead of minting a fresh _PROGRAM_CACHE
-                # entry per observed range: align kmin down to 4K and
-                # round the spread to a capacity bucket. A WIDER table
-                # is trivially sound — extra slots end with cnt==0 and
-                # emit nothing — and the range check covers the widened
-                # bounds, so it only gets laxer, never wrong.
-                kmin_b = (int(kmin_h) >> 12) << 12  # floor, sign-safe
-                spread_b = block_lib._round_capacity(
-                    int(kmax_h) - kmin_b + 1)
-                # Table work is O(spread) per shard (+ an O(spread)
-                # psum): require it comfortably under the input size and
-                # an absolute cap (32 MB of table+counts per shard).
-                if 0 < spread_b <= min(1 << 22, 2 * blk.capacity * n) \
-                        and kmin_b + spread_b - 1 <= np.iinfo(np.int32).max:
-                    table_range = (kmin_b, spread_b)
-
-        if table_range is not None:
-            kmin_c, spread = table_range
-            op = self._op
-            vdt = jnp.dtype(schema_d[vname])
-            out_cap_t = block_lib._round_capacity(
-                min(spread, int(spread / max(n, 1) * 1.3) + 128))
-
-            def table_prog(counts, *col_arrays):
-                cols = dict(zip(in_names, col_arrays))
-                cols, count = _apply_chain(chain, cols, counts[0])
-                keys = cols[KEY]
-                vals = cols[vname]
-                vdt_t = vals.dtype  # trace-time truth, never closure bake
-                cap = keys.shape[0]
-                maskv = kernels.valid_mask(cap, count)
-                in_rng = ((keys >= jnp.int32(kmin_c))
-                          & (keys <= jnp.int32(kmin_c + spread - 1)))
-                bad = jnp.any(maskv & ~in_rng)
-                ok = maskv & in_rng
-                # Dropped rows (invalid or out-of-range) scatter to the
-                # out-of-bounds slot `spread`, which mode="drop" ignores.
-                idx = jnp.where(ok, keys - jnp.int32(kmin_c),
-                                jnp.int32(spread))
-                if op == "add":
-                    tbl = jnp.zeros((spread,), vdt_t)
-                    tbl = tbl.at[idx].add(vals, mode="drop")
-                elif op == "min":
-                    init = (jnp.inf if vdt_t == jnp.dtype(jnp.float32)
-                            else jnp.iinfo(jnp.int32).max)
-                    tbl = jnp.full((spread,), init, vdt_t)
-                    tbl = tbl.at[idx].min(vals, mode="drop")
-                else:
-                    init = (-jnp.inf if vdt_t == jnp.dtype(jnp.float32)
-                            else jnp.iinfo(jnp.int32).min)
-                    tbl = jnp.full((spread,), init, vdt_t)
-                    tbl = tbl.at[idx].max(vals, mode="drop")
-                cnt = jnp.zeros((spread,), jnp.int32)
-                cnt = cnt.at[idx].add(1, mode="drop")
-                tbl = jax.lax.psum(tbl, mesh_lib.SHARD_AXIS)
-                cnt = jax.lax.psum(cnt, mesh_lib.SHARD_AXIS)
-                keys_all = jnp.int32(kmin_c) + lax.iota(jnp.int32, spread)
-                me = jax.lax.axis_index(mesh_lib.SHARD_AXIS)
-                mine = ((_bucket_cols({KEY: keys_all}, n) == me)
-                        & (cnt > 0))  # absent keys must not emit rows
-                out, out_count = kernels.compact(
-                    {KEY: keys_all, vname: tbl}, mine, out_cap_t)
-                overflow = bad | (out_count > jnp.int32(out_cap_t))
-                return (out_count.reshape(1), out[KEY], out[vname],
-                        overflow.reshape(1).astype(jnp.int32))
-
-            prog = _cached_program(
-                ("rbk_table", self.mesh, tuple(in_names), vname,
-                 str(vdt), _chain_fp(chain), n, out_cap_t, kmin_c,
-                 spread, op),
-                lambda: _shard_program(self.mesh, table_prog,
-                                       1 + len(in_names), (_SPEC,) * 4),
-            )
-            # The gate above checked _dense_no_defer, but a CONCURRENT
-            # thread's settlement repair may have set it since: re-check
-            # immediately before launch and fall through to the standard
-            # plan if so. Without this, _run_exchange would take its
-            # blocking retry loop, whose grown capacities this build
-            # lambda ignores — six identical fixed-caps launches ending in
-            # a spurious VegaError instead of a plan fallback.
-            if self.context.__dict__.get("_dense_no_defer"):
-                table_range = None
-        if table_range is not None:
-            # The gate guarantees _dense_no_defer is off, so this is
-            # exactly _run_exchange's deferred fixed-caps launch — bus
-            # events, the pending entry, and settlement/repair all ride
-            # the shared choreography (a failed flag repairs through the
-            # standard plan: the rerun holds _dense_no_defer).
-            self._fetch_extra_outs = 0
-            self._elided = False
-            self._table_plan = True  # observability/tests
-            outs_t, _ = self._run_exchange(
-                lambda slot, cap: (
-                    prog, (blk.counts,
-                           *[blk.cols[nm] for nm in in_names])),
-                lambda: blk.counts_np,
-                fixed_caps=(0, out_cap_t),
-            )
-            t_counts, t_keys, t_vals = outs_t
-            return self._attach_pending(Block(
-                cols={KEY: t_keys, vname: t_vals}, counts=t_counts,
-                capacity=out_cap_t, mesh=self.mesh,
-                counts_host=self._last_counts_host))
-        self._table_plan = False
 
         def build(slot, out_cap):
             exchange, x_tok = ((kernels.bucket_exchange, _X_ELIDED)
@@ -4126,33 +3928,7 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                 cols, count = _apply_chain(chain, cols, counts[0])
                 if track_sovf:
                     cols[_SOVF] = jnp.zeros(cols[KEY].shape[0], jnp.int32)
-                if n > 1 and not elide and plan == "sort_partition":
-                    # Alternative plan: key-only sort -> presorted
-                    # map-side combine -> stable counting partition of
-                    # the (often much smaller) combined rows. Equal keys
-                    # share a bucket by hash determinism, so combining
-                    # across bucket boundaries is safe.
-                    cols = kernels.sort_by_column(
-                        cols, count, KEY, lo_name=_lo_of(cols),
-                        impl=sort_impl)
-                    cols, count = this._segment_reduce(
-                        cols, count, presorted=True, sort_impl=sort_impl)
-                    capacity = cols[KEY].shape[0]
-                    mask = kernels.valid_mask(capacity, count)
-                    bucket = _bucket_cols(cols, n)
-                    bucket = jnp.where(mask, bucket, n)
-                    # counting-path intermediates are O(capacity * n):
-                    # bound them (~256 MiB) on big blocks via the argsort
-                    # escape so the plan can't OOM where fused_sort won't
-                    low_mem = capacity * (n + 1) * 4 > (256 << 20)
-                    cols, bucket = kernels.partition_by_bucket(
-                        cols, bucket, n, prefer_low_memory=low_mem,
-                        sort_impl=sort_impl)
-                    cols, count, overflow = exchange(
-                        cols, count, bucket, n, slot, out_cap,
-                        pregrouped=True,
-                    )
-                elif n > 1 and not elide:
+                if n > 1 and not elide:
                     # 2-sort exchange: ONE multi-key sort (bucket major,
                     # key minor) feeds both the presorted map-side combine
                     # (reference: dependency.rs:176-223) and a pregrouped
@@ -4163,11 +3939,9 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                     bucket = _bucket_cols(cols, n)
                     bucket = jnp.where(mask, bucket, n)
                     cols, bucket = kernels.bucket_key_sort(
-                        cols, count, bucket, KEY, lo_name=_lo_of(cols),
-                        impl=sort_impl, n_shards=n,
-                    )
+                        cols, bucket, KEY, lo_name=_lo_of(cols))
                     cols, count = this._segment_reduce(
-                        cols, count, presorted=True, sort_impl=sort_impl)
+                        cols, count, presorted=True)
                     # compact kept (bucket, key) order; re-derive the
                     # combiner rows' buckets from their keys (hash is cheap
                     # and deterministic).
@@ -4179,9 +3953,7 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                 elif not elide:
                     bucket = jnp.zeros_like(cols[KEY])
                     cols, count, overflow = exchange(
-                        cols, count, bucket, n, slot, out_cap,
-                        sort_impl=sort_impl,
-                    )
+                        cols, count, bucket, n, slot, out_cap)
                 else:
                     capacity = cols[KEY].shape[0]
                     cols, count, overflow = kernels.passthrough_exchange(
@@ -4189,26 +3961,12 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                     )
                 # reduce-side merge (reference: shuffled_rdd.rs:149-170)
                 cols, count = this._segment_reduce(
-                    cols, count, presorted=elide_sorted,
-                    sort_impl=sort_impl)
+                    cols, count, presorted=elide_sorted)
                 res = (count.reshape(1),)
                 if track_sovf:
                     m = kernels.valid_mask(cols[_SOVF].shape[0], count)
                     sovf = jnp.any(jnp.where(m, cols[_SOVF], 0) != 0)
                     res += (sovf.reshape(1).astype(jnp.int32),)
-                if learn_range:
-                    # Observed key range of the OUTPUT (same min/max as
-                    # the input keys), riding the counts fetch for free:
-                    # feeds the table plan's hint for the next warm run.
-                    mo = kernels.valid_mask(cols[KEY].shape[0], count)
-                    res += (
-                        jnp.min(jnp.where(
-                            mo, cols[KEY],
-                            jnp.iinfo(jnp.int32).max)).reshape(1),
-                        jnp.max(jnp.where(
-                            mo, cols[KEY],
-                            jnp.iinfo(jnp.int32).min)).reshape(1),
-                    )
                 return res + tuple(
                     cols[nm] for nm in names
                 ) + (overflow.reshape(1),)
@@ -4216,13 +3974,12 @@ class _ReduceByKeyRDD(_ExchangeRDD):
             key = ("rbk", self.mesh, tuple(in_names), tuple(names),
                    _chain_fp(chain), n, slot, out_cap, elide, elide_sorted,
                    self.exchange_mode, x_tok, self._op or _fp(self._func),
-                   track_sovf, learn_range, plan, sort_impl)
+                   track_sovf)
             prog = _cached_program(
                 key,
                 lambda: _shard_program(
                     self.mesh, prog_fn, 1 + len(in_names),
-                    (_SPEC,) * (2 + track_sovf + 2 * learn_range
-                                + len(names)),
+                    (_SPEC,) * (2 + track_sovf + len(names)),
                 ),
             )
             return prog, (blk.counts, *[blk.cols[nm] for nm in in_names])
@@ -4232,36 +3989,17 @@ class _ReduceByKeyRDD(_ExchangeRDD):
         # counts are already host-known, else the parent's capacity —
         # never a fetch. Slot is unused by the passthrough.
         self._elided = elide
-        # sovf / learned-key-range ride the (counts, overflow) transfer;
-        # deferred launches re-check sovf at settlement via validate.
-        extra_n = (1 if track_sovf else 0) + (2 if learn_range else 0)
+        # sovf rides the (counts, overflow) transfer; deferred launches
+        # re-check it at settlement via validate.
+        extra_n = int(track_sovf)
         self._fetch_extra_outs = extra_n
         validate = ((lambda head: not bool(np.any(np.asarray(head[1]))))
                     if track_sovf else None)
-
-        def bank_range(lo_arr, hi_arr):
-            # Per-shard sentinels (empty shards report int32 max/min)
-            # fall out of the global min/max.
-            kmin_o = int(np.asarray(lo_arr).min())
-            kmax_o = int(np.asarray(hi_arr).max())
-            if kmin_o <= kmax_o:
-                hk = self._hint_key()
-                range_hints.pop(hk, None)
-                range_hints[hk] = (kmin_o, kmax_o)
-                while len(range_hints) > 4096:
-                    range_hints.pop(next(iter(range_hints)))
-
-        # Deferred launches bank the range at settlement commit —
-        # without this, an evicted range hint under a live capacity hint
-        # would pay for the two extra outputs forever while the table
-        # plan never re-activates.
-        on_success = ((lambda head: bank_range(head[-2], head[-1]))
-                      if learn_range else None)
         if elide:
             outs, out_cap = self._run_exchange(
                 build, lambda: blk.counts_np,
                 fixed_caps=(0, _elide_out_cap(blk)),
-                validate=validate, on_success=on_success,
+                validate=validate,
             )
         else:
             outs, out_cap = self._run_exchange(
@@ -4269,7 +4007,7 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                 make_hists=lambda: ([self._hash_histogram(blk, chain)],
                                     None),
                 hint_key=self._hint_key(),
-                validate=validate, on_success=on_success,
+                validate=validate,
             )
         counts, col_arrays = outs[0], outs[1 + extra_n:]
         extra = self._last_extra_host
@@ -4277,9 +4015,6 @@ class _ReduceByKeyRDD(_ExchangeRDD):
             # Blocking path saw the flag inline (the deferred path
             # reaches here via _settle_pending's repair rerun).
             return self._host_exact_fold()
-        if learn_range and extra is not None and len(extra) >= 2:
-            # Blocking path: bank inline (deferred banks via on_success).
-            bank_range(extra[-2], extra[-1])
         return self._attach_pending(Block(
             cols=dict(zip(names, col_arrays)), counts=counts,
             capacity=out_cap, mesh=self.mesh,
@@ -4315,7 +4050,6 @@ class _GroupByKeyRDD(_ExchangeRDD):
         blk = root.block_spec()  # we register our own pending entry
         in_names = list(blk.cols)
         names = [nm for nm, _ in self.parent._schema()]
-        sort_impl = _sort_impl()
 
         def build(slot, out_cap):
             exchange, x_tok = ((kernels.bucket_exchange, _X_ELIDED)
@@ -4334,20 +4068,17 @@ class _GroupByKeyRDD(_ExchangeRDD):
                     bucket = (_bucket_cols(cols, n)
                               if n > 1 else jnp.zeros_like(cols[KEY]))
                     cols, count, overflow = exchange(
-                        cols, count, bucket, n, slot, out_cap,
-                        sort_impl=sort_impl,
-                    )
+                        cols, count, bucket, n, slot, out_cap)
                 if not elide_sorted:  # already sorted rows skip the sort
                     cols = kernels.sort_by_column(cols, count, KEY,
-                                                  lo_name=_lo_of(cols),
-                                                  impl=sort_impl)
+                                                  lo_name=_lo_of(cols))
                 return (count.reshape(1),) + tuple(
                     cols[nm] for nm in names
                 ) + (overflow.reshape(1),)
 
             key = ("gbk", self.mesh, tuple(in_names), tuple(names),
                    _chain_fp(chain), n, slot, out_cap, elide,
-                   elide_sorted, self.exchange_mode, x_tok, sort_impl)
+                   elide_sorted, self.exchange_mode, x_tok)
             prog = _cached_program(
                 key,
                 lambda: _shard_program(
@@ -4479,7 +4210,6 @@ class _JoinRDD(_ExchangeRDD):
         l_chain = _detached_chain(l_chain)
         r_chain = _detached_chain(r_chain)
         outer, fill_value = self.outer, self.fill_value
-        sort_impl = _sort_impl()
         lblk = l_root.block_spec()  # we register our own pending entry
         rblk = r_root.block_spec()
         l_in = list(lblk.cols)
@@ -4506,8 +4236,7 @@ class _JoinRDD(_ExchangeRDD):
                 )
             bucket = (_bucket_cols(cols, n)
                       if n > 1 else jnp.zeros_like(cols[KEY]))
-            return exchange(cols, count, bucket, n, slot_pair, out_cap,
-                            sort_impl=sort_impl)
+            return exchange(cols, count, bucket, n, slot_pair, out_cap)
 
         def build(slot_pair, out_cap):
             join_cap = join_cap_override[0] or out_cap
@@ -4539,7 +4268,7 @@ class _JoinRDD(_ExchangeRDD):
                     lcols, lcount, rcols, rcount, KEY, join_cap,
                     outer=outer, fill_value=fill_value,
                     left_sorted=l_sorted, right_sorted=r_sorted,
-                    lo_name=lo_name, sort_impl=sort_impl,
+                    lo_name=lo_name,
                 )
                 return (
                     jcount.reshape(1), jtotal.reshape(1),
@@ -4555,7 +4284,7 @@ class _JoinRDD(_ExchangeRDD):
                  slot_pair, out_cap,
                  join_cap, l_elide, r_elide, l_sorted, r_sorted,
                  self.exchange_mode, x_tok, self.outer,
-                 repr(self.fill_value), sort_impl),
+                 repr(self.fill_value)),
                 lambda: _shard_program(
                     self.mesh, prog_fn, 2 + len(l_in) + len(r_in),
                     (_SPEC,) * (3 + len(key_names) + n_vals)),
@@ -4768,7 +4497,6 @@ class _SortByKeyRDD(_ExchangeRDD):
             bounds_dev = mesh_lib.host_put(bounds, repl)
             bounds_lo_dev = None
         ascending = self.ascending
-        sort_impl = _sort_impl()
 
         def build(slot, out_cap):
             exchange, x_tok = self._resolve_exchange((blk,), slot, out_cap)
@@ -4790,20 +4518,17 @@ class _SortByKeyRDD(_ExchangeRDD):
                         keys_lo=cols.get(lo_name) if composite else None,
                     )
                 cols, count, overflow = exchange(
-                    cols, count, bucket, n, slot, out_cap,
-                    sort_impl=sort_impl,
-                )
+                    cols, count, bucket, n, slot, out_cap)
                 cols = kernels.sort_by_column(
                     cols, count, KEY, descending=not ascending,
-                    lo_name=lo_name, impl=sort_impl,
-                )
+                    lo_name=lo_name)
                 return (count.reshape(1),) + tuple(
                     cols[nm] for nm in names
                 ) + (overflow.reshape(1),)
 
             key = ("sort", self.mesh, tuple(in_names), tuple(names),
                    _chain_fp(chain), n, slot, out_cap,
-                   ascending, self.exchange_mode, x_tok, sort_impl)
+                   ascending, self.exchange_mode, x_tok)
             prog = _cached_program(
                 key,
                 lambda: _shard_program(

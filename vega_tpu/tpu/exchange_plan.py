@@ -251,8 +251,7 @@ def plan_exchange(n_shards: int, capacity: int, slot_capacity: int,
 def exchange_callable(plan: ExchangePlan):
     """The exchange implementation for a plan, with the staged group
     bound — a drop-in for the (cols, count, bucket, n_shards, slot,
-    out_capacity, pregrouped=, sort_impl=) call shape every exchange
-    site uses."""
+    out_capacity, pregrouped=) call shape every exchange site uses."""
     if plan.program == "ring":
         from vega_tpu.tpu.ring import ring_exchange
 

@@ -134,8 +134,7 @@ def passthrough_exchange(cols: Cols, count: jax.Array, capacity: int,
 
 
 def _group_by_bucket(cols: Cols, bucket: jax.Array, n_shards: int,
-                     prefer_low_memory: bool = False,
-                     sort_impl: str = None):
+                     prefer_low_memory: bool = False):
     """Stable-group rows by target bucket; returns (grouped cols,
     per-bucket counts, per-bucket start offsets).
 
@@ -144,13 +143,7 @@ def _group_by_bucket(cols: Cols, bucket: jax.Array, n_shards: int,
     beats the O(n log n) argsort. The one-hot/cumsum intermediates are
     O(capacity * n_shards), so callers with a memory bound to honor
     (ring_exchange) set prefer_low_memory and larger meshes always take the
-    argsort path.
-
-    sort_impl is the caller's RESOLVED dense_sort_impl — cached-program
-    builders must thread the exact value that sits in their program-cache
-    key (exchange/partition_by_bucket forward it), so an in-process config
-    flip re-traces instead of silently A/B-ing a stale cached program.
-    None (direct/uncached callers only) resolves from the live config."""
+    argsort path."""
     from vega_tpu.tpu import pallas_kernels as _pk
 
     counts_all = _pk.bucket_hist(bucket, n_shards + 1)
@@ -177,26 +170,15 @@ def _group_by_bucket(cols: Cols, bucket: jax.Array, n_shards: int,
                 dst = jnp.zeros((capacity,) + col.shape[1:], col.dtype)
                 grouped[name] = dst.at[pos].set(col, mode="drop")
             return grouped, counts_to, starts
-    # Escape hatch (>64 buckets, or low-memory without the Pallas path).
-    # Honors dense_sort_impl: 'packed' (and CPU 'auto') takes the
-    # single-operand packed sort by bucket — same stable order as the
-    # argsort at a fraction of the comparator cost; anything else keeps
-    # the argsort so a pinned 'xla' (the unmeasured-on-chip-packed TPU
-    # default) never executes packed code. Every row participates;
-    # padding rows carry bucket == n_shards and sort last by value.
-    if (sort_impl if sort_impl is not None
-            else resolve_sort_impl()) == "packed":
-        order = packed_sort_perm(orderable_words([bucket]),
-                                 jnp.int32(bucket.shape[0]))
-    else:
-        order = jnp.argsort(bucket, stable=True)
+    # Escape hatch (>64 buckets, or low-memory without the Pallas path):
+    # a stable argsort by bucket. Every row participates; padding rows
+    # carry bucket == n_shards and sort last by value.
+    order = jnp.argsort(bucket, stable=True)
     return gather_rows(cols, order), counts_to, starts
 
 
-def bucket_key_sort(cols: Cols, count: jax.Array, bucket: jax.Array,
-                    key_name: str, lo_name: str = None,
-                    impl: str = "xla",
-                    n_shards: int = None) -> Tuple[Cols, jax.Array]:
+def bucket_key_sort(cols: Cols, bucket: jax.Array, key_name: str,
+                    lo_name: str = None) -> Tuple[Cols, jax.Array]:
     """One stable multi-key sort by (bucket major, key minor).
 
     Rows become bucket-grouped with a key-sorted run per bucket, so a single
@@ -207,39 +189,8 @@ def bucket_key_sort(cols: Cols, count: jax.Array, bucket: jax.Array,
     ghosted invalid rows (bucket = n_shards) so they sink to the end.
     lo_name names the low word of a two-column int64 key (block.py KEY_LO):
     it joins the sort keys so runs are sorted by the full 64-bit key.
-    Returns (cols, bucket), both permuted.
-
-    impl='radix'/'radix4': the LSD radix form — key word passes plus ONE
-    narrow pass for the bucket as the most significant word (8-bit
-    buckets; n_shards tells the radix path the bucket range, and values
-    past 254 keep lax.sort)."""
+    Returns (cols, bucket), both permuted."""
     capacity = bucket.shape[0]
-    key = cols[key_name]
-    if impl.startswith("radix") and n_shards is not None \
-            and n_shards < 255 \
-            and (lo_name is not None or _radix_supported(key)):
-        # bucket values (incl. the ghost n_shards) fit the 8-bit word
-        key_cols = ([cols[lo_name], key] if lo_name is not None
-                    else [key])
-        words = orderable_words(key_cols)
-        word_bits = [32] * len(words)
-        words.append(lax.bitcast_convert_type(bucket, jnp.uint32))
-        word_bits.append(8)
-        order = radix_sort_perm(words, count, bits=4 if impl == "radix4"
-                                else 8, word_bits=word_bits)
-        out = gather_rows(cols, order)
-        return out, jnp.take(bucket, order)
-    if impl == "packed" and (lo_name is not None or _radix_supported(key)):
-        # LSD packed passes: key word(s) then the bucket as the most
-        # significant word — one fast single-operand sort per word
-        # instead of one slow multi-operand comparator sort.
-        key_cols = ([cols[lo_name], key] if lo_name is not None
-                    else [key])
-        words = orderable_words(key_cols)
-        words.append(_orderable_u32(bucket, False))
-        order = packed_sort_perm(words, count)
-        out = gather_rows(cols, order)
-        return out, jnp.take(bucket, order)
     perm_src = lax.iota(jnp.int32, capacity)
     if lo_name is None:
         sorted_bucket, sorted_key, perm = lax.sort(
@@ -257,211 +208,6 @@ def bucket_key_sort(cols: Cols, count: jax.Array, bucket: jax.Array,
     )
     out.update(sorted_keys)  # already produced by the sort; skip gathers
     return out, sorted_bucket
-
-
-def _orderable_u32(word: jax.Array, is_float: bool) -> jax.Array:
-    """Map a 32-bit word to uint32 whose UNSIGNED order equals the source
-    order: ints flip the sign bit; floats use the sign-magnitude flip
-    (negative floats reverse). Radix digit source."""
-    u = lax.bitcast_convert_type(word, jnp.uint32)
-    if is_float:
-        mask = jnp.where((u >> jnp.uint32(31)) != jnp.uint32(0),
-                         jnp.uint32(0xFFFFFFFF), jnp.uint32(0x80000000))
-        return u ^ mask
-    return u ^ jnp.uint32(0x80000000)
-
-
-def radix_sort_perm(words, count: jax.Array,
-                    descending: bool = False, bits: int = 8,
-                    word_bits=None) -> jax.Array:
-    """Stable LSD radix sort permutation over orderable-uint32 words
-    (LEAST significant word first); ghost rows (index >= count) sink to
-    the end. Each pass streams the digits once through the Pallas
-    histogram + rank kernels on TPU (XLA equivalents elsewhere via
-    lax.platform_dependent) and scatters only the still-needed words +
-    the permutation — payload columns move ONCE, via the returned perm:
-    output row j should be source row perm[j] (gather_rows semantics,
-    same contract as the argsort order in sort_by_column).
-
-    word_bits optionally gives each word's significant width (default 32
-    each): a bucket id carried as the MOST significant word costs one
-    8-bit pass instead of four — the radix form of the fused
-    (bucket, key) multi-key sort. Narrow words must be value-bounded by
-    their width; descending requires full-width words (the flip is ~w)."""
-    from vega_tpu.tpu import pallas_kernels as pk
-
-    if word_bits is None:
-        word_bits = [32] * len(words)
-    assert not (descending and any(b != 32 for b in word_bits))
-    cap = words[0].shape[0]
-    mask = valid_mask(cap, count)
-    active = []
-    for w, wb in zip(words, word_bits):
-        if descending:
-            w = ~w
-        # ghosts get the max significant value EVERY pass: they start
-        # last and stay last under stability
-        active.append(jnp.where(mask, w, jnp.uint32((1 << wb) - 1)))
-    widths = list(word_bits)
-    perm = lax.iota(jnp.int32, cap)
-    n_bins = 1 << bits
-    digit_mask = jnp.uint32(n_bins - 1)
-    while active:
-        word = active[0]
-        for shift in range(0, widths[0], bits):
-            d = ((word >> jnp.uint32(shift))
-                 & digit_mask).astype(jnp.int32)
-            hist = pk.radix_hist(d, n_bins)
-            starts = (jnp.cumsum(hist) - hist).astype(jnp.int32)
-            pos = pk.radix_pos(d, starts, n_bins)
-            # pos is a full permutation (every digit in range): scatter
-            # the still-needed words + perm
-            active = [jnp.zeros_like(a).at[pos].set(a) for a in active]
-            perm = jnp.zeros_like(perm).at[pos].set(perm)
-            word = active[0]
-        active = active[1:]  # this word's digits are consumed
-        widths = widths[1:]
-    return perm
-
-
-def orderable_words(cols) -> list:
-    """[_orderable_u32(c)] for a sequence of 32-bit columns — the shared
-    radix word construction (sort_by_column, bucket_key_sort, and the
-    take_ordered row sort all build word lists from columns; one site
-    keeps the orderable encoding in lockstep)."""
-    return [_orderable_u32(c, jnp.issubdtype(c.dtype, jnp.floating))
-            for c in cols]
-
-
-def _radix_supported(key: jax.Array) -> bool:
-    return key.dtype in (jnp.dtype(jnp.int32), jnp.dtype(jnp.float32))
-
-
-def resolve_backend_mode(name: str, value: str, allowed: tuple,
-                         cpu_choice: str, other_choice: str) -> str:
-    """Shared resolver for the per-backend 'auto' config knobs
-    (dense_sort_impl, dense_rbk_plan, dense_table_plan): validate the
-    string, then resolve 'auto' from the measured evidence — one choice
-    on CPU, the conservative choice elsewhere until the queued on-chip
-    A/Bs decide (env.py notes). Safe to ask the backend here: resolution
-    happens at trace/materialize time, inside device work."""
-    from vega_tpu.errors import VegaError
-
-    if value not in allowed:
-        raise VegaError(
-            f"{name} must be one of {', '.join(repr(a) for a in allowed)};"
-            f" got {value!r}")
-    if value == "auto":
-        return (cpu_choice if jax.default_backend() == "cpu"
-                else other_choice)
-    return value
-
-
-def resolve_sort_impl() -> str:
-    """Configuration.dense_sort_impl, validated and with 'auto' resolved
-    per backend (packed on CPU — measured 3.8x on the dominant sort at
-    bench shapes; xla on TPU until the queued on-chip A/B decides, see
-    env.py). Read at trace time; callers put the resolved value in their
-    program-cache keys. Lives here (not dense_rdd) so kernel-internal
-    sort choices honor the same setting."""
-    from vega_tpu.env import Env
-
-    return resolve_backend_mode(
-        "dense_sort_impl",
-        getattr(Env.get().conf, "dense_sort_impl", "auto"),
-        ("auto", "xla", "packed", "radix", "radix4"), "packed", "xla")
-
-
-def packed_sort_perm(words, count: jax.Array,
-                     descending: bool = False) -> jax.Array:
-    """Stable sort permutation over orderable-uint32 words via
-    SINGLE-OPERAND int64 sorts of (word << 31 | position).
-
-    XLA:CPU's multi-operand comparator sort is 4-8x slower than its
-    single-operand sort at bench shapes (5M rows: sort_key_val 2.01s,
-    3-operand 2.69s, packed 0.53s on this sandbox's CPU in round 5), so
-    packing the key and the permutation into one 63-bit word turns the
-    sort+permutation problem into the fast single-column case. The
-    position in the low 31 bits is also the stability tie-break. Words
-    are LSD-first like radix_sort_perm (wide int64 keys: [lo, hi]);
-    multi-word keys run one stable packed pass per word. Invalid rows
-    (position >= count) sort last (their word is forced to the max;
-    among max-ties the position tie-break keeps valid rows - which
-    always occupy lower positions - in front). int64 exists only inside
-    the scoped enable_x64 (the block dtype contract stays 32-bit).
-
-    Requires capacity < 2^31 (position must fit 31 bits) — HBM bounds
-    any real shard far below that."""
-    capacity = words[0].shape[0]
-    if capacity >= (1 << 31):
-        raise ValueError("packed_sort_perm: capacity must fit 31 bits")
-    mask = valid_mask(capacity, count)
-    order = None
-    with jax.enable_x64():
-        idx0 = lax.iota(jnp.int64, capacity)
-        for wi, w in enumerate(words):  # LSD -> MSD: one stable pass/word
-            if descending:
-                w = ~w
-            w = jnp.where(mask, w, jnp.uint32(0xFFFFFFFF))
-
-            def one_pass(w=w, order=order):
-                wp = (w if order is None
-                      else jnp.take(w, order, axis=0))
-                # Dtype-explicit lax ops: scalar int64 literals (jnp.int64(31))
-                # canonicalize to int32 tensors on jax < 0.5 even inside the
-                # enable_x64 scope, which fails stablehlo verification for
-                # shift_left — broadcast + convert is identical HLO on
-                # current jax and correct on both.
-                wp64 = lax.convert_element_type(wp, jnp.int64)
-                shift = lax.convert_element_type(
-                    jnp.full(wp.shape, 31, jnp.int32), jnp.int64)
-                lowmask = lax.convert_element_type(
-                    jnp.full(wp.shape, 0x7FFFFFFF, jnp.int32), jnp.int64)
-                packed = lax.bitwise_or(lax.shift_left(wp64, shift), idx0)
-                sw = lax.sort(packed)
-                pos = lax.convert_element_type(
-                    lax.bitwise_and(sw, lowmask), jnp.int32)
-                return (pos if order is None
-                        else jnp.take(order, pos, axis=0))
-
-            if wi == 0:
-                order = one_pass()
-                continue
-            # More-significant words are often CONSTANT across the valid
-            # rows (wide int64 ids in a narrow band: the hi word of
-            # BIG + small keys) — the pass would change nothing: valid
-            # rows all tie (stable keeps the prior order) and ghosts,
-            # already last with forced-max words, stay last. Skip it at
-            # RUNTIME via cond, halving the sort cost for that shape.
-            wmin = jnp.min(jnp.where(mask, w, jnp.uint32(0xFFFFFFFF)))
-            wmax = jnp.max(jnp.where(mask, w, jnp.uint32(0)))
-            order = lax.cond(wmin >= wmax,  # empty shards skip too
-                             lambda order=order: order,
-                             one_pass)
-    return order
-
-
-def partition_by_bucket(cols: Cols, bucket: jax.Array, n_shards: int,
-                        prefer_low_memory: bool = False,
-                        sort_impl: str = None
-                        ) -> Tuple[Cols, jax.Array]:
-    """Stable counting partition: rows become contiguous per bucket (the
-    ghost bucket n_shards sinks last), preserving in-bucket row order —
-    the sort-free way to feed a pregrouped exchange when rows are already
-    key-sorted. This is the 'sort_partition' reduce plan's grouping step:
-    key-only lax.sort -> map-side combine -> THIS, versus the fused
-    plan's multi-key (bucket, key) lax.sort over all pre-combine rows.
-
-    The counting path's one-hot/cumsum intermediates are O(capacity *
-    n_shards) — capacity is the STATIC pre-combine size, not the shrunk
-    row count — so callers bound it with prefer_low_memory (the
-    _group_by_bucket escape hatch: a single-key stable argsort by bucket
-    instead). Returns (grouped cols, grouped bucket)."""
-    grouped, _cto, _starts = _group_by_bucket(
-        dict(cols, __bucket=bucket), bucket, n_shards,
-        prefer_low_memory=prefer_low_memory, sort_impl=sort_impl)
-    b = grouped.pop("__bucket")
-    return grouped, b
 
 
 def range_bucket(bounds: jax.Array, keys: jax.Array,
@@ -507,7 +253,6 @@ def bucket_exchange(
     slot_capacity: int,  # C: max rows this shard sends to any one target
     out_capacity: int,  # per-shard capacity of the received block
     pregrouped: bool = False,  # rows already bucket-grouped (bucket_key_sort)
-    sort_impl: str = None,  # caller's resolved dense_sort_impl (cache-keyed)
 ) -> Tuple[Cols, jax.Array, jax.Array]:
     """All-to-all by bucket id. Returns (cols, new_count, overflow_flag).
 
@@ -530,7 +275,7 @@ def bucket_exchange(
         sorted_cols = cols
     else:
         sorted_cols, counts_to, starts = _group_by_bucket(
-            cols, bucket, n_shards, sort_impl=sort_impl)
+            cols, bucket, n_shards)
     overflow_send = jnp.any(counts_to > slot_capacity)
 
     # Build [n_shards, slot_capacity] send buffers per column.
@@ -567,30 +312,10 @@ def bucket_exchange(
 
 
 def sort_by_column(cols: Cols, count: jax.Array, key_name: str,
-                   descending: bool = False, lo_name: str = None,
-                   impl: str = "xla") -> Cols:
+                   descending: bool = False, lo_name: str = None) -> Cols:
     """Stable sort valid rows by one column (or a (key, lo) two-column
-    int64 key when lo_name is given); invalid rows sink to the end.
-    impl='radix' (Configuration.dense_sort_impl) uses the LSD radix path
-    for int32/float32/wide keys — Pallas-streamed passes on TPU instead
-    of lax.sort's comparator network; impl='packed' packs (key, perm)
-    into one 63-bit word so the sort is XLA's fast single-operand case
-    (packed_sort_perm). Unsupported dtypes keep lax.sort."""
+    int64 key when lo_name is given); invalid rows sink to the end."""
     key = cols[key_name]
-    if impl in ("radix", "radix4", "packed") and (
-            lo_name is not None or _radix_supported(key)):
-        if lo_name is not None:
-            # wide int64: stored lo's signed order == true-lo unsigned
-            # order, so the plain int transform applies to both words
-            words = orderable_words([cols[lo_name], key])
-        else:
-            words = orderable_words([key])
-        if impl == "packed":
-            order = packed_sort_perm(words, count, descending)
-        else:
-            order = radix_sort_perm(words, count, descending,
-                                    bits=4 if impl == "radix4" else 8)
-        return gather_rows(cols, order)
     capacity = key.shape[0]
     mask = valid_mask(capacity, count)
     if lo_name is not None:
@@ -684,7 +409,6 @@ def segment_reduce_sorted(
     combine: Callable,  # (value_cols_a, value_cols_b) -> value_cols
     presorted: bool = False,
     lo_name: str = None,
-    sort_impl: str = "xla",
 ) -> Tuple[Cols, jax.Array]:
     """Generic reduce_by_key over a shard: sort by key, then a segmented
     associative scan with an arbitrary traceable combiner; the last row of
@@ -697,8 +421,7 @@ def segment_reduce_sorted(
     of chasing hash buckets."""
     capacity = cols[key_name].shape[0]
     if not presorted:
-        cols = sort_by_column(cols, count, key_name, lo_name=lo_name,
-                              impl=sort_impl)
+        cols = sort_by_column(cols, count, key_name, lo_name=lo_name)
     mask = valid_mask(capacity, count)
     keys = cols[key_name]
     first = jnp.concatenate([
@@ -785,15 +508,14 @@ def _segment_totals_blocked(vals: jax.Array, first: jax.Array,
 
 def segment_reduce_named(
     cols: Cols, count: jax.Array, key_name: str, op: str,
-    presorted: bool = False, lo_name: str = None, sort_impl: str = "xla",
+    presorted: bool = False, lo_name: str = None,
 ) -> Tuple[Cols, jax.Array]:
     """Fast path for the common monoids via XLA segment ops. lo_name names
     the low word of a two-column int64 key (sorts/segments with the key)."""
     seg_op = _FAST_SEGMENT_OPS[op]
     capacity = cols[key_name].shape[0]
     if not presorted:
-        cols = sort_by_column(cols, count, key_name, lo_name=lo_name,
-                              impl=sort_impl)
+        cols = sort_by_column(cols, count, key_name, lo_name=lo_name)
     mask = valid_mask(capacity, count)
     keys = cols[key_name]
     first = jnp.concatenate(
@@ -935,7 +657,6 @@ def merge_join_expand(
     left_sorted: bool = False,   # caller guarantees valid-prefix + sorted
     right_sorted: bool = False,
     lo_name: str = None,         # low word of a two-column int64 key
-    sort_impl: str = "xla",
 ) -> Tuple[Cols, jax.Array, jax.Array]:
     """General sort-merge join with duplicate keys on BOTH sides.
 
@@ -960,11 +681,10 @@ def merge_join_expand(
     lcap = left[key_name].shape[0]
     rcap = right[key_name].shape[0]
     if not left_sorted:
-        left = sort_by_column(left, left_count, key_name, lo_name=lo_name,
-                              impl=sort_impl)
+        left = sort_by_column(left, left_count, key_name, lo_name=lo_name)
     if not right_sorted:
         right = sort_by_column(right, right_count, key_name,
-                               lo_name=lo_name, impl=sort_impl)
+                               lo_name=lo_name)
     lmask = valid_mask(lcap, left_count)
     rmask = valid_mask(rcap, right_count)
     lkeys = left[key_name]

@@ -75,8 +75,7 @@ def hash_bucket(keys: jax.Array, n_buckets: int) -> jax.Array:
 # counting-partition rank kernel
 # ---------------------------------------------------------------------------
 #
-# The stable counting partition (kernels._group_by_bucket, and through it
-# partition_by_bucket / the sort_partition reduce plan) needs, per row,
+# The stable counting partition (kernels._group_by_bucket) needs, per row,
 # pos = starts[bucket] + (# earlier rows with the same bucket). The XLA
 # formulation materializes a [capacity, n_buckets+1] one-hot plus its
 # column cumsum in HBM — O(capacity * k) reads+writes. This kernel streams
@@ -238,29 +237,6 @@ def bucket_hist(bucket: jax.Array, n_bins: int) -> jax.Array:
         bucket,
         tpu=lambda b: digit_hist_pallas(b, n_bins),
         default=lambda b: jnp.bincount(b, length=n_bins).astype(jnp.int32),
-    )
-
-
-def radix_hist(digits: jax.Array, n_bins: int = 256) -> jax.Array:
-    """Digit histogram for one radix pass, platform-selected at lowering:
-    the Pallas streaming kernel on TPU, bincount elsewhere. n_bins = 2^bits
-    (8-bit digits -> fewer passes, 4-bit -> 16x less per-tile unroll; the
-    hardware A/B decides)."""
-    return jax.lax.platform_dependent(
-        digits,
-        tpu=lambda d: digit_hist_pallas(d, n_bins),
-        default=lambda d: jnp.bincount(d, length=n_bins).astype(jnp.int32),
-    )
-
-
-def radix_pos(digits: jax.Array, starts: jax.Array,
-              n_bins: int = 256) -> jax.Array:
-    """Stable counting-partition positions for one radix pass,
-    platform-selected at lowering (Pallas rank kernel on TPU)."""
-    return jax.lax.platform_dependent(
-        digits, starts,
-        tpu=lambda d, s: partition_pos_pallas(d, n_bins, s),
-        default=lambda d, s: _xla_onehot_pos(d, s, n_bins),
     )
 
 

@@ -51,7 +51,6 @@ def ring_exchange(
     slot_capacity: int,
     out_capacity: int,
     pregrouped: bool = False,
-    sort_impl: str = None,
 ) -> Tuple[Cols, jax.Array, jax.Array]:
     """Drop-in replacement for kernels.bucket_exchange (same contract:
     returns (cols, new_count, overflow_flag)): the group=1 extreme of the
@@ -61,8 +60,7 @@ def ring_exchange(
         return kernels.passthrough_exchange(cols, count, bucket.shape[0],
                                             out_capacity)
     return staged_exchange(cols, count, bucket, n_shards, slot_capacity,
-                           out_capacity, pregrouped=pregrouped,
-                           sort_impl=sort_impl, group=1)
+                           out_capacity, pregrouped=pregrouped, group=1)
 
 
 def staged_exchange(
@@ -73,16 +71,13 @@ def staged_exchange(
     slot_capacity: int,
     out_capacity: int,
     pregrouped: bool = False,
-    sort_impl: str = None,
     group: int = 1,
 ) -> Tuple[Cols, jax.Array, jax.Array]:
     """Blocked/staged exchange: rows move in ceil((n-1)/group) rounds of
     `group` shifted ppermutes each. Same contract as
     kernels.bucket_exchange — returns (cols, new_count, overflow_flag);
     pregrouped means rows are already contiguous per bucket, so grouping
-    collapses to a bincount; sort_impl is the caller's resolved
-    dense_sort_impl, threaded so the grouping escape hatch matches the
-    caller's program-cache key.
+    collapses to a bincount.
 
     Per round the live transient per column is one stacked
     [group, slot_capacity] send buffer plus its received mirror, and the
@@ -107,9 +102,7 @@ def staged_exchange(
         # intermediates would defeat exactly the peak-memory bound this
         # exchange exists to provide.
         sorted_cols, counts_to, starts = kernels._group_by_bucket(
-            cols, bucket, n_shards, prefer_low_memory=True,
-            sort_impl=sort_impl,
-        )
+            cols, bucket, n_shards, prefer_low_memory=True)
     overflow = jnp.any(counts_to > slot_capacity)
 
     my_id = lax.axis_index(SHARD_AXIS)
