@@ -258,6 +258,29 @@ def test_partition_pos_pallas_lowers_for_tpu():
     assert "tpu_custom_call" in exp.mlir_module()
 
 
+def _tag(n):
+    return np.arange(n, dtype=np.int32)  # source position of every row
+
+
+# Payload blocks for the sorts: what rides the sort as operands (1-D columns
+# of any dtype and number) and what is gathered behind it (a column with
+# more than one dimension).
+_PAYLOADS = {
+    "ties_payload": lambda rng, n: {"v": _tag(n)},
+    "mixed_payloads": lambda rng, n: {
+        "f": rng.randn(n).astype(np.float32), "i": _tag(n),
+        "b": rng.rand(n) < 0.5, "u": rng.randint(0, 255, n).astype(np.uint8)},
+    "eight_values": lambda rng, n: {
+        f"v{j}": (_tag(n) * (j + 1)).astype(np.float32 if j % 2 else np.int32)
+        for j in range(8)},
+    "deep_column": lambda rng, n: {
+        "v": _tag(n), "m": rng.randn(n, 3).astype(np.float32),
+        "t": np.arange(n * 4, dtype=np.int32).reshape(n, 2, 2)},
+}
+_SORT_KEYSETS = ["int32", "float32", "wide", *_PAYLOADS,
+                 "wide_eight_values_deep"]
+
+
 def _sort_case(keyset, rng, n):
     """(cols, lo_name, host keys) for one key layout, duplicate keys
     included (the values then pin the stable order)."""
@@ -265,6 +288,18 @@ def _sort_case(keyset, rng, n):
     from vega_tpu.tpu.block import KEY, KEY_LO, VALUE
 
     vals = rng.randint(0, 10**6, size=n).astype(np.int32)
+    if keyset in _PAYLOADS:
+        # five distinct keys over thousands of rows: only the payload tells
+        # a key's rows apart, so every column pins the stable order
+        host = rng.randint(0, 5, size=n).astype(np.int32)
+        return {KEY: host, **_PAYLOADS[keyset](rng, n)}, None, host
+    if keyset == "wide_eight_values_deep":
+        host = rng.randint(-2**50, 2**50, size=n).astype(np.int64)
+        host[: n // 2] = host[0] + np.arange(n // 2) % 3
+        hi, lo = block_lib.encode_i64(host)
+        payload = {**_PAYLOADS["eight_values"](rng, n),
+                   **_PAYLOADS["deep_column"](rng, n)}
+        return {KEY: hi, KEY_LO: lo, **payload}, KEY_LO, host
     if keyset == "int32":
         host = rng.randint(-100, 100, size=n).astype(np.int32)
         return {KEY: host, VALUE: vals}, None, host
@@ -295,7 +330,7 @@ def _assert_sorted_like(cols, out, order, count):
 
 @pytest.mark.parametrize("descending", [False, True],
                          ids=["ascending", "descending"])
-@pytest.mark.parametrize("keyset", ["int32", "float32", "wide"])
+@pytest.mark.parametrize("keyset", _SORT_KEYSETS)
 def test_sort_by_column_matches_numpy_stable_argsort(keyset, descending):
     """sort_by_column against numpy on the valid prefix: every column
     follows the key's stable order (duplicates included), and the ghost
@@ -333,6 +368,20 @@ def _edge_rows(kind):
     elif kind == "wide_full_range":
         host = rng.randint(-2**62, 2**62, size=n).astype(np.int64)
         host[0], host[1] = np.iinfo(np.int64).min, np.iinfo(np.int64).max
+    elif kind == "int32_full":  # count == capacity: no ghost at all
+        host = rng.randint(-2**31, 2**31 - 1, size=n).astype(np.int32)
+        host[: n // 4] = rng.randint(-50, 50, size=n // 4)
+        host[5], host[n - 1] = -2**31, 2**31 - 1
+        count = n
+    elif kind == "wide_full":
+        host = rng.randint(-2**62, 2**62, size=n).astype(np.int64)
+        host[: n // 4] = host[0] + np.arange(n // 4) % 5
+        host[n - 1], host[n - 2] = (np.iinfo(np.int64).min,
+                                    np.iinfo(np.int64).max)
+        count = n
+    elif kind == "wide_all_ghost":
+        host = rng.randint(-2**62, 2**62, size=n).astype(np.int64)
+        count = 0
     else:
         assert kind == "all_ghost"
         host = rng.randint(-50, 50, size=n).astype(np.int32)
@@ -344,11 +393,14 @@ def _edge_rows(kind):
                          ids=["ascending", "descending"])
 @pytest.mark.parametrize("kind", [
     "int32_extremes", "float32_infinities", "wide_constant_high_word",
-    "wide_full_range", "all_ghost"])
+    "wide_full_range", "all_ghost", "int32_full", "wide_full",
+    "wide_all_ghost"])
 def test_sort_by_column_edge_rows(kind, descending):
     """Extreme keys tie with the ghosts' padding and must still come
     first (stability: valid rows sit at lower positions); the ghosts keep
-    their own order behind them, so an all-ghost shard is the identity."""
+    their own order behind them, so an all-ghost shard is the identity on
+    every column that rides the sort. The ghosts' key words hold the
+    padding the sort ordered them by (the docstring's promise)."""
     from vega_tpu.tpu import block as block_lib
     from vega_tpu.tpu.block import KEY, KEY_LO, VALUE
 
@@ -366,6 +418,14 @@ def test_sort_by_column_edge_rows(kind, descending):
     _assert_sorted_like(cols, out, _stable_order(host[:count], descending),
                         count)
     assert np.asarray(out[VALUE])[count:].tolist() == list(range(count, n))
+    for nm in set(cols) - {VALUE}:
+        word = np.asarray(out[nm])
+        if word.dtype.kind == "f":
+            pad = -np.inf if descending else np.inf
+        else:
+            info = np.iinfo(word.dtype)
+            pad = info.min if descending else info.max
+        assert (word[count:] == pad).all(), nm
 
 
 def test_sort_by_column_descending_int_min():
@@ -380,15 +440,17 @@ def test_sort_by_column_descending_int_min():
     assert np.asarray(out[KEY]).tolist() == [7, 5, 0, -2**31]
 
 
-@pytest.mark.parametrize("keyset", ["int32", "float32", "wide"])
-def test_bucket_key_sort_matches_numpy_lexsort(keyset):
+@pytest.mark.parametrize("count", [3_500, 0, 4_000],
+                         ids=["some_ghosts", "all_ghost", "no_ghost"])
+@pytest.mark.parametrize("keyset", _SORT_KEYSETS)
+def test_bucket_key_sort_matches_numpy_lexsort(keyset, count):
     """The fused (bucket major, key minor) sort against
     np.lexsort((key, bucket)) over every row, ghosts (bucket = n_shards)
     included: lexsort is stable, so the whole permutation is pinned and
-    each column must follow it."""
+    each column must follow it, the ghosts' keys too."""
     from vega_tpu.tpu.block import KEY
 
-    n, count, n_shards = 4_000, 3_500, 8
+    n, n_shards = 4_000, 8
     cols, lo_name, host = _sort_case(keyset, np.random.RandomState(6), n)
     bucket = np.asarray(
         kernels.hash32(jnp.asarray(cols[KEY])) % jnp.uint32(n_shards)

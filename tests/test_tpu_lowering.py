@@ -174,6 +174,54 @@ def test_merge_join_expand_gathers_do_not_grow_with_capacity(wide, outer):
     assert large <= 16
 
 
+@pytest.mark.parametrize("deep", [0, 1], ids=["flat_columns", "one_2d"])
+@pytest.mark.parametrize("kernel", [
+    "sort_one_word", "sort_one_word_descending", "sort_two_word",
+    "sort_two_word_descending", "bucket_one_word", "bucket_two_word"])
+def test_sorts_carry_columns_and_gather_only_2d(kernel, deep):
+    """No chip needed: a sort's 1-D columns are operands of its one
+    lax.sort, so the jaxpr holds no gather at all (and one sort); a column
+    with more than one dimension cannot be an operand, and the block that
+    has one holds exactly that column's gather."""
+    wide = "two_word" in kernel
+
+    def prog(n, bucket, keys, lo, ints, floats, flags, matrix):
+        cols = {KEY: keys, VALUE: ints, "f": floats, "b": flags}
+        if wide:
+            cols[KEY_LO] = lo
+        if deep:
+            cols["m"] = matrix
+        lo_name = KEY_LO if wide else None
+        if kernel.startswith("bucket"):
+            return kernels.bucket_key_sort(cols, bucket, KEY,
+                                           lo_name=lo_name)
+        return kernels.sort_by_column(
+            cols, n, KEY, descending=kernel.endswith("descending"),
+            lo_name=lo_name)
+
+    col = jax.ShapeDtypeStruct((CAP,), jnp.int32)
+    jaxpr = jax.make_jaxpr(prog)(
+        jax.ShapeDtypeStruct((), jnp.int32), col, col, col, col,
+        jax.ShapeDtypeStruct((CAP,), jnp.float32),
+        jax.ShapeDtypeStruct((CAP,), jnp.bool_),
+        jax.ShapeDtypeStruct((CAP, 3), jnp.float32)).jaxpr
+    assert _gathers(jaxpr) == deep
+    assert sum(e.primitive.name == "sort" for e in jaxpr.eqns) == 1
+
+
+def test_lowering_sort_carries_mixed_columns():
+    """A sort whose operands mix int32, float32, bool and uint8 columns
+    with a 2-D column behind it lowers for the tpu platform."""
+    def prog(counts, keys, vals):
+        cols = {KEY: keys, VALUE: vals, "f": vals.astype(jnp.float32),
+                "b": vals > 0, "u": vals.astype(jnp.uint8),
+                "m": jnp.stack([vals, keys], axis=1)}
+        out = kernels.sort_by_column(cols, counts[0], KEY, descending=True)
+        return tuple(out[nm] for nm in cols)
+
+    _export_sharded(prog, 3, 6, _pair_args())
+
+
 def test_lowering_range_sort():
     def prog(bounds, counts, keys, vals):
         count = counts[0]

@@ -115,8 +115,33 @@ def compact(cols: Cols, keep: jax.Array, out_capacity: int) -> Tuple[Cols, jax.A
     return out, jnp.sum(keep).astype(jnp.int32)
 
 
-def gather_rows(cols: Cols, idx: jax.Array) -> Cols:
-    return {n: jnp.take(c, idx, axis=0) for n, c in cols.items()}
+def sort_carrying(keys, cols: Cols):
+    """One stable lax.sort by `keys` (a tuple of 1-D key words, major first)
+    that moves the rows of `cols` with them. Returns (sorted keys, cols).
+
+    Every 1-D column is a further operand of the sort, so it arrives in
+    order with the keys: no permutation is sorted and no column is gathered
+    through one. lax.sort takes operands of one shape only, so where a
+    column has more than one dimension an iota rides too and those columns
+    alone are gathered through it: decided from the columns' rank, at trace
+    time.
+
+    No cap on the operands. On a v5e at 64Mi rows (PERF.md, PR 33) a 32-bit
+    operand adds 0.081 s to the sort's run and 13.4 s to its compile, both
+    in proportion up to the eight measured (one key and eight columns:
+    0.82 s to run, 124 s to compile), where the gather it replaces runs
+    0.98 s an action and compiles in none (sorting an iota and gathering
+    eight columns: 8.97 s, 22 s): a program run fifteen times has its
+    compile back, and the compile cache keeps it."""
+    flat = {n: c for n, c in cols.items() if c.ndim == 1}
+    deep = {n: c for n, c in cols.items() if c.ndim > 1}
+    operands = (*keys, *flat.values())
+    if deep:
+        operands += (lax.iota(jnp.int32, keys[0].shape[0]),)
+    res = lax.sort(operands, num_keys=len(keys), is_stable=True)
+    out = dict(zip(flat, res[len(keys):]))
+    out.update((n, jnp.take(c, res[-1], axis=0)) for n, c in deep.items())
+    return res[:len(keys)], out
 
 
 # ---------------------------------------------------------------------------
@@ -171,10 +196,11 @@ def _group_by_bucket(cols: Cols, bucket: jax.Array, n_shards: int,
                 grouped[name] = dst.at[pos].set(col, mode="drop")
             return grouped, counts_to, starts
     # Escape hatch (>64 buckets, or low-memory without the Pallas path):
-    # a stable argsort by bucket. Every row participates; padding rows
-    # carry bucket == n_shards and sort last by value.
-    order = jnp.argsort(bucket, stable=True)
-    return gather_rows(cols, order), counts_to, starts
+    # a stable sort by bucket that carries the columns. Every row
+    # participates; padding rows carry bucket == n_shards and sort last by
+    # value.
+    _, grouped = sort_carrying((bucket,), cols)
+    return grouped, counts_to, starts
 
 
 def bucket_key_sort(cols: Cols, bucket: jax.Array, key_name: str,
@@ -189,24 +215,14 @@ def bucket_key_sort(cols: Cols, bucket: jax.Array, key_name: str,
     ghosted invalid rows (bucket = n_shards) so they sink to the end.
     lo_name names the low word of a two-column int64 key (block.py KEY_LO):
     it joins the sort keys so runs are sorted by the full 64-bit key.
-    Returns (cols, bucket), both permuted."""
-    capacity = bucket.shape[0]
-    perm_src = lax.iota(jnp.int32, capacity)
-    if lo_name is None:
-        sorted_bucket, sorted_key, perm = lax.sort(
-            (bucket, cols[key_name], perm_src), num_keys=2, is_stable=True
-        )
-        sorted_keys = {key_name: sorted_key}
-    else:
-        sorted_bucket, sk, sl, perm = lax.sort(
-            (bucket, cols[key_name], cols[lo_name], perm_src),
-            num_keys=3, is_stable=True,
-        )
-        sorted_keys = {key_name: sk, lo_name: sl}
-    out = gather_rows(
-        {n: c for n, c in cols.items() if n not in sorted_keys}, perm
-    )
-    out.update(sorted_keys)  # already produced by the sort; skip gathers
+    Returns (cols, bucket), both in the sorted order: every row, ghosts
+    included, keeps all its columns (the keys are sorted as they are, the
+    other columns ride the sort, sort_carrying)."""
+    names = [key_name] if lo_name is None else [key_name, lo_name]
+    (sorted_bucket, *words), out = sort_carrying(
+        (bucket, *(cols[n] for n in names)),
+        {n: c for n, c in cols.items() if n not in names})
+    out.update(zip(names, words))
     return out, sorted_bucket
 
 
@@ -314,34 +330,33 @@ def bucket_exchange(
 def sort_by_column(cols: Cols, count: jax.Array, key_name: str,
                    descending: bool = False, lo_name: str = None) -> Cols:
     """Stable sort valid rows by one column (or a (key, lo) two-column
-    int64 key when lo_name is given); invalid rows sink to the end."""
-    key = cols[key_name]
-    capacity = key.shape[0]
-    mask = valid_mask(capacity, count)
-    if lo_name is not None:
-        hi_k, lo_k = key, cols[lo_name]
-        if descending:
-            hi_k, lo_k = ~hi_k, ~lo_k  # order-reversing, overflow-free
-        hi_k = jnp.where(mask, hi_k, _orderable_max(hi_k))
-        lo_k = jnp.where(mask, lo_k, _orderable_max(lo_k))
-        perm_src = lax.iota(jnp.int32, capacity)
-        _, _, order = lax.sort((hi_k, lo_k, perm_src), num_keys=2,
-                               is_stable=True)
-        return gather_rows(cols, order)
-    if descending:
-        k = _orderable(key)
+    int64 key when lo_name is given); invalid rows sink to the end.
+
+    The key words are the keys of one lax.sort and every other column rides
+    it (sort_carrying). Rows past `count` keep their own order and their
+    other columns, but not their key: the sort orders them by the padding
+    they are forced to, and that is what their key words hold afterwards
+    (the dtype's largest value, +inf for floats; descending its flip, the
+    smallest value, -inf). Ascending, each whole key column is therefore
+    sorted, padding included. Every reader masks by `count`."""
+    names = [key_name] if lo_name is None else [key_name, lo_name]
+    mask = valid_mask(cols[key_name].shape[0], count)
+
+    def flip(w):
         # bitwise-not is the overflow-free order flip for ints (negation
-        # wraps INT32_MIN onto itself and mis-sorts it first); floats
-        # negate exactly
-        flipped = -k if jnp.issubdtype(k.dtype, jnp.floating) else ~k
-        order = jnp.argsort(
-            jnp.where(mask, flipped, _orderable_max(key)), stable=True
-        )
-    else:
-        order = jnp.argsort(
-            jnp.where(mask, _orderable(key), _orderable_max(key)), stable=True
-        )
-    return gather_rows(cols, order)
+        # wraps INT32_MIN onto itself and mis-sorts it first), word by word
+        # the flip of the lexicographic order too; floats negate exactly.
+        # Each is its own inverse, bit for bit.
+        if not descending:
+            return w
+        return -w if jnp.issubdtype(w.dtype, jnp.floating) else ~w
+
+    words, out = sort_carrying(
+        tuple(jnp.where(mask, flip(cols[n]), _orderable_max(cols[n]))
+              for n in names),
+        {n: c for n, c in cols.items() if n not in names})
+    out.update((n, flip(w)) for n, w in zip(names, words))
+    return out
 
 
 _WIDE_BIAS = 0x80000000  # sign-flip bias on stored low words (block._LO_BIAS)
@@ -389,11 +404,6 @@ def wide_select(a_hi, a_lo, b_hi, b_lo, take_min: bool):
     a_less = (a_hi < b_hi) | ((a_hi == b_hi) & (a_lo < b_lo))
     pick_a = a_less if take_min else ~a_less
     return (jnp.where(pick_a, a_hi, b_hi), jnp.where(pick_a, a_lo, b_lo))
-
-
-def _orderable(key: jax.Array) -> jax.Array:
-    """Map a column to an order-preserving integer/float domain."""
-    return key
 
 
 def _orderable_max(key: jax.Array):
