@@ -1049,7 +1049,16 @@ class DenseRDD(RDD):
             log.info("dense map_values fell back to host tier: wide "
                      "int64 value column")
             return super().map_values(f)
-        if len(value_names) != 1:
+        if isinstance(self, _JoinRDD):
+            # A joined block's rows are (k, (lv, rv)) on both tiers
+            # (_JoinRDD._rows), so f has a host form whatever the sides
+            # hold; the device traces it on the pair of scalars.
+            if wide_los or set(value_names) & set(self._dicts()):
+                log.info("dense map_values fell back to host tier: wide "
+                         "int64 or dictionary-encoded (string) side of a "
+                         "join")
+                return super().map_values(f)
+        elif len(value_names) != 1:
             # Named/multi-column blocks (wide or not) have no host (k, v)
             # row form — the documented crisp-error exception.
             raise VegaError(
@@ -1057,7 +1066,7 @@ class DenseRDD(RDD):
                 f"{value_names}); use select(...) or a tuple-valued "
                 "reduce_by_key on multi-column blocks"
             )
-        if value_names[0] in self._dicts():
+        elif value_names[0] in self._dicts():
             if value_names == [VALUE]:
                 # Dictionary-encoded (string) VALUE: a traced f would see
                 # int32 codes, not strings; the canonical pair layout
@@ -1072,7 +1081,7 @@ class DenseRDD(RDD):
                 f"{VALUE!r}}}) to the canonical layout for the host "
                 "fallback"
             )
-        if value_names[0] in block_lib.wide_value_pairs(names):
+        elif value_names[0] in block_lib.wide_value_pairs(names):
             # ONE named wide column: a traced f would see only the hi
             # word, and a named block has no host (k, v) row form to fall
             # back on — crisp, naming the one logical column.
@@ -2131,13 +2140,20 @@ class _MapRDD(_NarrowRDD):
 class _MapValuesRDD(_NarrowRDD):
     def __init__(self, parent: DenseRDD, f):
         pschema = dict(parent._schema())
-        # The single value column, whatever its name (canonical 'v' or a
-        # named column from dense_from_columns).
-        self._vname = next(nm for nm in pschema if nm not in (KEY, KEY_LO))
+        if isinstance(parent, _JoinRDD):
+            # f sees the joined pair (lv, rv), as the host tier calls it
+            # on (k, (lv, rv)) rows, and mints the one column VALUE.
+            self._in_names, self._vname = ("lv", "rv"), VALUE
+        else:
+            # The single value column, whatever its name (canonical 'v'
+            # or a named column from dense_from_columns).
+            self._vname = next(nm for nm in pschema
+                               if nm not in (KEY, KEY_LO))
+            self._in_names = (self._vname,)
         try:
-            out = jax.eval_shape(
-                f, jax.ShapeDtypeStruct((), pschema[self._vname])
-            )
+            out = jax.eval_shape(f, self._value_arg(
+                {nm: jax.ShapeDtypeStruct((), pschema[nm])
+                 for nm in self._in_names}))
         except Exception as e:  # noqa: BLE001
             raise _NotTraceable(str(e)) from e
         if not hasattr(out, "shape") or out.shape != ():
@@ -2152,9 +2168,13 @@ class _MapValuesRDD(_NarrowRDD):
         # the value column is minted by the closure.
         self._dict_renames = {KEY: KEY}
 
+    def _value_arg(self, cols):
+        vals = tuple(cols[nm] for nm in self._in_names)
+        return vals[0] if len(vals) == 1 else vals
+
     def _shard_fn(self, cols, count):
         out = {KEY: cols[KEY],
-               self._vname: jax.vmap(self._f)(cols[self._vname])}
+               self._vname: jax.vmap(self._f)(self._value_arg(cols))}
         if KEY_LO in cols:
             out[KEY_LO] = cols[KEY_LO]
         return out, count
@@ -3159,6 +3179,20 @@ def _elide_out_cap(blk: Block) -> int:
     return blk.capacity
 
 
+def _count_fill(moved, n_shards: int, out_cap: int) -> None:
+    """How full a succeeded exchange ran, for the session tally: each side
+    in `moved` (its root block and the narrow chain fused above it) put its
+    rows into the exchange and the program held `n_shards x out_cap` receive
+    slots for it. Rows in equal rows out, so the block's host-known counts
+    are the rows; a side whose counts are still on the device, or whose
+    fused chain may filter, counts neither rows nor slots (never a fetch)."""
+    rows = [int(blk.counts_host.sum()) for blk, chain in moved
+            if not chain and blk.counts_host is not None]
+    if rows:
+        spans.count("exchange_rows", sum(rows))
+        spans.count("exchange_slots", len(rows) * n_shards * out_cap)
+
+
 def _settle_pending(ctx) -> None:
     """Verify every deferred (speculative) exchange in ONE device
     transfer; repair failures in place.
@@ -3194,6 +3228,7 @@ def _settle_pending(ctx) -> None:
                 hint_store.pop(next(iter(hint_store)))
         if e["on_success"] is not None:
             e["on_success"](head)
+        _count_fill(e["moved"], e["rdd"].mesh.size, e["caps"][1])
 
     def depends_on(rdd, failed_rdds) -> bool:
         """True if rdd's dense lineage reaches any failed node (possibly
@@ -3474,7 +3509,7 @@ class _ExchangeRDD(DenseRDD):
                       hists: Optional[List[np.ndarray]] = None,
                       slot_hists: Optional[List[np.ndarray]] = None,
                       make_hists=None, hint_key=None, fixed_caps=None,
-                      validate=None, on_success=None):
+                      validate=None, on_success=None, moved=()):
         """Run the fused exchange program with capacity sizing.
 
         Sizing order: (1) `fixed_caps` — capacities known a priori
@@ -3492,7 +3527,9 @@ class _ExchangeRDD(DenseRDD):
         between otherwise async-pipelined launches — and leaves a
         pending entry for _attach_pending/_settle_pending to verify at
         the next genuine host read. `validate`/`on_success` ride the
-        entry (join product checks / node bookkeeping)."""
+        entry (join product checks / node bookkeeping), as does `moved`:
+        the (root block, fused chain) of each side that crosses shards,
+        tallied by _count_fill once the launch is known to have fit."""
         from vega_tpu.scheduler import events as ev
 
         n = self.mesh.size
@@ -3539,6 +3576,7 @@ class _ExchangeRDD(DenseRDD):
                 "caps": (slot, out_cap),
                 "validate": validate,
                 "on_success": on_success,
+                "moved": moved,
             }
             self._last_counts_host = None
             self._last_extra_host = None
@@ -3611,6 +3649,7 @@ class _ExchangeRDD(DenseRDD):
                         # oldest entries past the cap.
                         while len(hint_store) > 4096:
                             hint_store.pop(next(iter(hint_store)))
+                    _count_fill(moved, n, out_cap)
                     return outs, out_cap
                 log.info("exchange overflow (slot=%d out=%d), retrying",
                          slot, out_cap)
@@ -4100,6 +4139,7 @@ class _GroupByKeyRDD(_ExchangeRDD):
                 make_hists=lambda: ([self._hash_histogram(blk, chain)],
                                     None),
                 hint_key=self._hint_key(),
+                moved=[(blk, chain)] if n > 1 else (),
             )
         counts, col_arrays = outs[0], outs[1:]
         return self._attach_pending(Block(
@@ -4343,10 +4383,14 @@ class _JoinRDD(_ExchangeRDD):
                 while len(hint_store) > 4096:
                     hint_store.pop(next(iter(hint_store)))
 
+        # One shard moves nothing, and an elided side stays where it is.
+        moved = [side for side, elide in (((lblk, l_chain), l_elide),
+                                          ((rblk, r_chain), r_elide))
+                 if n > 1 and not elide]
         outs, _ = self._run_exchange(build, counts_fn,
                                      make_hists=make_hists,
                                      hint_key=hint, validate=validate,
-                                     on_success=on_success)
+                                     on_success=on_success, moved=moved)
         if "_deferred_entry" not in self.__dict__:
             # Blocking path: run the same product checks the deferred
             # entry runs at settlement (ONE policy, validate above). On a
@@ -4359,7 +4403,8 @@ class _JoinRDD(_ExchangeRDD):
                                              make_hists=make_hists,
                                              hint_key=hint,
                                              validate=validate,
-                                             on_success=on_success)
+                                             on_success=on_success,
+                                             moved=moved)
             if "_deferred_entry" not in self.__dict__ \
                     and join_cap_override[0]:
                 on_success(None)
@@ -4554,6 +4599,7 @@ class _SortByKeyRDD(_ExchangeRDD):
             # already fetched).
             hint_key=self._hint_key(counts_host.tobytes(),
                                     bounds.tobytes()),
+            moved=[(blk, chain)] if n > 1 else (),
         )
         counts, col_arrays = outs[0], outs[1:]
         return self._attach_pending(Block(

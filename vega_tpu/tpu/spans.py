@@ -26,11 +26,15 @@ slicing, concatenation, the int64 and dictionary decodes of a fetched block),
 `pivot` (columns -> Python row objects), `fingerprint` (pickling a closure
 for a program-cache key).
 
-Counters: `count(name)` adds one to a count-only entry of the same tally
+Counters: `count(name, n=1)` adds `n` to a count-only entry of the same tally
 (seconds and bytes stay 0; no annotation, nothing to nest). `exchange` (one a
 call of `_run_exchange`), `exchange_round` (one a launch of its program: an
 overflow launches again with grown capacities) and `exchange_repair` (one a
-block `_settle_pending` rebuilt after a speculative launch overflowed).
+block `_settle_pending` rebuilt after a speculative launch overflowed);
+`exchange_rows` and `exchange_slots` (once a launch has succeeded, for each
+side that moved and whose row count the host already held: the rows it put
+into the exchange over all shards, and the `n_shards x out_cap` receive slots
+the program held for it; their quotient is how full the exchange ran).
 
 Programs are rare (2-3 a run), so `programs()` is always recorded:
 {kind: {"mints", "first_call_s"}}, the second the host seconds of each minted
@@ -131,8 +135,8 @@ class span:
         return False
 
 
-def count(name: str) -> None:
-    """Add one to the count-only entry `name` of the session tally; off, one
+def count(name: str, n: int = 1) -> None:
+    """Add `n` to the count-only entry `name` of the session tally; off, one
     `_enabled()` check and nothing recorded."""
     global _live
     if not _enabled():
@@ -143,7 +147,7 @@ def count(name: str) -> None:
         if not _live:
             _session.clear()
             _live = True
-        _entry(name)["count"] += 1
+        _entry(name)["count"] += n
 
 
 def new_session() -> None:
