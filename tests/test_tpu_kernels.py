@@ -1,6 +1,10 @@
 """Device-kernel unit tests: pallas kernels (interpret mode on CPU), ring
 vs all_to_all exchange parity, shard-local kernel correctness."""
 
+import functools
+import zlib
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -82,6 +86,78 @@ def test_segment_reduce_kernels_direct():
     got2 = {int(k): float(x) for k, x in
             zip(out2["k"][:int(n2)], out2["v"][:int(n2)])}
     assert got2 == got
+
+
+_SEG_CAP = 40
+_NP_REDUCE = {"add": np.add, "min": np.minimum, "max": np.maximum,
+              "prod": np.multiply}
+
+
+@functools.lru_cache(maxsize=None)
+def _named_reduce_program(op, wide):
+    return jax.jit(lambda cols, count: kernels.segment_reduce_named(
+        cols, count, "k", op, presorted=True,
+        lo_name="k.lo" if wide else None))
+
+
+def _segment_case(op, wide, column, count, keys):
+    """Sorted valid rows, then rows past `count` that hold anything: keys
+    out of order and values no reduction may pick up. Values are whole
+    numbers (prod: -1, 1, 2) so any order of a float reduction is exact."""
+    rng = np.random.RandomState(
+        zlib.crc32(repr((op, wide, column, count, keys)).encode()))
+    if keys == "a_key_a_row":
+        hi = rng.permutation(_SEG_CAP).astype(np.int32) - 7
+    elif keys == "one_key":
+        hi = np.full(_SEG_CAP, 5, np.int32)
+    else:
+        hi = rng.randint(-2, _SEG_CAP // 10 - 2, _SEG_CAP).astype(np.int32)
+    lo = np.zeros(_SEG_CAP, np.int32)
+    if wide and keys == "ten_rows_a_key":  # the low word splits a key's rows
+        lo = rng.randint(-1, 1, _SEG_CAP).astype(np.int32)
+    order = np.lexsort((lo[:count], hi[:count]))
+    hi[:count], lo[:count] = hi[:count][order], lo[:count][order]
+    shape = (_SEG_CAP, 3) if column == "float32_3wide" else (_SEG_CAP,)
+    vals = rng.choice([-1, 1, 2], shape) if op == "prod" \
+        else rng.randint(-50, 50, shape)
+    vals = vals.astype(np.int32 if column == "int32" else np.float32)
+    return hi, lo, vals
+
+
+@pytest.mark.parametrize("keys", ["ten_rows_a_key", "a_key_a_row", "one_key"])
+@pytest.mark.parametrize("count", [0, 1, _SEG_CAP - 1, _SEG_CAP],
+                         ids=["empty", "one_row", "all_but_one", "full"])
+@pytest.mark.parametrize("column", ["float32", "int32", "float32_3wide"])
+@pytest.mark.parametrize("wide", [False, True], ids=["one_word", "two_word"])
+@pytest.mark.parametrize("op", ["add", "min", "max", "prod"])
+def test_segment_reduce_named_matches_numpy_group_by(op, wide, column, count,
+                                                     keys):
+    """Segment i's key and reduction are at row i, in key order, and every
+    row from n_segments on is zero in every column: whatever the rows past
+    `count` hold, and where min / max / prod leave their identity."""
+    hi, lo, vals = _segment_case(op, wide, column, count, keys)
+    cols = {"k": jnp.asarray(hi), "v": jnp.asarray(vals)}
+    if wide:
+        cols["k.lo"] = jnp.asarray(lo)
+    out, n_seg = _named_reduce_program(op, wide)(cols, jnp.int32(count))
+    out = {name: np.asarray(col) for name, col in out.items()}
+    assert set(out) == set(cols)
+
+    pairs = np.stack([hi[:count], lo[:count]], axis=1)
+    starts = np.flatnonzero(
+        np.r_[True, (pairs[1:] != pairs[:-1]).any(axis=1)][:count])
+    n = len(starts)
+    assert int(n_seg) == n
+    np.testing.assert_array_equal(out["k"][:n], hi[:count][starts])
+    if wide:
+        np.testing.assert_array_equal(out["k.lo"][:n], lo[:count][starts])
+    if n:
+        want = _NP_REDUCE[op].reduceat(vals[:count], starts, axis=0,
+                                       dtype=vals.dtype)
+        np.testing.assert_array_equal(out["v"][:n], want)
+    for name, col in out.items():
+        assert col.shape == cols[name].shape and col.dtype == cols[name].dtype
+        assert not col[n:].any(), name
 
 
 def test_masked_reduce_ignores_invalid_rows():

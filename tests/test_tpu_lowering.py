@@ -134,18 +134,23 @@ def test_lowering_merge_join_expand():
     _export_sharded(prog, 3, 5, _pair_args())
 
 
-def _gathers(jaxpr, times=1):
-    """Gather operations a jaxpr runs: one inside a scan counts once a
-    step (jnp.searchsorted is a scan of log2(n) steps, a gather each)."""
+def _ops(jaxpr, name, times=1):
+    """Operations of one primitive a jaxpr runs: one inside a scan counts
+    once a step (jnp.searchsorted is a scan of log2(n) steps, a gather
+    each); one inside a cond counts in every branch that holds it."""
     n = 0
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "gather":
+        if eqn.primitive.name == name:
             n += times
         inner = times * eqn.params.get("length", 1) \
             if eqn.primitive.name == "scan" else times
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            n += _gathers(sub, inner)
+            n += _ops(sub, name, inner)
     return n
+
+
+def _gathers(jaxpr, times=1):
+    return _ops(jaxpr, "gather", times)
 
 
 @pytest.mark.parametrize("wide", [False, True], ids=["one_word", "two_word"])
@@ -207,6 +212,39 @@ def test_sorts_carry_columns_and_gather_only_2d(kernel, deep):
         jax.ShapeDtypeStruct((CAP, 3), jnp.float32)).jaxpr
     assert _gathers(jaxpr) == deep
     assert sum(e.primitive.name == "sort" for e in jaxpr.eqns) == 1
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["one_word", "two_word"])
+@pytest.mark.parametrize("op,seg_scatter", [("add", "scatter-add"),
+                                            ("min", "scatter-min")])
+def test_segment_reduce_named_writes_each_column_once(op, seg_scatter, wide):
+    """No chip needed: every output column of the named reduce is one
+    scatter. A key word is compacted by the rows that start a segment, a
+    value column is the segment op's scatter and rows past the last segment
+    are cleared elementwise; nothing is gathered, no `nonzero` counts
+    (it is a scatter-add of its own) and nothing is compacted afterwards.
+    8,192 rows, so the float add's long-run branch is in the program."""
+    cap = 2 * kernels.LONG_RUN_ROWS
+
+    def prog(n, keys, lo, floats, matrix):
+        cols = {KEY: keys, VALUE: floats, "m": matrix}
+        if wide:
+            cols[KEY_LO] = lo
+        return kernels.segment_reduce_named(
+            cols, n, KEY, op, presorted=True,
+            lo_name=KEY_LO if wide else None)
+
+    col = jax.ShapeDtypeStruct((cap,), jnp.int32)
+    jaxpr = jax.make_jaxpr(prog)(
+        jax.ShapeDtypeStruct((), jnp.int32), col, col,
+        jax.ShapeDtypeStruct((cap,), jnp.float32),
+        jax.ShapeDtypeStruct((cap, 3), jnp.float32)).jaxpr
+    assert _gathers(jaxpr) == 0
+    assert _ops(jaxpr, "scatter") == (2 if wide else 1)  # the key words
+    scatters = {nm: _ops(jaxpr, nm) for nm in
+                ("scatter-add", "scatter-min", "scatter-max", "scatter-mul")}
+    assert scatters.pop(seg_scatter) == 2  # the two value columns
+    assert not any(scatters.values())
 
 
 def test_lowering_sort_carries_mixed_columns():

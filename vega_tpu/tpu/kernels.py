@@ -102,10 +102,10 @@ def valid_mask(capacity: int, count: jax.Array) -> jax.Array:
 
 def compact(cols: Cols, keep: jax.Array, out_capacity: int) -> Tuple[Cols, jax.Array]:
     """Move rows where keep=True to the front; returns (cols, new_count).
-    Stable (kept rows' positions are their exclusive prefix count, which is
-    increasing), static-shape. Implemented as cumsum + scatter — O(n) work
-    instead of the O(n log n) argsort this hot helper used to pay (it runs
-    inside every exchange, filter, and segment reduction)."""
+    Stable (a kept row lands at its exclusive prefix count), static-shape,
+    zeros after the kept rows: a cumsum and one scatter a column. Whole rows
+    go through it in every exchange, filter, sample, flat_map and union; the
+    named segment reduce sends its key words alone, the scanned one its rows."""
     pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
     idx = jnp.where(keep, pos, out_capacity)  # dropped rows land out of range
     out = {}
@@ -521,7 +521,17 @@ def segment_reduce_named(
     presorted: bool = False, lo_name: str = None,
 ) -> Tuple[Cols, jax.Array]:
     """Fast path for the common monoids via XLA segment ops. lo_name names
-    the low word of a two-column int64 key (sorts/segments with the key)."""
+    the low word of a two-column int64 key (sorts/segments with the key).
+
+    Every output column is written once, at the row of its segment's id
+    (segments are numbered in key order, from 0): a value column by the
+    segment op's one scatter, which leaves segment i's reduction at row i;
+    a key word by one compact of the rows that start a segment, which
+    leaves the i-th segment's key at row i. Nothing is gathered and no row
+    moves twice. Rows from n_segments on are zero in every column (a min,
+    max or prod leaves its identity in the segments no row reached, and
+    the padding rows' reduction at row capacity - 1: both are cleared).
+    Returns (cols, n_segments), the value columns first."""
     seg_op = _FAST_SEGMENT_OPS[op]
     capacity = cols[key_name].shape[0]
     if not presorted:
@@ -539,11 +549,10 @@ def segment_reduce_named(
     first = first & mask
     seg_ids = jnp.cumsum(first.astype(jnp.int32)) - 1
     seg_ids = jnp.where(mask, seg_ids, capacity - 1)
-    n_segments = jnp.sum(first).astype(jnp.int32)
-    key_set = {key_name} if lo_name is None else {key_name, lo_name}
+    key_names = [key_name] if lo_name is None else [key_name, lo_name]
     masked_cols: Cols = {}
     for name, col in cols.items():
-        if name in key_set:
+        if name in key_names:
             continue
         if op == "add" or op == "prod":
             neutral = jnp.zeros((), col.dtype) if op == "add" else jnp.ones((), col.dtype)
@@ -575,16 +584,19 @@ def segment_reduce_named(
             lambda vals: vals,
             [masked_cols[name] for name in long_add])
         masked_cols.update(zip(long_add, totals))
-    out: Cols = {name: seg_op(col, seg_ids, num_segments=capacity)
-                 for name, col in masked_cols.items()}
-    # Key of segment i = key at the i-th segment start.
-    start_rows = jnp.nonzero(first, size=capacity, fill_value=capacity - 1)[0]
-    out[key_name] = jnp.take(keys, start_rows)
-    if lo_name is not None:
-        out[lo_name] = jnp.take(cols[lo_name], start_rows)
-    seg_valid = lax.iota(jnp.int32, capacity) < n_segments
-    comp, _ = compact(out, seg_valid, capacity)
-    return comp, n_segments
+    # Key of segment i = key at the i-th segment start: the start rows'
+    # exclusive prefix count is seg_ids there, so this compact puts it at row i.
+    seg_keys, n_segments = compact(
+        {name: cols[name] for name in key_names}, first, capacity)
+    seg_valid = valid_mask(capacity, n_segments)
+    out: Cols = {}
+    for name, col in masked_cols.items():
+        red = seg_op(col, seg_ids, num_segments=capacity)
+        out[name] = jnp.where(
+            seg_valid.reshape(seg_valid.shape + (1,) * (red.ndim - 1)),
+            red, jnp.zeros((), red.dtype))
+    out.update(seg_keys)
+    return out, n_segments
 
 
 # ---------------------------------------------------------------------------
