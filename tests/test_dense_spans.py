@@ -7,6 +7,8 @@ import threading
 import numpy as np
 import pytest
 
+from test_dense_zipf import on_devices  # noqa: F401
+
 
 @pytest.fixture()
 def dctx():
@@ -295,3 +297,42 @@ def test_exchange_stage_duration_is_monotonic(dctx):
     wall = time.perf_counter() - t0
     dctx.bus.flush()
     assert seen and all(0 <= d <= wall for d in seen)
+
+
+@pytest.mark.parametrize("devices, exchange, rounds", [
+    (4, None, 1),  # the planner's one-shot all_to_all
+    (4, "ring", 3),  # the mesh's n - 1
+    (8, "ring", 7),
+    (4, "staged", 1),  # forced: the largest group that fits, one round here
+    (1, None, None),  # one shard plans nothing and counts nothing
+])
+def test_exchange_plan_rounds_an_action(dctx, on_devices, session, devices,
+                                        exchange, rounds):
+    """`exchange_plan_rounds` adds the resolved plan's rounds once a launch:
+    a sort's action reads which collective program ran; cold (sized by the
+    histogram) and warm (hinted, the launch deferred) alike."""
+    from vega_tpu.tpu import spans
+
+    on_devices(devices)
+    keys = (np.arange(N, dtype=np.int64) * 2654435761) % (2**40)
+    pairs = dctx.dense_from_numpy(keys, np.arange(N, dtype=np.float64))
+    for _run in ("cold", "warm"):
+        spans.new_session()
+        with session:
+            node = pairs.sort_by_key(exchange=exchange)
+            cols = node.collect_arrays()
+        assert np.array_equal(cols["k"], np.sort(keys))
+        tally = spans.session()
+        assert tally["exchange"]["count"] == 1
+        if rounds is None:
+            assert "exchange_plan_rounds" not in tally
+            assert node._exchange_plan is None
+        else:
+            assert tally["exchange_plan_rounds"] == {
+                "count": rounds, "seconds": 0.0, "bytes": 0, "by_kind": {}}
+            assert node._exchange_plan.rounds == rounds
+        assert spans.nested() == 0
+    # off, the counter records nothing
+    before = spans.session()
+    pairs.sort_by_key(exchange=exchange).collect_arrays()
+    assert spans.session() == before

@@ -3377,9 +3377,8 @@ class _ExchangeRDD(DenseRDD):
         non-elided sides, and the estimate models the JOINT launch
         footprint (both operands and outputs live together, the
         costlier side's transients on top), not the max of the sides.
-        Records the plan on the node (_exchange_plan), the module
-        counters, and the event bus (DenseExchangePlanned ->
-        MetricsListener) for observability."""
+        Records the plan on the node (_exchange_plan), the module counters,
+        the tally (exchange_plan_rounds, once a launch) and the event bus."""
         from vega_tpu.env import Env
         from vega_tpu.tpu import exchange_plan
 
@@ -3401,6 +3400,7 @@ class _ExchangeRDD(DenseRDD):
         )
         self._exchange_plan = plan
         exchange_plan.record_plan(plan)
+        spans.count("exchange_plan_rounds", plan.rounds)
         bus = getattr(self.context, "bus", None)
         if bus is not None:
             from vega_tpu.scheduler import events as ev

@@ -34,7 +34,11 @@ block `_settle_pending` rebuilt after a speculative launch overflowed);
 `exchange_rows` and `exchange_slots` (once a launch has succeeded, for each
 side that moved and whose row count the host already held: the rows it put
 into the exchange over all shards, and the `n_shards x out_cap` receive slots
-the program held for it; their quotient is how full the exchange ran).
+the program held for it; their quotient is how full the exchange ran);
+`exchange_plan_rounds` (once a launch across shards, the rounds of the plan
+`_resolve_exchange` gave it: 1 the one-shot `all_to_all`, `ceil((n - 1) /
+group)` for `staged`, `n - 1` for `ring`, so a traced window says which
+collective program ran; one shard plans nothing and adds nothing).
 
 Programs are rare (2-3 a run), so `programs()` is always recorded:
 {kind: {"mints", "first_call_s"}}, the second the host seconds of each minted
