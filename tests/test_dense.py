@@ -704,6 +704,197 @@ def test_dense_int64_key_roundtrip_and_encoding(dctx):
     )
 
 
+def _decode_i64_reference(hi, lo):
+    """The plain reference: block.decode_i64 as it stood before it wrote
+    each word once (five whole-column temporaries)."""
+    lo_u = (np.asarray(lo).view(np.uint32)
+            ^ np.uint32(0x80000000)).astype(np.int64)
+    return (np.asarray(hi).astype(np.int64) << 32) | lo_u
+
+
+_I64_MIN, _I64_MAX = -2**63, 2**63 - 1
+_DECODE_VALUES = {
+    "empty": [],
+    "one_row": [-3_000_000_007],
+    "int64_min_max": [_I64_MIN, _I64_MAX, _I64_MIN + 1, _I64_MAX - 1],
+    # true low words 0x7fffffff / 0x80000000 / 0xffffffff / 0: both sides
+    # of the stored word's flipped sign bit, under both signs of the high
+    "low_word_sign_bit": [2**31 - 1, 2**31, 2**32 - 1, 2**32, 0, -1,
+                          -2**31, -2**31 - 1, 5 * 2**32 + 2**31,
+                          -5 * 2**32 + 2**31 - 1],
+    # more than one chunk of the join, and not a multiple of it
+    "three_chunks": None,
+}
+
+
+def _decode_values(name):
+    if name == "three_chunks":
+        from vega_tpu.tpu import block as block_lib
+
+        n = 2 * block_lib._DECODE_CHUNK_ROWS + 12_345
+        return np.random.RandomState(7).randint(
+            _I64_MIN, _I64_MAX, size=n, dtype=np.int64)
+    return np.array(_DECODE_VALUES[name], dtype=np.int64)
+
+
+@pytest.mark.parametrize("inputs", ["contiguous", "strided", "read_only",
+                                    "uint32_lo", "lists_of_rows"])
+@pytest.mark.parametrize("values", sorted(_DECODE_VALUES))
+def test_decode_i64_equals_the_plain_formula(values, inputs):
+    """One pass, no 64-bit temporaries: the same int64s as the formula it
+    replaced, for every input the formula took."""
+    from vega_tpu.tpu import block as block_lib
+
+    want = _decode_values(values)
+    hi, lo = block_lib.encode_i64(want)
+    if inputs == "strided":  # every other element of a wider buffer
+        wide_hi = np.full(2 * len(hi) + 1, 99, np.int32)
+        wide_lo = np.full(2 * len(lo) + 1, 99, np.int32)
+        wide_hi[1::2], wide_lo[1::2] = hi, lo
+        hi, lo = wide_hi[1::2], wide_lo[1::2]
+        assert len(hi) < 2 or not hi.flags.c_contiguous
+    elif inputs == "read_only":  # what jax hands back from a fetch
+        hi.flags.writeable = lo.flags.writeable = False
+    elif inputs == "uint32_lo":
+        lo = lo.view(np.uint32)
+    elif inputs == "lists_of_rows":  # [rows, d]: a wide value column
+        if len(hi) % 2:
+            hi, lo, want = hi[:-1], lo[:-1], want[:-1]
+        hi, lo, want = (a.reshape(-1, 2) for a in (hi, lo, want))
+    before = (hi.copy(), lo.copy())
+    got = block_lib.decode_i64(hi, lo)
+    assert got.dtype == np.int64 and got.shape == want.shape
+    assert got.flags.c_contiguous and got.flags.writeable
+    np.testing.assert_array_equal(got, _decode_i64_reference(hi, lo))
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(hi, before[0])  # inputs untouched
+    np.testing.assert_array_equal(lo, before[1])
+    # out=: a slice of a larger result is filled in place, nothing beside it
+    larger = np.full((len(hi) + 5,) + hi.shape[1:], 42, np.int64)
+    dst = larger[3:3 + len(hi)]
+    assert block_lib.decode_i64(hi, lo, out=dst) is dst
+    np.testing.assert_array_equal(larger[3:3 + len(hi)], want)
+    assert (larger[:3] == 42).all() and (larger[3 + len(hi):] == 42).all()
+
+
+_TO_NUMPY_COUNTS = {
+    "1_shard": [77],
+    "1_shard_full": [128],
+    "2_shards_first_empty": [0, 100],
+    "2_shards_no_rows": [0, 0],
+    "8_shards_uneven": [3, 0, 128, 1, 0, 50, 127, 9],
+}
+
+
+def _uneven_block(counts, backing, cap=128):
+    """(block, reference columns): a wide key, a plain float, a wide value,
+    a dictionary column and a [rows, 3] column, `counts[s]` valid rows in
+    shard s of `cap` and a sentinel in every padding row. `backing`:
+    "device" (a shard a device, the mesh's layout), "one_device" (jax
+    columns that are not laid out a shard a device: fetched whole) or
+    "numpy" (a host-tier block behind _HostMeshStub)."""
+    import jax.numpy as jnp
+
+    from vega_tpu.tpu import block as block_lib
+    from vega_tpu.tpu import mesh as mesh_lib
+    from vega_tpu.tpu.dense_rdd import _HostMeshStub
+
+    counts = np.asarray(counts, np.int32)
+    n_shards, total = len(counts), int(counts.sum())
+    rng = np.random.RandomState(n_shards)
+    words = np.array(["ash", "birch", "cedar", "fir", "oak"])
+    ref = {
+        "k": rng.randint(_I64_MIN, _I64_MAX, size=total, dtype=np.int64),
+        "v": rng.rand(total).astype(np.float32),
+        "w": rng.randint(-2**40, 2**40, size=total, dtype=np.int64),
+        "s": words[rng.randint(0, len(words), size=total)],
+        "m": rng.randint(-9, 9, size=(total, 3)).astype(np.int32),
+    }
+    k_hi, k_lo = block_lib.encode_i64(ref["k"])
+    w_hi, w_lo = block_lib.encode_i64(ref["w"])
+    stored = {"k": k_hi, "k.lo": k_lo, "v": ref["v"], "w": w_hi,
+              "w.lo": w_lo,
+              "s": np.searchsorted(words, ref["s"]).astype(np.int32),
+              "m": ref["m"]}
+    mesh = (mesh_lib.make_mesh(n_shards) if backing == "device"
+            else _HostMeshStub(n_shards))
+    cols, at = {}, np.concatenate([[0], np.cumsum(counts)])
+    for name, src in stored.items():
+        dst = np.full((n_shards * cap,) + src.shape[1:], -7, src.dtype)
+        for s, c in enumerate(counts):
+            dst[s * cap:s * cap + c] = src[at[s]:at[s + 1]]
+        cols[name] = (mesh_lib.host_put(dst, mesh_lib.shard_spec(mesh))
+                      if backing == "device"
+                      else jnp.asarray(dst) if backing == "one_device"
+                      else dst)
+    blk = block_lib.Block(cols=cols, counts=counts, capacity=cap, mesh=mesh,
+                          counts_host=counts, dicts={"s": words})
+    return blk, ref
+
+
+@pytest.mark.parametrize("backing", ["device", "one_device", "numpy"])
+@pytest.mark.parametrize("counts", sorted(_TO_NUMPY_COUNTS))
+def test_block_to_numpy_fills_each_column_once(counts, backing):
+    """Valid rows only, shard order, schema order; int64 for a wide pair,
+    strings for a dictionary column, the column's own dtype otherwise; fresh
+    arrays that alias neither the block's columns nor each other's call."""
+    blk, ref = _uneven_block(_TO_NUMPY_COUNTS[counts], backing)
+    got = blk.to_numpy()
+    assert list(got) == ["k", "v", "w", "s", "m"]
+    for name, want in ref.items():
+        assert got[name].dtype == want.dtype and got[name].shape == want.shape
+        assert got[name].flags.c_contiguous and got[name].flags.writeable
+        assert got[name].flags.owndata
+        np.testing.assert_array_equal(got[name], want)
+        if backing == "numpy":
+            assert not np.shares_memory(got[name], blk.cols[name])
+    # shard_rows reads the same rows a shard at a time
+    for name, want in ref.items():
+        parts = [blk.shard_rows(s)[name] for s in range(blk.n_shards)]
+        np.testing.assert_array_equal(np.concatenate(parts), want)
+    # a caller that scribbles on its result changes no later read
+    for name in ("k", "v", "w", "m"):
+        got[name][...] = 0
+    again = blk.to_numpy()
+    for name, want in ref.items():
+        np.testing.assert_array_equal(again[name], want)
+
+
+@pytest.mark.parametrize("n_shards", [1, 8])
+def test_block_to_numpy_makes_no_whole_column_temporary(n_shards):
+    """The mechanism, pinned: a 1Mi-row wide-key block comes back at a peak
+    of its own result's bytes (a quarter of room; the slice lists,
+    np.concatenate and the five-temporary int64 join it replaced peak at
+    three times that, by this test on the parent). On the CPU mesh a
+    fetched column is a view of the device's buffer, which tracemalloc does
+    not count."""
+    import tracemalloc
+
+    from vega_tpu.tpu import block as block_lib
+    from vega_tpu.tpu import mesh as mesh_lib
+
+    n = 1 << 20
+    rng = np.random.RandomState(3)
+    keys = rng.randint(_I64_MIN, _I64_MAX, size=n, dtype=np.int64)
+    vals = rng.rand(n).astype(np.float32)
+    blk = block_lib.from_numpy({"k": keys, "v": vals},
+                               mesh_lib.make_mesh(n_shards))
+    assert "k.lo" in blk.cols
+    blk.to_numpy()  # counts and the fetch are the block's from here on
+    tracemalloc.start()
+    try:
+        base = tracemalloc.get_traced_memory()[0]
+        got = blk.to_numpy()
+        peak = tracemalloc.get_traced_memory()[1] - base
+    finally:
+        tracemalloc.stop()
+    returned = sum(col.nbytes for col in got.values())
+    assert returned == n * (8 + 4)
+    np.testing.assert_array_equal(got["k"], keys)
+    np.testing.assert_array_equal(got["v"], vals)
+    assert peak <= 1.25 * returned, (peak, returned)
+
+
 def test_dense_int64_key_reduce_group_parity(dctx):
     keys, vals = _i64_fixture(1)
     d = dctx.dense_from_numpy(keys, vals)

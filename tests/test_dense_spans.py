@@ -103,6 +103,30 @@ def test_on_tallies_decode_and_pivot(dctx, session):
     assert tally["fetch"]["count"] >= 1 and tally["launch"]["count"] >= 2
 
 
+def test_collect_arrays_is_one_fetch_across_shards(dctx, on_devices, session):
+    """to_numpy reads every shard's own buffer of every column in ONE
+    host_get: a 4-shard block is one `fetch` span (one blocking round trip)
+    of the block's whole bytes, and one `decode` of its valid rows."""
+    from vega_tpu.tpu import spans
+
+    on_devices(4)
+    keys = (np.arange(N, dtype=np.int64) * 2654435761) % (2**40)
+    vals = np.arange(N, dtype=np.float64)
+    pairs = dctx.dense_from_numpy(keys, vals)
+    blk = pairs.block()
+    assert blk.n_shards == 4 and sorted(blk.cols) == ["k", "k.lo", "v"]
+    with session:
+        cols = pairs.collect_arrays()
+    assert np.array_equal(cols["k"], keys)
+    assert np.array_equal(cols["v"], vals.astype(np.float32))
+    tally = spans.session()
+    assert tally["fetch"]["count"] == 1
+    assert tally["fetch"]["bytes"] == blk.nbytes
+    assert tally["decode"]["count"] == 1
+    assert tally["decode"]["bytes"] == N * (8 + 4)
+    assert "launch" not in tally and spans.nested() == 0
+
+
 @pytest.mark.parametrize("name", sorted(LINEAGES))
 def test_spans_are_flat_and_change_no_result(dctx, session, name):
     from vega_tpu.tpu import spans

@@ -13,6 +13,7 @@ Layout: each column is [n_shards * capacity, ...] sharded on axis 0; rows
 from __future__ import annotations
 
 import dataclasses
+import sys
 from typing import Dict, List, Optional
 
 import jax
@@ -38,6 +39,13 @@ VALUE = "v"  # canonical value column
 LO_SUFFIX = ".lo"
 KEY_LO = KEY + LO_SUFFIX
 _LO_BIAS = np.uint32(0x80000000)
+_LO_BIAS_I32 = np.int32(-2**31)  # the same bit, for int32 words
+# Where an int64's high and low 32 bits sit among its two int32 words.
+_LO_WORD, _HI_WORD = (0, 1) if sys.byteorder == "little" else (1, 0)
+# decode_i64 joins this many rows at a time, so that a chunk of `out`
+# is still in cache when its second word is written (64Mi rows: 0.22 s
+# against 0.33 s unchunked on the host that sized it).
+_DECODE_CHUNK_ROWS = 1 << 18
 
 
 def lo_of(name: str) -> str:
@@ -64,10 +72,23 @@ def encode_i64(src: np.ndarray):
     return hi, lo
 
 
-def decode_i64(hi: np.ndarray, lo: np.ndarray) -> np.ndarray:
-    """Inverse of encode_i64."""
-    lo_u = (np.asarray(lo).view(np.uint32) ^ _LO_BIAS).astype(np.int64)
-    return (np.asarray(hi).astype(np.int64) << 32) | lo_u
+def decode_i64(hi: np.ndarray, lo: np.ndarray,
+               out: Optional[np.ndarray] = None) -> np.ndarray:
+    """Inverse of encode_i64, in one pass: an int64's two halves ARE the
+    two stored words (`lo` with its sign bit flipped back), so each word
+    is written straight into its half of `out` — no 64-bit temporaries.
+    `out` (C-contiguous int64 of hi's shape, e.g. a slice of a larger
+    result) is filled in place and returned; a fresh array otherwise."""
+    hi = np.asarray(hi)
+    lo = np.asarray(lo).view(np.int32)
+    if out is None:
+        out = np.empty(hi.shape, np.int64)
+    words = out.view(np.int32).reshape(out.shape + (2,))
+    for at in range(0, len(hi), _DECODE_CHUNK_ROWS):
+        rows = slice(at, at + _DECODE_CHUNK_ROWS)
+        words[rows, ..., _HI_WORD] = hi[rows]
+        np.bitwise_xor(lo[rows], _LO_BIAS_I32, out=words[rows, ..., _LO_WORD])
+    return out
 
 
 def _decode_key_cols(cols: dict) -> dict:
@@ -95,6 +116,19 @@ def _decode_dict_cols(cols: dict, dicts) -> dict:
         return cols
     return {name: (dicts[name][np.asarray(col)] if name in dicts else col)
             for name, col in cols.items()}
+
+
+def _shard_buffers(col, capacity: int):
+    """The single-device buffers behind a fully addressable jax.Array
+    column, placed by the rows each holds (not by list order): entry s
+    is block shard s's `capacity` rows. None where the column is not a
+    jax.Array laid out one block shard a device."""
+    if not isinstance(col, jax.Array) \
+            or col.sharding.shard_shape(col.shape)[0] != capacity:
+        return None
+    by_shard = {(sh.index[0].start or 0) // capacity: sh.data
+                for sh in col.addressable_shards}
+    return [by_shard[s] for s in range(len(by_shard))]
 
 
 @dataclasses.dataclass
@@ -175,32 +209,56 @@ class Block:
         return sum(int(np.prod(c.shape)) * c.dtype.itemsize
                    for c in self.cols.values())
 
+    def _host_shards(self) -> Dict[str, List[np.ndarray]]:
+        """{column: [shard s's `capacity` host rows, for every s]}, all
+        columns in ONE transfer (a device_get per column is a blocking
+        round trip each). A column laid out a shard a device is read as
+        its shards' own host buffers, so nothing assembles a global host
+        array only for to_numpy to slice it apart again; any other is
+        fetched whole and sliced here, as views. Multi-process: share
+        shard_rows' replicated cache — each miss is a full-block
+        all-gather."""
+        cap, n = self.capacity, self.n_shards
+        first = next(iter(self.cols.values()), None)
+        if isinstance(first, jax.Array) and not first.is_fully_addressable:
+            fetched = self.host_cols()
+        else:
+            fetched = mesh_lib.host_get({
+                name: _shard_buffers(col, cap) or col
+                for name, col in self.cols.items()})
+        return {name: got if isinstance(got, list) else
+                [np.asarray(got)[s * cap:(s + 1) * cap] for s in range(n)]
+                for name, got in fetched.items()}
+
     def to_numpy(self) -> Dict[str, np.ndarray]:
         """Gather valid rows to host, shard order preserved. Two-column
         int64 keys (KEY_LO) come back as one int64 KEY column — host-facing
-        consumers never see the encoding."""
+        consumers never see the encoding. Every output column is allocated
+        once at sum(counts) rows and each of its bytes written once, a
+        shard at a time: a plain column by one slice assignment, a wide
+        (name, name.lo) pair joined by decode_i64 straight into place."""
         counts = self.counts_np
-        # One transfer for every column (a separate device_get per column
-        # is a blocking round trip each). Multi-process:
-        # share shard_rows' replicated cache — each miss is a full-block
-        # all-gather.
-        first = next(iter(self.cols.values()), None)
-        if isinstance(first, jax.Array) and not first.is_fully_addressable:
-            host_cols = self.host_cols()
-        else:
-            host_cols = {name: np.asarray(c) for name, c in
-                         mesh_lib.host_get(dict(self.cols)).items()}
+        shards = self._host_shards()
         with spans.span("decode") as sp:
-            out: Dict[str, List[np.ndarray]] = {n: [] for n in self.cols}
+            total = int(np.sum(counts))
+            out, fills = {}, []  # fills: (dst, its shards, its .lo's or None)
+            for name, col in self.cols.items():
+                if is_lo(name):
+                    continue
+                lo = shards.get(lo_of(name))
+                out[name] = np.empty((total,) + col.shape[1:],
+                                     col.dtype if lo is None else np.int64)
+                fills.append((out[name], shards[name], lo))
+            at = 0
             for s in range(self.n_shards):
-                lo = s * self.capacity
                 c = int(counts[s])
-                for name in self.cols:
-                    out[name].append(host_cols[name][lo:lo + c])
-            gathered = {n: np.concatenate(parts) if parts else np.empty((0,))
-                        for n, parts in out.items()}
-            decoded = _decode_dict_cols(_decode_key_cols(gathered),
-                                        self.dicts)
+                for dst, src, lo in fills:
+                    if lo is None:
+                        dst[at:at + c] = src[s][:c]
+                    else:
+                        decode_i64(src[s][:c], lo[s][:c], out=dst[at:at + c])
+                at += c
+            decoded = _decode_dict_cols(out, self.dicts)
             sp.nbytes = sum(c.nbytes for c in decoded.values())
             return decoded
 
