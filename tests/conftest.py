@@ -98,9 +98,10 @@ def _drop_compiled_programs():
     yield
     import jax
 
-    from vega_tpu.tpu import dense_rdd
+    from vega_tpu.tpu import dense_rdd, spans
 
     dense_rdd._PROGRAM_CACHE.clear()
+    spans.forget_lowered()  # each keeps its program's executable alive
     jax.clear_caches()
 
 

@@ -260,8 +260,12 @@ def test_first_call_is_timed_once_under_threads():
         assert first_call_s > 0
         launch(3)
         assert spans.programs()[kind]["first_call_s"] == first_call_s
+        # a program that cannot be lowered again: counted, no rows, no error
+        table = spans.program_stages()[kind]
+        assert (table["programs"], table["ops"]) == (1, [])
     finally:
         spans._programs.pop(kind)
+        spans._stage_tables.pop(kind)
 
 
 def test_host_only_summary_imports_no_jax():
@@ -276,7 +280,8 @@ def test_host_only_summary_imports_no_jax():
         "    assert ctx.parallelize(range(10), 2).map(lambda x: x + 1)"
         ".collect()[-1] == 10\n"
         "    dense = ctx.metrics_summary()['dense_spans']\n"
-        "assert dense == {'session': {}, 'programs': {}}, dense\n"
+        "assert dense == {'session': {}, 'programs': {},"
+        " 'program_stages': {}}, dense\n"
         "assert 'vega_tpu.tpu.spans' in sys.modules\n"
         "assert 'jax' not in sys.modules\n")
     p = subprocess.run([sys.executable, "-c", code], capture_output=True,
@@ -284,7 +289,7 @@ def test_host_only_summary_imports_no_jax():
     assert p.returncode == 0, p.stderr[-2000:]
 
 
-def test_metrics_summary_has_both(dctx, session):
+def test_metrics_summary_has_all_three(dctx, session):
     from vega_tpu.tpu import spans
 
     pairs, table = _sources(dctx)
@@ -292,7 +297,10 @@ def test_metrics_summary_has_both(dctx, session):
         LINEAGES["scan"](pairs, table)
     dense = dctx.metrics_summary()["dense_spans"]
     assert dense == {"session": spans.session(),
-                     "programs": spans.programs()}
+                     "programs": spans.programs(),
+                     "program_stages": spans.program_stages()}
+    assert "narrow" in {row["stage"] for row in
+                        dense["program_stages"]["narrow"]["ops"]}
     assert dense["session"]["launch"]["count"] == 2
     assert dense["programs"]["narrow"]["mints"] >= 1
     # copies: an edit does not reach the tally
@@ -360,3 +368,237 @@ def test_exchange_plan_rounds_an_action(dctx, on_devices, session, devices,
     before = spans.session()
     pairs.sort_by_key(exchange=exchange).collect_arrays()
     assert spans.session() == before
+
+
+# ---- stages: the device side by the program's own names --------------------
+
+HLO = """\
+HloModule jit_prog_fn.5ef7b579, entry_computation_layout={(s32[8]{0})->s32[8]{0}}
+
+%fused_computation (param_0: s32[8]) -> s32[8] {
+  %param_0 = s32[8]{0} parameter(0)
+  ROOT %add.1 = s32[8]{0} add(%param_0, %param_0), metadata={op_name="jit(f)/vega.narrow/add"}
+}
+
+%region_0.1 (a: s32[], b: s32[]) -> pred[] {
+  %a = s32[] parameter(0)
+  %b = s32[] parameter(1)
+  ROOT %lt = pred[] compare(%a, %b), direction=LT, metadata={op_name="jit(f)/vega.key_sort/sort"}
+}
+
+%body (p: (s32[8])) -> (s32[8]) {
+  %p = (s32[8]{0}) parameter(0)
+  %gte = s32[8]{0} get-tuple-element(%p), index=0
+  %copy.3 = s32[8]{0:T(1024)} copy(%gte), metadata={op_name="jit(f)/while/body/vega.exchange_send/copy"}
+  ROOT %tuple.2 = (s32[8]{0}) tuple(%copy.3)
+}
+
+ENTRY %main.9 (x: s32[8]) -> s32[8] {
+  %x = s32[8]{0} parameter(0), metadata={op_name="x"}
+  %fusion.1 = s32[8]{0:T(1024)} fusion(%x), kind=kLoop, calls=%fused_computation, metadata={op_name="jit(f)/vega.segment_reduce/vega.key_sort/add" stack_frame_id=3}, backend_config={"kind":"x"}
+  %sort.2 = (s32[8]{0}, s32[8]{0}) sort(%fusion.1, %x), dimensions={0}, is_stable=true, to_apply=%region_0.1, metadata={op_name="jit(f)/vega.exchange_compact/scatter"}
+  %while.4 = (s32[8]{0}) while(%sort.2), condition=%cond, body=%body
+  %bitcast.7 = s32[8]{0} bitcast(%x), metadata={op_name="jit(f)/vega.not_a_stage/reshape"}
+  ROOT %copy.5 = s32[8]{0} copy(%bitcast.7), metadata={op_name="jit(f)/reshape"}
+}
+"""
+
+
+def test_parse_stages_reads_a_compiled_text():
+    """The innermost `vega.` scope is the stage; fusion bodies and applied
+    comparators are left out, a while's body is not; parameters and tuples
+    are no steps; a scope STAGES does not list is no stage; the four words
+    are what trace_reduce.short_name keeps of the same line."""
+    from vega_tpu.tpu import spans
+
+    rows = {row["op"]: row for row in spans.parse_stages(HLO)}
+    assert sorted(rows) == ["bitcast.7", "copy.3", "copy.5", "fusion.1",
+                            "sort.2", "while.4"]
+    assert rows["fusion.1"] == {"op": "fusion.1", "shape": "s32[8]",
+                                "opcode": "fusion", "kind": "kLoop",
+                                "stage": "key_sort",
+                                "key": "fusion.1 s32[8] fusion kLoop(x)"}
+    assert rows["sort.2"] == {"op": "sort.2", "shape": "s32[8]",
+                              "opcode": "sort", "kind": "",
+                              "stage": "exchange_compact",
+                              "key": "sort.2 s32[8] sort(fusion.1,x)"}
+    assert rows["copy.3"]["stage"] == "exchange_send"
+    # a profile names the event by the same text, operand shapes included
+    assert spans.instruction_key(
+        "%sort.2 = (s32[8]{0:T(1024)}, s32[8]{0:T(1024)}) sort("
+        "s32[8]{0:T(1024)} %fusion.1, s32[8]{0:T(1024)} %x), dimensions={0}"
+    ) == rows["sort.2"]["key"]
+    assert [rows[op]["stage"] for op in ("while.4", "bitcast.7", "copy.5")] \
+        == [None, None, None]
+
+
+def test_an_unlisted_stage_is_refused():
+    from vega_tpu.tpu import spans
+
+    with pytest.raises(ValueError, match="no stage 'shuffle'"):
+        spans.stage("shuffle")
+    with pytest.raises(ValueError):
+        spans.stage("vega.key_sort")
+    assert isinstance(spans.stage_placement(), str) \
+        and len(spans.stage_placement()) == 8
+
+
+@pytest.fixture()
+def fresh_programs(monkeypatch):
+    """Every program of the test is minted anew and the stage tables hold
+    those programs alone."""
+    from vega_tpu.tpu import dense_rdd, spans
+
+    monkeypatch.setattr(dense_rdd, "_PROGRAM_CACHE", {})
+    monkeypatch.setattr(spans, "_lowered", {})
+    monkeypatch.setattr(spans, "_stage_tables", {})
+    return spans
+
+
+class _Compiles:
+    """Counts the compiles asked of jax while it is `on`."""
+
+    def __init__(self):
+        import jax.monitoring as monitoring
+
+        self.on, self.count = False, 0
+        monitoring.register_event_duration_secs_listener(self)
+
+    def __call__(self, event, _secs, **_kw):
+        if self.on and event == "/jax/core/compile/backend_compile_duration":
+            self.count += 1
+
+
+PIPELINES = {
+    # devices, the stages its programs must name
+    "reduce_join": (4, {"key_sort", "segment_reduce", "merge_join",
+                        "exchange_group", "exchange_send", "exchange_wire",
+                        "exchange_compact"}),
+    "sort_take_ordered": (4, {"sample", "exchange_group", "exchange_send",
+                              "exchange_wire", "exchange_compact",
+                              "key_sort", "topk"}),
+    "filter_count": (4, {"narrow", "named_reduce"}),
+    "one_shard": (1, {"exchange_compact", "key_sort", "segment_reduce",
+                      "merge_join", "topk"}),
+    "ring": (4, {"exchange_group", "exchange_send", "exchange_wire",
+                 "exchange_compact", "key_sort"}),
+}
+
+
+def _run_pipeline(name, pairs, table):
+    if name in ("reduce_join", "one_shard"):
+        out = sorted(pairs.reduce_by_key(op="add").join(table).collect())
+        assert len(out) == KEYS
+    if name in ("sort_take_ordered", "one_shard"):
+        cols = pairs.sort_by_key().collect_arrays()
+        assert np.array_equal(cols["k"], np.sort(cols["k"]))
+        assert pairs.take_ordered(5) == sorted(pairs.collect())[:5]
+    if name == "filter_count":
+        assert pairs.filter(lambda kv: kv[1] >= 3).count() > 0
+        assert LINEAGES["scan"](pairs, table) > 0
+    if name == "ring":
+        cols = pairs.sort_by_key(exchange="ring").collect_arrays()
+        assert np.array_equal(cols["k"], np.sort(cols["k"]))
+
+
+@pytest.mark.parametrize("name", sorted(PIPELINES))
+def test_stage_tables_of_a_pipeline(dctx, on_devices, fresh_programs, name):
+    """Every stage a pipeline reaches is named in some minted program's
+    table, no instruction has a stage outside STAGES, and asking for the
+    tables compiles nothing and mints nothing."""
+    from vega_tpu.tpu import dense_rdd
+
+    spans = fresh_programs
+    devices, reached = PIPELINES[name]
+    on_devices(devices)
+    pairs, table = _sources(dctx)
+    before = spans.programs()
+    _run_pipeline(name, pairs, table)
+    minted = {kind for kind, p in spans.programs().items()
+              if p["mints"] > before.get(kind, {"mints": 0})["mints"]}
+    mints = dense_rdd.program_mints()
+    compiles = _Compiles()
+    compiles.on = True
+    tables = spans.program_stages()
+    again = spans.program_stages()
+    compiles.on = False
+    assert compiles.count == 0
+    assert dense_rdd.program_mints() == mints
+    assert again == tables
+    assert set(tables) == minted  # every minted program left its lowering
+    seen = {row["stage"] for t in tables.values() for row in t["ops"]}
+    assert reached <= seen, reached - seen
+    assert seen - {None} <= set(spans.STAGES)
+    for kind, t in tables.items():
+        assert t["programs"] >= 1 and t["ops"] and t["parse_s"] > 0, kind
+        assert {"temp_bytes", "argument_bytes", "output_bytes"} <= set(t)
+        assert all(sorted(row) == ["key", "kind", "op", "opcode", "shape",
+                                   "stage"] for row in t["ops"])
+        assert len({row["key"] for row in t["ops"]}) == len(t["ops"])
+    assert spans.nested() == 0
+    assert dctx.metrics_summary()["dense_spans"]["program_stages"] == tables
+
+
+def test_a_scatter_and_its_compaction_keep_their_stage(dctx, on_devices,
+                                                       fresh_programs):
+    """The segment reduce's key compaction is the segment reduce's, the
+    passthrough's is the exchange's: `compact` itself carries no scope."""
+    on_devices(1)
+    pairs, _table = _sources(dctx)
+    pairs.reduce_by_key(op="add").collect()
+    ops = fresh_programs.program_stages()["rbk"]["ops"]
+    by_stage = {}
+    for row in ops:
+        by_stage.setdefault(row["stage"], set()).add(row["opcode"])
+    assert "sort" in by_stage["key_sort"]
+    assert by_stage["segment_reduce"] and by_stage["exchange_compact"]
+
+
+def test_only_the_first_call_lowers_again():
+    """The first call of a minted program leaves its lowering; later calls
+    take the plain path (the `first` lock is spent). A `lower` that raises
+    leaves an empty table and no error."""
+    from vega_tpu.tpu import dense_rdd, spans
+
+    lowered = []
+
+    class Prog:
+        def __init__(self, fail):
+            self.fail = fail
+
+        def __call__(self, x):
+            return x + 1
+
+        def lower(self, *args):
+            lowered.append(args)
+            if self.fail:
+                raise RuntimeError("cannot lower")
+            return "a lowering that cannot be compiled"
+
+    for kind, fail in (("test_lowers_once", False), ("test_lower_fails", True)):
+        launch = dense_rdd._spanned_program(kind, Prog(fail))
+        spans.program_minted(kind)
+        try:
+            assert [launch(1), launch(2), launch(3)] == [2, 3, 4]
+            assert lowered == [(1,)]
+            table = spans.program_stages()[kind]
+            assert (table["programs"], table["ops"]) == (1, [])
+        finally:
+            lowered.clear()
+            spans._programs.pop(kind)
+            spans._stage_tables.pop(kind)
+
+
+def test_put_counts_the_bytes_of_a_value_without_nbytes(dctx, session):
+    from vega_tpu.tpu import mesh as mesh_lib
+    from vega_tpu.tpu import spans
+
+    repl = mesh_lib.replicated_spec(mesh_lib.default_mesh())
+    spans.new_session()
+    with session:
+        mesh_lib.host_put([1, 2, 3], repl)
+        mesh_lib.host_put(np.arange(5, dtype=np.int32), repl)
+        mesh_lib.host_put(np.zeros(0, np.int32), repl)
+    put = spans.session()["put"]
+    assert put["count"] == 3
+    assert put["bytes"] == np.asarray([1, 2, 3]).nbytes + 20

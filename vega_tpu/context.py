@@ -469,16 +469,19 @@ class Context:
     def metrics_summary(self) -> dict:
         """The event bus's counters, and under "dense_spans" what the
         dense tier's host side did: "session", the spans tallied under the
-        newest profiler session (see `profiler`), and "programs", the shard
+        newest profiler session (see `profiler`), "programs", the shard
         programs minted by kind with the host seconds of their first
-        calls."""
+        calls, and "program_stages", each kind's compiled instructions by
+        the stage that wrote them (spans.program_stages: what a device
+        profile's operations are joined with)."""
         if not self.bus.flush():
             log.warning("event bus flush timed out; metrics may lag")
         from vega_tpu.tpu import spans  # imports no jax
 
         summary = self.metrics.summary()
         summary["dense_spans"] = {"session": spans.session(),
-                                  "programs": spans.programs()}
+                                  "programs": spans.programs(),
+                                  "program_stages": spans.program_stages()}
         return summary
 
     def fleet_status(self) -> dict:

@@ -80,16 +80,18 @@ def _canonical_value_layout(schema) -> bool:
 
 
 def _shard_program(mesh, fn, in_specs, out_specs):
-    """jit(shard_map(fn))."""
+    """jit(shard_map(fn)), named `<fn>.<spans.stage_placement()>`: the
+    compile cache then never hands a tree an executable traced under another
+    tree's stage scopes (the cache's key leaves metadata out, names in)."""
     from vega_tpu.tpu import compat
 
     if isinstance(in_specs, int):
         in_specs = (_SPEC,) * in_specs
-    return jax.jit(
-        compat.shard_map(
-            fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        )
+    mapped = compat.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
     )
+    mapped.__name__ = f"{fn.__name__}.{spans.stage_placement()}"
+    return jax.jit(mapped)
 
 
 # Structural program cache: identical pipelines (same op kinds, same closure
@@ -121,20 +123,35 @@ def _fp(obj) -> str:
             return f"id:{id(obj)}"
 
 
+def _lowered_again(prog, args):
+    """`prog.lower(*args)` once `prog(*args)` has returned: jax's trace and
+    lowering caches serve it (0.4 ms, nothing compiles), and the result pins
+    no array. None where `prog` cannot be lowered again: the stage table is
+    an extra, never an error on the launch path."""
+    try:
+        return prog.lower(*args)
+    except Exception:  # noqa: BLE001 — see above
+        return None
+
+
 def _spanned_program(kind: str, prog):
     """`prog` under a `launch <kind>` span: the host's dispatch of one shard
     program. The first call (trace, lower, compile or persistent-cache
     load, dispatch) is timed into spans.programs() whether or not a
-    profiler session runs: it happens once a program."""
+    profiler session runs: it happens once a program. That call also leaves
+    its lowering with spans.program_stages(), which reads the program's
+    stages off the compiled text when someone asks."""
     first = threading.Lock()  # taken by the first call, never released
 
     def launch(*args):
         if not first.locked() and first.acquire(blocking=False):
             t0 = time.perf_counter()
             try:
-                return launch(*args)  # `first` is spent: the plain path
+                out = launch(*args)  # `first` is spent: the plain path
             finally:
                 spans.program_first_call(kind, time.perf_counter() - t0)
+            spans.program_lowered(kind, _lowered_again(prog, args))
+            return out
         with spans.span("launch", kind):
             return prog(*args)
 
@@ -1702,6 +1719,7 @@ class DenseRDD(RDD):
         blk = self.block()
         k = min(n, blk.capacity)
 
+        @spans.stage("topk")
         def shard_topk(vals, counts):
             mask = kernels.valid_mask(vals.shape[0], counts[0])
             if largest:
@@ -1777,10 +1795,12 @@ class DenseRDD(RDD):
                     operands.append(flipped)
                 else:
                     operands.append(c)
-            out = lax.sort(tuple(operands), num_keys=len(operands),
-                           is_stable=True)
+            with spans.stage("key_sort"):
+                out = lax.sort(tuple(operands), num_keys=len(operands),
+                               is_stable=True)
             n_valid = jnp.minimum(counts[0], k).reshape(1)
-            return (n_valid,) + tuple(o[:k] for o in out[1:])
+            with spans.stage("topk"):
+                return (n_valid,) + tuple(o[:k] for o in out[1:])
 
         prog = _cached_program(
             ("topk_rows", self.mesh, tuple(names), k, largest,
@@ -3149,6 +3169,7 @@ def _narrow_chain(node):
     return chain, cur
 
 
+@spans.stage("narrow")
 def _apply_chain(chain, cols, count):
     for nd in chain:
         cols, count = nd._shard_fn(cols, count)
@@ -3159,6 +3180,7 @@ def _chain_fp(chain) -> tuple:
     return tuple(nd._node_fp() for nd in chain)
 
 
+@spans.stage("exchange_group")
 def _bucket_cols(cols, n: int) -> jax.Array:
     """Hash-bucket rows by key, two-column int64 keys included. The
     composite hash mixes BOTH words (hash32_pair) so placement keeps its
@@ -3433,6 +3455,7 @@ class _ExchangeRDD(DenseRDD):
         else:
             in_names = [KEY] + ([KEY_LO] if KEY_LO in blk.cols else [])
 
+        @spans.stage("exchange_group")
         def prog_fn(counts, *col_arrays):
             cols = dict(zip(in_names, col_arrays))
             cols, count = _apply_chain(chain, cols, counts[0])
@@ -3465,6 +3488,7 @@ class _ExchangeRDD(DenseRDD):
         else:
             in_names = [KEY] + ([KEY_LO] if composite else [])
 
+        @spans.stage("exchange_group")
         def prog_fn(*args):
             n_bounds = 1 + composite
             bnds = args[0]
@@ -4483,6 +4507,7 @@ class _SortByKeyRDD(_ExchangeRDD):
         m = max(1, self.sample_size // max(1, blk.n_shards))
         samp_cap = blk.capacity  # plain int: samp_fn must not pin the Block
 
+        @spans.stage("sample")
         def samp_fn(counts_arg, *col_arrays):
             cols, count = _apply_chain(
                 chain, dict(zip(samp_in, col_arrays)), counts_arg[0]

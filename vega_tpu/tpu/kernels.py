@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from vega_tpu.tpu.mesh import SHARD_AXIS
+from vega_tpu.tpu.spans import stage
 
 Cols = Dict[str, jax.Array]
 
@@ -115,6 +116,7 @@ def compact(cols: Cols, keep: jax.Array, out_capacity: int) -> Tuple[Cols, jax.A
     return out, jnp.sum(keep).astype(jnp.int32)
 
 
+@stage("key_sort")
 def sort_carrying(keys, cols: Cols):
     """One stable lax.sort by `keys` (a tuple of 1-D key words, major first)
     that moves the rows of `cols` with them. Returns (sorted keys, cols).
@@ -149,6 +151,7 @@ def sort_carrying(keys, cols: Cols):
 # ---------------------------------------------------------------------------
 
 
+@stage("exchange_compact")
 def passthrough_exchange(cols: Cols, count: jax.Array, capacity: int,
                          out_capacity: int):
     """Single-shard fast path shared by every exchange implementation: the
@@ -158,6 +161,7 @@ def passthrough_exchange(cols: Cols, count: jax.Array, capacity: int,
     return out, new_count, new_count > out_capacity
 
 
+@stage("exchange_group")
 def _group_by_bucket(cols: Cols, bucket: jax.Array, n_shards: int,
                      prefer_low_memory: bool = False):
     """Stable-group rows by target bucket; returns (grouped cols,
@@ -226,6 +230,7 @@ def bucket_key_sort(cols: Cols, bucket: jax.Array, key_name: str,
     return out, sorted_bucket
 
 
+@stage("exchange_group")
 def range_bucket(bounds: jax.Array, keys: jax.Array,
                  ascending: bool, bounds_lo: jax.Array = None,
                  keys_lo: jax.Array = None) -> jax.Array:
@@ -250,6 +255,7 @@ def range_bucket(bounds: jax.Array, keys: jax.Array,
     return searchsorted2(bounds, bounds_lo, keys, keys_lo).astype(jnp.int32)
 
 
+@stage("exchange_group")
 def pregrouped_group(bucket: jax.Array, n_shards: int):
     """(counts_to, starts) for rows already contiguous per bucket — the
     histogram shortcut both exchanges use instead of _group_by_bucket."""
@@ -283,43 +289,51 @@ def bucket_exchange(
     capacity = bucket.shape[0]
     if n_shards == 1:
         return passthrough_exchange(cols, count, capacity, out_capacity)
-    mask = valid_mask(capacity, count)
-    bucket = jnp.where(mask, bucket, n_shards)  # invalid rows -> ghost bucket
+    with stage("exchange_group"):
+        mask = valid_mask(capacity, count)
+        bucket = jnp.where(mask, bucket, n_shards)  # invalid rows -> ghost
 
-    if pregrouped:
-        counts_to, starts = pregrouped_group(bucket, n_shards)
-        sorted_cols = cols
-    else:
-        sorted_cols, counts_to, starts = _group_by_bucket(
-            cols, bucket, n_shards)
-    overflow_send = jnp.any(counts_to > slot_capacity)
+        if pregrouped:
+            counts_to, starts = pregrouped_group(bucket, n_shards)
+            sorted_cols = cols
+        else:
+            sorted_cols, counts_to, starts = _group_by_bucket(
+                cols, bucket, n_shards)
+        overflow_send = jnp.any(counts_to > slot_capacity)
 
     # Build [n_shards, slot_capacity] send buffers per column.
-    slot_rows = starts[:, None] + jnp.arange(slot_capacity)[None, :]
-    slot_valid = jnp.arange(slot_capacity)[None, :] < counts_to[:, None]
-    slot_rows = jnp.clip(slot_rows, 0, capacity - 1)
-
-    send_counts = jnp.minimum(counts_to, slot_capacity).astype(jnp.int32)
-    recv_counts = lax.all_to_all(
-        send_counts, SHARD_AXIS, split_axis=0, concat_axis=0
-    )
+    with stage("exchange_send"):
+        slot_rows = starts[:, None] + jnp.arange(slot_capacity)[None, :]
+        slot_valid = jnp.arange(slot_capacity)[None, :] < counts_to[:, None]
+        slot_rows = jnp.clip(slot_rows, 0, capacity - 1)
+        send_counts = jnp.minimum(counts_to, slot_capacity).astype(jnp.int32)
+    with stage("exchange_wire"):
+        recv_counts = lax.all_to_all(
+            send_counts, SHARD_AXIS, split_axis=0, concat_axis=0
+        )
 
     received: Cols = {}
     for name, col in sorted_cols.items():
-        buf = jnp.take(col, slot_rows, axis=0)  # [n_shards, C, ...]
-        zero = jnp.zeros((), dtype=col.dtype)
-        expand = slot_valid.reshape(slot_valid.shape + (1,) * (buf.ndim - 2))
-        buf = jnp.where(expand, buf, zero)
-        got = lax.all_to_all(buf, SHARD_AXIS, split_axis=0, concat_axis=0)
-        received[name] = got.reshape((n_shards * slot_capacity,) + got.shape[2:])
+        with stage("exchange_send"):
+            buf = jnp.take(col, slot_rows, axis=0)  # [n_shards, C, ...]
+            zero = jnp.zeros((), dtype=col.dtype)
+            expand = slot_valid.reshape(
+                slot_valid.shape + (1,) * (buf.ndim - 2))
+            buf = jnp.where(expand, buf, zero)
+        with stage("exchange_wire"):
+            got = lax.all_to_all(buf, SHARD_AXIS, split_axis=0,
+                                 concat_axis=0)
+            received[name] = got.reshape(
+                (n_shards * slot_capacity,) + got.shape[2:])
 
-    recv_valid = (
-        jnp.arange(slot_capacity)[None, :] < recv_counts[:, None]
-    ).reshape(-1)
-    new_count = jnp.sum(recv_counts).astype(jnp.int32)
-    overflow_recv = new_count > out_capacity
-    out_cols, _ = compact(received, recv_valid, out_capacity)
-    return out_cols, new_count, overflow_send | overflow_recv
+    with stage("exchange_compact"):
+        recv_valid = (
+            jnp.arange(slot_capacity)[None, :] < recv_counts[:, None]
+        ).reshape(-1)
+        new_count = jnp.sum(recv_counts).astype(jnp.int32)
+        overflow_recv = new_count > out_capacity
+        out_cols, _ = compact(received, recv_valid, out_capacity)
+        return out_cols, new_count, overflow_send | overflow_recv
 
 
 # ---------------------------------------------------------------------------
@@ -327,6 +341,7 @@ def bucket_exchange(
 # ---------------------------------------------------------------------------
 
 
+@stage("key_sort")
 def sort_by_column(cols: Cols, count: jax.Array, key_name: str,
                    descending: bool = False, lo_name: str = None) -> Cols:
     """Stable sort valid rows by one column (or a (key, lo) two-column
@@ -412,6 +427,7 @@ def _orderable_max(key: jax.Array):
     return jnp.array(jnp.iinfo(key.dtype).max, dtype=key.dtype)
 
 
+@stage("segment_reduce")
 def segment_reduce_sorted(
     cols: Cols,
     count: jax.Array,
@@ -516,6 +532,7 @@ def _segment_totals_blocked(vals: jax.Array, first: jax.Array,
     return jnp.where(ends, v.reshape(-1)[:n], 0)
 
 
+@stage("segment_reduce")
 def segment_reduce_named(
     cols: Cols, count: jax.Array, key_name: str, op: str,
     presorted: bool = False, lo_name: str = None,
@@ -669,6 +686,7 @@ def merge_ranks(lwords, rwords):
     return lo, hi
 
 
+@stage("merge_join")
 def merge_join_expand(
     left: Cols, left_count: jax.Array,
     right: Cols, right_count: jax.Array,
@@ -759,6 +777,7 @@ def merge_join_expand(
 # ---------------------------------------------------------------------------
 
 
+@stage("named_reduce")
 def masked_reduce(col: jax.Array, count: jax.Array, op: str) -> jax.Array:
     mask = valid_mask(col.shape[0], count)
     m = mask.reshape(mask.shape + (1,) * (col.ndim - 1))

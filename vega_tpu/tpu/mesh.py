@@ -300,8 +300,9 @@ def host_put(value, spec: NamedSharding) -> jax.Array:
     materializes only its addressable shards via make_array_from_callback;
     single-process falls through to plain device_put."""
     with spans.span("put") as sp:
-        if sp.on:
-            sp.nbytes = int(getattr(value, "nbytes", 0))
+        if sp.on:  # a list or a scalar has no nbytes: what it becomes has
+            sp.nbytes = int(getattr(value, "nbytes", None)
+                            or np.asarray(value).nbytes)
         if jax.process_count() == 1:
             return jax.device_put(value, spec)
         arr = np.asarray(value)

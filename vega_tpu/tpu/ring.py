@@ -39,6 +39,7 @@ from jax import lax
 
 from vega_tpu.tpu import kernels
 from vega_tpu.tpu.mesh import SHARD_AXIS
+from vega_tpu.tpu.spans import stage
 
 Cols = Dict[str, jax.Array]
 
@@ -91,19 +92,20 @@ def staged_exchange(
         return kernels.passthrough_exchange(cols, count, capacity,
                                             out_capacity)
     group = max(1, min(int(group), n_shards - 1))
-    mask = kernels.valid_mask(capacity, count)
-    bucket = jnp.where(mask, bucket, n_shards)
+    with stage("exchange_group"):
+        mask = kernels.valid_mask(capacity, count)
+        bucket = jnp.where(mask, bucket, n_shards)
 
-    if pregrouped:
-        counts_to, starts = kernels.pregrouped_group(bucket, n_shards)
-        sorted_cols = cols
-    else:
-        # prefer_low_memory: the counting sort's O(capacity * n_shards)
-        # intermediates would defeat exactly the peak-memory bound this
-        # exchange exists to provide.
-        sorted_cols, counts_to, starts = kernels._group_by_bucket(
-            cols, bucket, n_shards, prefer_low_memory=True)
-    overflow = jnp.any(counts_to > slot_capacity)
+        if pregrouped:
+            counts_to, starts = kernels.pregrouped_group(bucket, n_shards)
+            sorted_cols = cols
+        else:
+            # prefer_low_memory: the counting sort's O(capacity * n_shards)
+            # intermediates would defeat exactly the peak-memory bound this
+            # exchange exists to provide.
+            sorted_cols, counts_to, starts = kernels._group_by_bucket(
+                cols, bucket, n_shards, prefer_low_memory=True)
+        overflow = jnp.any(counts_to > slot_capacity)
 
     my_id = lax.axis_index(SHARD_AXIS)
 
@@ -113,6 +115,7 @@ def staged_exchange(
     }
     write_pos = jnp.zeros((), jnp.int32)
 
+    @stage("exchange_send")
     def take_slot(target):
         """[slot_capacity] rows destined for `target` + their count."""
         start = jnp.take(starts, target)
@@ -132,6 +135,7 @@ def staged_exchange(
         }
         return slot, n_rows
 
+    @stage("exchange_compact")
     def append_round(out_cols, write_pos, slots, rows_list):
         """Bulk-append one round's received slots: one scatter per column
         over the stacked [g, slot_capacity] buffer."""
@@ -167,11 +171,12 @@ def staged_exchange(
             perm = [(i, (i + s) % n_shards) for i in range(n_shards)]
             target = (my_id + s) % n_shards
             slot, n_rows = take_slot(target)
-            recv_slots.append({
-                name: lax.ppermute(c, SHARD_AXIS, perm)
-                for name, c in slot.items()
-            })
-            recv_rows.append(lax.ppermute(n_rows, SHARD_AXIS, perm))
+            with stage("exchange_wire"):
+                recv_slots.append({
+                    name: lax.ppermute(c, SHARD_AXIS, perm)
+                    for name, c in slot.items()
+                })
+                recv_rows.append(lax.ppermute(n_rows, SHARD_AXIS, perm))
         out_cols, write_pos = append_round(out_cols, write_pos,
                                            recv_slots, recv_rows)
 
