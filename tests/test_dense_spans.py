@@ -2,6 +2,7 @@
 nothing; under a jax profiler session they tally launches, fetches, decodes
 and pivots by name, never nest, and start over with each session."""
 
+import re
 import threading
 
 import numpy as np
@@ -478,8 +479,9 @@ PIPELINES = {
                               "exchange_wire", "exchange_compact",
                               "key_sort", "topk"}),
     "filter_count": (4, {"narrow", "named_reduce"}),
-    "one_shard": (1, {"exchange_compact", "key_sort", "segment_reduce",
-                      "merge_join", "topk"}),
+    # no "exchange_compact": a one-shard passthrough moves no row, and the
+    # select that is all of it may fuse into its consumer
+    "one_shard": (1, {"key_sort", "segment_reduce", "merge_join", "topk"}),
     "ring": (4, {"exchange_group", "exchange_send", "exchange_wire",
                  "exchange_compact", "key_sort"}),
 }
@@ -539,19 +541,52 @@ def test_stage_tables_of_a_pipeline(dctx, on_devices, fresh_programs, name):
     assert dctx.metrics_summary()["dense_spans"]["program_stages"] == tables
 
 
-def test_a_scatter_and_its_compaction_keep_their_stage(dctx, on_devices,
-                                                       fresh_programs):
-    """The segment reduce's key compaction is the segment reduce's, the
-    passthrough's is the exchange's: `compact` itself carries no scope."""
-    on_devices(1)
-    pairs, _table = _sources(dctx)
-    pairs.reduce_by_key(op="add").collect()
-    ops = fresh_programs.program_stages()["rbk"]["ops"]
+def _opcodes_by_stage(ops):
     by_stage = {}
     for row in ops:
         by_stage.setdefault(row["stage"], set()).add(row["opcode"])
+    return by_stage
+
+
+def test_a_scatter_and_its_compaction_keep_their_stage(dctx, on_devices,
+                                                       fresh_programs):
+    """The segment reduce's key compaction is the segment reduce's, the
+    received rows' is the exchange's: `compact` itself carries no scope."""
+    on_devices(4)
+    pairs, _table = _sources(dctx)
+    pairs.reduce_by_key(op="add").collect()
+    by_stage = _opcodes_by_stage(fresh_programs.program_stages()["rbk"]["ops"])
     assert "sort" in by_stage["key_sort"]
     assert by_stage["segment_reduce"] and by_stage["exchange_compact"]
+
+
+def test_no_scatter_where_no_row_moves(dctx, on_devices, fresh_programs):
+    """On one shard every exchange is the passthrough: a slice or a pad and
+    a select a column. Nothing under `exchange_compact` scatters or sorts
+    (the chip runs a 64Mi-slot scatter as a sort and a kCustom fusion), in
+    the compiled tables and, since the CPU's compiler hides a scatter inside
+    a loop fusion, in what was traced."""
+    on_devices(1)
+    pairs, table = _sources(dctx)
+    assert len(pairs.reduce_by_key(op="add").join(table).collect()) == KEYS
+    cols = pairs.sort_by_key().collect_arrays()
+    assert np.array_equal(cols["k"], np.sort(cols["k"]))
+    spans = fresh_programs
+    traced = {kind: list(lows) for kind, lows in spans._lowered.items()}
+    tables = spans.program_stages()
+    for kind in ("rbk", "join", "sort"):
+        moved = [row["key"] for row in tables[kind]["ops"]
+                 if row["stage"] == "exchange_compact"
+                 and (row["opcode"] in ("scatter", "sort")
+                      or row["kind"] == "kCustom")]
+        assert moved == [], (kind, moved)
+        assert traced[kind], kind
+        for lowered in traced[kind]:
+            names = re.findall(r"vega\.exchange_compact/([\w()]+)",
+                               lowered.as_text(debug_info=True))
+            assert names, kind  # the passthrough was traced, under its stage
+            assert not [n for n in names
+                        if re.search("scatter|sort|cumsum", n)], (kind, names)
 
 
 def test_only_the_first_call_lowers_again():

@@ -167,6 +167,62 @@ def test_masked_reduce_ignores_invalid_rows():
     assert float(kernels.masked_reduce(col, jnp.int32(2), "max")) == 5.0
 
 
+_PASS_COLUMNS = {
+    # every slot holds something other than zero, past `count` too; the
+    # floats carry a -0.0 and a NaN so that "bit for bit" means bits
+    "int32": lambda cap: np.arange(1, cap + 1, dtype=np.int32) * -7,
+    "float32": lambda cap: np.where(
+        np.arange(cap) % 5 == 3, np.float32("nan"),
+        np.where(np.arange(cap) % 5 == 1, np.float32(-0.0),
+                 np.arange(1, cap + 1, dtype=np.float32) / 3)
+    ).astype(np.float32),
+    "float32_3wide": lambda cap: (
+        np.arange(1, 3 * cap + 1, dtype=np.float32).reshape(cap, 3) - 0.5),
+}
+_PASS_COUNTS = {
+    "zero": lambda cap, out: 0,
+    "one": lambda cap, out: 1,
+    "mid": lambda cap, out: min(cap, out) // 2,
+    "capacity": lambda cap, out: cap,
+    "past_out": lambda cap, out: out + 5,
+}
+
+
+@pytest.mark.parametrize("count", sorted(_PASS_COUNTS))
+@pytest.mark.parametrize("column", sorted(_PASS_COLUMNS))
+@pytest.mark.parametrize("capacity,out_capacity",
+                         [(48, 64), (64, 64), (64, 48)],
+                         ids=["pad", "same", "slice"])
+def test_passthrough_exchange_is_compact_of_a_prefix(capacity, out_capacity,
+                                                     column, count):
+    """No row moves through a passthrough, so it scatters nothing; what it
+    returns is still compact() of the prefix mask, bit for bit: the kept
+    rows, zeros after them, the count, the overflow flag and the rows cut
+    on overflow."""
+    cols = {"k": jnp.asarray(_PASS_COLUMNS["int32"](capacity)),
+            "v": jnp.asarray(_PASS_COLUMNS[column](capacity))}
+    n = _PASS_COUNTS[count](capacity, out_capacity)
+
+    def old(cols, n):
+        out, new_count = kernels.compact(
+            cols, kernels.valid_mask(capacity, n), out_capacity)
+        return out, new_count, new_count > out_capacity
+
+    want, want_count, want_overflow = jax.jit(old)(cols, jnp.int32(n))
+    got, got_count, got_overflow = jax.jit(
+        functools.partial(kernels.passthrough_exchange, capacity=capacity,
+                          out_capacity=out_capacity))(cols, jnp.int32(n))
+    assert int(got_count) == int(want_count) == min(n, capacity)
+    assert got_count.dtype == want_count.dtype
+    assert bool(got_overflow) == bool(want_overflow) \
+        == (min(n, capacity) > out_capacity)
+    for name in cols:
+        assert got[name].shape == want[name].shape
+        assert got[name].dtype == want[name].dtype
+        assert np.asarray(got[name]).tobytes() \
+            == np.asarray(want[name]).tobytes(), name
+
+
 def test_group_by_bucket_branch_parity():
     """Counting-sort and argsort branches of _group_by_bucket must agree
     (grouped rows, counts, starts) — the argsort branch is otherwise

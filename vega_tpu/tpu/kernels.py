@@ -105,8 +105,10 @@ def compact(cols: Cols, keep: jax.Array, out_capacity: int) -> Tuple[Cols, jax.A
     """Move rows where keep=True to the front; returns (cols, new_count).
     Stable (a kept row lands at its exclusive prefix count), static-shape,
     zeros after the kept rows: a cumsum and one scatter a column. Whole rows
-    go through it in every exchange, filter, sample, flat_map and union; the
-    named segment reduce sends its key words alone, the scanned one its rows."""
+    go through it after every exchange that moves rows (the received rows),
+    and in filter, sample, flat_map and union; the named segment reduce sends
+    its key words alone, the scanned one its rows. Where the mask is a prefix
+    no row moves and nothing is scattered: passthrough_exchange."""
     pos = jnp.cumsum(keep.astype(jnp.int32)) - 1
     idx = jnp.where(keep, pos, out_capacity)  # dropped rows land out of range
     out = {}
@@ -155,9 +157,27 @@ def sort_carrying(keys, cols: Cols):
 def passthrough_exchange(cols: Cols, count: jax.Array, capacity: int,
                          out_capacity: int):
     """Single-shard fast path shared by every exchange implementation: the
-    bucket/sort/collective is a no-op; just re-capacity the block."""
-    mask = valid_mask(capacity, count)
-    out, new_count = compact(cols, mask, out_capacity)
+    bucket/sort/collective is a no-op; just re-capacity the block.
+
+    No row moves: the valid rows are the prefix [0, count), so each column
+    is sliced or zero-padded to out_capacity (decided at trace time from the
+    two static capacities) and one select zeroes the slots past the kept
+    rows. Bit for bit what compact(cols, valid_mask(capacity, count),
+    out_capacity) gives (kept rows first in their order, zeros after them,
+    the rows past out_capacity cut on overflow) without its cumsum and
+    scatter, which the chip runs as a sort and a fusion over every slot
+    (0.49 s a 64Mi-slot column: PERF.md, PR 39)."""
+    new_count = jnp.clip(count, 0, capacity).astype(jnp.int32)
+    kept = valid_mask(out_capacity, new_count)
+    out = {}
+    for n, c in cols.items():
+        if capacity >= out_capacity:
+            c = c[:out_capacity]
+        else:
+            c = jnp.pad(c, [(0, out_capacity - capacity)]
+                        + [(0, 0)] * (c.ndim - 1))
+        out[n] = jnp.where(kept.reshape((-1,) + (1,) * (c.ndim - 1)), c,
+                           jnp.zeros((), c.dtype))
     return out, new_count, new_count > out_capacity
 
 

@@ -68,8 +68,9 @@ the innermost `vega.` scope of an instruction is its stage:
                     `ring.staged_exchange`'s `take_slot`
   exchange_wire     the `lax.all_to_all`s and `lax.ppermute`s
   exchange_compact  the received rows' `compact` (`bucket_exchange`), the
-                    single-shard `passthrough_exchange`'s, the staged
-                    program's `append_round`
+                    staged program's `append_round`, and the single-shard
+                    or elided `passthrough_exchange` (a slice or pad and a
+                    select: it may fuse into its consumer and leave nothing)
   topk              the selection of `take_ordered` / `top` (`lax.top_k`, the
                     slice of the sorted rows)
   sample            `sort_by_key`'s strided key sample (`sortsamp`)
