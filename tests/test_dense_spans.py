@@ -589,6 +589,28 @@ def test_no_scatter_where_no_row_moves(dctx, on_devices, fresh_programs):
                         if re.search("scatter|sort|cumsum", n)], (kind, names)
 
 
+@pytest.mark.parametrize("name", ["reduce_join", "sort_take_ordered", "ring"])
+def test_send_buffers_gather_nothing(dctx, on_devices, fresh_programs, name):
+    """Across shards every send buffer is sliced, one dynamic slice a
+    destination (kernels.send_slots): the programs still name
+    `exchange_send`, and nothing in that stage is a gather, in the compiled
+    tables or, since the CPU's compiler may hide a gather inside a loop
+    fusion, in what was traced."""
+    on_devices(PIPELINES[name][0])
+    pairs, table = _sources(dctx)
+    _run_pipeline(name, pairs, table)
+    spans = fresh_programs
+    traced = [lowered for lows in spans._lowered.values() for lowered in lows]
+    sending = [row for t in spans.program_stages().values()
+               for row in t["ops"] if row["stage"] == "exchange_send"]
+    assert sending
+    assert [r["key"] for r in sending if r["opcode"] == "gather"] == []
+    names = [n for lowered in traced for n in re.findall(
+        r"vega\.exchange_send/([\w()]+)", lowered.as_text(debug_info=True))]
+    assert not [n for n in names if "gather" in n or "take" in n], names
+    assert "dynamic_slice" in names
+
+
 def test_only_the_first_call_lowers_again():
     """The first call of a minted program leaves its lowering; later calls
     take the plain path (the `first` lock is spent). A `lower` that raises

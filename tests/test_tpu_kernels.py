@@ -223,6 +223,158 @@ def test_passthrough_exchange_is_compact_of_a_prefix(capacity, out_capacity,
             == np.asarray(want[name]).tobytes(), name
 
 
+_X_CAP = 40
+
+
+def _take_send_buffer(col, starts, counts, slot):
+    """The reference send buffer: a take through starts + iota clipped to
+    the column, empty slots zeroed."""
+    capacity = col.shape[0]
+    slot_rows = starts[:, None] + jnp.arange(slot)[None, :]
+    slot_valid = jnp.arange(slot)[None, :] < counts[:, None]
+    slot_rows = jnp.clip(slot_rows, 0, capacity - 1)
+    buf = jnp.take(col, slot_rows, axis=0)
+    return jnp.where(slot_valid.reshape(slot_valid.shape + (1,) * (buf.ndim - 2)),
+                     buf, jnp.zeros((), col.dtype))
+
+
+def _x_case(case, n, rng):
+    """(buckets [n, cap], counts [n], slot, out_cap, column kinds); buckets
+    are drawn over every row, rows past a shard's count are ghosts."""
+    counts = rng.randint(_X_CAP - 6, _X_CAP + 1, n)
+    buckets = rng.randint(0, n, (n, _X_CAP))
+    kinds = ("int32", "float32")
+    if case == "empty_destinations":
+        buckets = (buckets // 2) * 2 % n  # odd destinations get nothing
+    elif case == "one_destination_every_row":
+        counts[:] = _X_CAP
+        buckets[:] = n - 1
+    elif case == "last_run_past_the_end":  # shard 0: a long run, then two rows
+        counts[0] = _X_CAP
+        buckets[0] = 0
+        buckets[0, 5:7] = n - 1
+    elif case == "int64_key_float32_value":
+        kinds = ("int64_words", "float32")
+    elif case == "two_d_column":
+        kinds = ("int32", "float32_3wide")
+    per_dest = np.stack([np.bincount(b[:c], minlength=n)
+                         for b, c in zip(buckets, counts)])
+    slot = int(per_dest.max())
+    if case == "slot_larger_than_column":
+        slot = _X_CAP + 7
+    elif case == "overflowing_destination":
+        slot = max(1, slot - 3)
+    return buckets.astype(np.int32), counts.astype(np.int32), slot, \
+        n * _X_CAP, kinds, per_dest
+
+
+def _x_columns(kinds, n, rng):
+    cols = {}
+    for kind in kinds:
+        if kind == "int64_words":
+            cols["k"] = rng.randint(-2**31, 2**31 - 1, n * _X_CAP, np.int64)
+            cols["k.lo"] = rng.randint(-2**31, 2**31 - 1, n * _X_CAP, np.int64)
+        elif kind == "int32":
+            cols["k"] = rng.randint(-2**31, 2**31 - 1, n * _X_CAP, np.int64)
+        elif kind == "float32":
+            cols["v"] = rng.randn(n * _X_CAP)
+        else:
+            cols["m"] = rng.randn(n * _X_CAP, 3)
+    return {name: jnp.asarray(c.astype(np.int32 if c.dtype == np.int64
+                                       else np.float32))
+            for name, c in cols.items()}
+
+
+_X_CASES = ["empty_destinations", "one_destination_every_row",
+            "last_run_past_the_end", "slot_larger_than_column",
+            "overflowing_destination", "int64_key_float32_value",
+            "two_d_column"]
+
+
+@pytest.mark.parametrize("exchange", ["all_to_all", "ring", "staged"])
+@pytest.mark.parametrize("n", [2, 4, 8])
+@pytest.mark.parametrize("case", _X_CASES)
+def test_send_buffers_are_the_take_bit_for_bit(case, n, exchange):
+    """send_slots builds each destination's slot with one dynamic slice; its
+    buffers, and what every exchange receives, counts and flags, are what
+    the take through starts + iota gave, bit for bit: runs that end past
+    capacity - slot, a slot larger than the column, a destination with
+    more rows than its slot (its first slot rows go, the flag is set)."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+
+    from vega_tpu.tpu import compat, ring
+    from vega_tpu.tpu import mesh as mesh_lib
+    from vega_tpu.tpu.mesh import SHARD_AXIS
+
+    rng = np.random.RandomState(zlib.crc32(repr((case, n)).encode()))
+    buckets, counts, slot, out_cap, kinds, per_dest = _x_case(case, n, rng)
+    if case == "last_run_past_the_end":
+        # the last destination's run starts at count - its rows
+        assert (counts - per_dest[:, -1] + slot > _X_CAP).any()
+    if case == "overflowing_destination":
+        assert (per_dest > slot).any()
+    cols = _x_columns(kinds, n, rng)
+
+    def prog(cols, count, bucket):
+        count = count[0]
+        ghosted = jnp.where(kernels.valid_mask(_X_CAP, count), bucket, n)
+        grouped, counts_to, starts = kernels._group_by_bucket(
+            cols, ghosted, n)
+        want = {name: _take_send_buffer(c, starts, counts_to, slot)
+                for name, c in grouped.items()}
+        got = {name: kernels.send_slots(c, starts, counts_to, slot)
+               for name, c in grouped.items()}
+        send_counts = jnp.minimum(counts_to, slot)
+        if exchange == "all_to_all":
+            out, new_count, ovf = kernels.bucket_exchange(
+                cols, count, bucket, n, slot, out_cap)
+        else:
+            out, new_count, ovf = ring.staged_exchange(
+                cols, count, bucket, n, slot, out_cap,
+                group=1 if exchange == "ring" else 2)
+        # the receiving side of the take's buffers, as the one-shot
+        # exchange puts them together (the staged one is checked below)
+        recv = lax.all_to_all(send_counts, SHARD_AXIS, 0, 0)
+        got_back = {name: lax.all_to_all(b, SHARD_AXIS, 0, 0).reshape(
+            (n * slot,) + b.shape[2:]) for name, b in want.items()}
+        oracle, _ = kernels.compact(
+            got_back, (jnp.arange(slot)[None, :] < recv[:, None]).reshape(-1),
+            out_cap)
+        return (want, got, send_counts, out, oracle, new_count.reshape(1),
+                ovf.reshape(1))
+
+    sp = P(SHARD_AXIS)
+    f = jax.jit(compat.shard_map(prog, mesh=mesh_lib.make_mesh(n),
+                                 in_specs=(sp, sp, sp), out_specs=sp))
+    want, got, send_counts, out, oracle, new_count, ovf = jax.device_get(f(
+        cols, jnp.asarray(counts), jnp.asarray(buckets.reshape(-1))))
+
+    for name in cols:
+        assert got[name].shape == (n * n, slot) + cols[name].shape[1:]
+        assert got[name].dtype == cols[name].dtype
+        assert got[name].tobytes() == want[name].tobytes(), name
+    send_counts = send_counts.reshape(n, n)  # [source, destination]
+    np.testing.assert_array_equal(send_counts, np.minimum(per_dest, slot))
+    received = send_counts.sum(axis=0)
+    np.testing.assert_array_equal(new_count, received)
+    np.testing.assert_array_equal(
+        ovf, (per_dest > slot).any(axis=1) | (received > out_cap))
+    assert ovf.any() == (case == "overflowing_destination")
+    for name in cols:
+        if exchange == "all_to_all":
+            expect = oracle[name]
+        else:  # round s brings shard d the slot of shard (d - s) mod n
+            bufs = want[name].reshape((n, n, slot) + cols[name].shape[1:])
+            expect = np.zeros_like(out[name])
+            for d in range(n):
+                rows = np.concatenate(
+                    [bufs[(d - s) % n, d, :send_counts[(d - s) % n, d]]
+                     for s in range(n)])[:out_cap]
+                expect[d * out_cap:d * out_cap + len(rows)] = rows
+        assert out[name].tobytes() == expect.tobytes(), name
+
+
 def test_group_by_bucket_branch_parity():
     """Counting-sort and argsort branches of _group_by_bucket must agree
     (grouped rows, counts, starts) — the argsort branch is otherwise

@@ -117,23 +117,13 @@ def staged_exchange(
 
     @stage("exchange_send")
     def take_slot(target):
-        """[slot_capacity] rows destined for `target` + their count."""
-        start = jnp.take(starts, target)
-        n_rows = jnp.minimum(jnp.take(counts_to, target),
-                             slot_capacity).astype(jnp.int32)
-        rows = start + jnp.arange(slot_capacity)
-        rows = jnp.clip(rows, 0, capacity - 1)
-        slot = {name: jnp.take(col, rows, axis=0)
+        """[slot_capacity] rows destined for `target` + their count: the
+        one-destination form of kernels.send_slots."""
+        start = lax.dynamic_slice_in_dim(starts, target, 1)
+        n_to = lax.dynamic_slice_in_dim(counts_to, target, 1)
+        slot = {name: kernels.send_slots(col, start, n_to, slot_capacity)[0]
                 for name, col in sorted_cols.items()}
-        valid = jnp.arange(slot_capacity) < n_rows
-        slot = {
-            name: jnp.where(
-                valid.reshape(valid.shape + (1,) * (c.ndim - 1)), c,
-                jnp.zeros((), c.dtype),
-            )
-            for name, c in slot.items()
-        }
-        return slot, n_rows
+        return slot, jnp.minimum(n_to[0], slot_capacity).astype(jnp.int32)
 
     @stage("exchange_compact")
     def append_round(out_cols, write_pos, slots, rows_list):

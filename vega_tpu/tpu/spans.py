@@ -70,8 +70,9 @@ the innermost `vega.` scope of an instruction is its stage:
   exchange_group    rows to buckets: `hash32`, `hash32_pair`, `range_bucket`
                     (`searchsorted2`), `_group_by_bucket`, `pregrouped_group`,
                     the Pallas bucket kernels, the sizing histograms
-  exchange_send     the send buffers of `bucket_exchange` (`slot_rows`, the
-                    take through them, the zeroing of empty slots) and
+  exchange_send     the send buffers: `kernels.send_slots` (one dynamic
+                    slice a destination of the zero-padded column, a select
+                    zeroing the empty slots), for `bucket_exchange` and for
                     `ring.staged_exchange`'s `take_slot`
   exchange_wire     the `lax.all_to_all`s and `lax.ppermute`s
   exchange_compact  the received rows' `compact` (`bucket_exchange`), the
