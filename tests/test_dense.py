@@ -2452,7 +2452,7 @@ def test_dense_string_sort_distinct_topk_parity(dctx):
 def test_dense_string_join_cross_dict_parity(dctx):
     """Two sides built from DIFFERENT key sets carry different
     dictionaries: the join must unify them (host merge + device remap)
-    and match the host result exactly, with zero capacity retries at the
+    and match the host result exactly, each side's table staged at the
     default dense_dict_capacity."""
     from vega_tpu.tpu.dense_rdd import _DictUnifyRDD, DenseRDD
 
@@ -2472,14 +2472,16 @@ def test_dense_string_join_cross_dict_parity(dctx):
         .join(dctx.parallelize(list(zip(rk.tolist(), rv.tolist())), 2))
         .collect())
     assert dev == host
-    assert all(n._dict_retries == 0 for n in unify)
+    assert all(n._table_capacity == 65536 for n in unify)
 
 
 def test_dense_string_dict_overflow_grows_capacity():
-    """dense_dict_capacity=2 (staged at the 128-entry floor) cannot hold
-    a 300-entry merged dictionary: the remap program's overflow flag must
-    drive capacity-doubling retries (the standard device contract) and
-    still produce the exact host-tier join."""
+    """dense_dict_capacity=2 (the 128-entry floor) stages a table far
+    smaller than the 300-entry merged dictionary: the capacity grows to the
+    power of two that holds the whole table, at once (no overflow, no
+    second launch), and the join is the exact host-tier join; a code
+    outside its dictionary is still caught on the device."""
+    from vega_tpu.errors import VegaError
     from vega_tpu.tpu.dense_rdd import _DictUnifyRDD
 
     ctx = v.Context("local", num_workers=2, dense_dict_capacity=2)
@@ -2497,8 +2499,15 @@ def test_dense_string_dict_overflow_grows_capacity():
         assert dev == host
         unify = [n for n in _lineage_nodes(j)
                  if isinstance(n, _DictUnifyRDD)]
-        assert unify and any(n._dict_retries >= 1 for n in unify), \
-            "tiny dictionary capacity never exercised the retry path"
+        assert unify and all(n._table_capacity == 256 for n in unify)
+        # the device's check: a side whose dictionary is cut below its
+        # codes has codes outside it
+        side = unify[0]
+        side.unpersist()
+        maps = side._unif.tables()[1 + side._side]
+        maps["k"] = maps["k"][:10]
+        with pytest.raises(VegaError, match="outside its dictionary"):
+            side.block()
     finally:
         ctx.stop()
 

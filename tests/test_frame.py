@@ -233,14 +233,20 @@ def test_string_group_key_and_join_compile_to_device(ctx):
 
 
 def test_wide_join_falls_back_to_host(ctx):
-    # >1 value column per side: no device join layout — silent host tier.
+    # >1 value column per side: the device join carries them all (it fell
+    # back to the host tier before the join took named columns); the host
+    # tier, asked for, gives the same rows.
     a = ctx.create_frame(k=np.arange(6) % 3, x=np.arange(6),
                          y=np.arange(6) * 2)
     b = ctx.create_frame(k=np.arange(3), z=np.arange(3) * 5)
     q = a.join(b, on="k").sort("k")
-    assert "host tier" in q.explain()
+    assert "device tier" in q.explain()
     rows = q.collect()
-    assert rows[0] == (0, 0, 0, 0) and len(rows) == 6
+    want = sorted((k, x, 2 * x, 5 * k) for x, k in enumerate(
+        np.arange(6) % 3))
+    assert sorted(rows) == want and [r[0] for r in rows] == sorted(
+        r[0] for r in want)
+    assert sorted(q.hint(tier="host").collect()) == want
 
 
 # -------------------------------------------------- whole-stage fusion

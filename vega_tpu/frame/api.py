@@ -169,6 +169,20 @@ class DataFrame:
     def limit(self, n: int) -> "DataFrame":
         return DataFrame(self._ctx, L.Limit(self._plan, n), self._options)
 
+    def cache(self) -> "DataFrame":
+        """This frame, kept: the first action that reads the returned frame
+        (or any frame built on it) materializes its lineage once, and every
+        later compile lowers to that same lineage, so later actions read
+        no file and recompute nothing above it. The device block is cached
+        as a DenseRDD's is (RDD.cache(): held until the HBM LRU of
+        Configuration.dense_hbm_budget evicts it, then rebuilt from the
+        lineage); a frame the host tier serves caches its RDD. Like
+        Spark's, lazy: nothing is read until an action."""
+        if isinstance(self._plan, L.Limit):
+            raise VegaError("cache() a frame before limit(), not after")
+        return DataFrame(self._ctx, L.CachedScan(
+            self._plan, planner_lib.FrameCache(self._plan)), self._options)
+
     _HINT_VALUES = {
         "tier": ("auto", "device", "host"),
         "exchange": ("auto", "all_to_all", "ring", "staged"),
@@ -246,7 +260,11 @@ class DataFrame:
     def collect(self) -> list:
         """Rows as tuples in frame column order (single-column frames
         still yield 1-tuples — the shape never depends on the plan)."""
-        cols = self.collect_columns()
+        return self._collect(self._compiled())
+
+    def _collect(self, compiled: planner_lib.Compiled) -> list:
+        """collect() of an already compiled plan of this frame."""
+        cols = self._collect_columns(compiled)
         names = self.columns
         arrays = [np.asarray(cols[nm]) for nm in names]
         n = len(arrays[0]) if arrays else 0
@@ -254,13 +272,18 @@ class DataFrame:
 
     def collect_columns(self) -> dict:
         """Columnar collect: {name: numpy array} — no per-row Python
-        objects on the device path."""
-        compiled = self._compiled()
+        objects on the device path. A date column comes back as
+        datetime64[D] from either tier."""
+        return self._collect_columns(self._compiled())
+
+    def _collect_columns(self, compiled: planner_lib.Compiled) -> dict:
         with self._shuffle_plan_override():
             if compiled.kind == "device":
                 blk_cols = compiled.rdd.collect_arrays()
-                out = {fn: np.asarray(blk_cols[bn])
-                       for fn, bn in compiled.out}
+                out = {fn: (np.asarray(blk_cols[bn]).astype(
+                    np.int64).astype("datetime64[D]")
+                    if fn in compiled.dates else np.asarray(blk_cols[bn]))
+                    for fn, bn in compiled.out}
             elif compiled.layout == "blocks":
                 blocks = compiled.rdd.collect()
                 out = {}
@@ -279,7 +302,7 @@ class DataFrame:
                         else compiled.rdd.collect())
                 out = {}
                 for i, nm in enumerate(compiled.cols):
-                    out[nm] = np.asarray([r[i] for r in rows])
+                    out[nm] = _as_days(np.asarray([r[i] for r in rows]))
         if compiled.limit is not None:
             out = {nm: c[:compiled.limit] for nm, c in out.items()}
         return out
@@ -317,12 +340,15 @@ class DataFrame:
             order = [bn for _fn, bn in compiled.out]
             schema_order = [nm for nm, _dt in compiled.rdd._schema()]
             rdd = compiled.rdd.to_rdd()
+            days = tuple(j for j, c in enumerate(compiled.cols)
+                         if c in compiled.dates)
             if len(schema_order) == 1:
-                return rdd.map(_scalar_to_tuple)
+                return rdd.map(_reorder_row([0], days))
             idx = [schema_order.index(bn) for bn in order]
             # Reorder to frame order and convert numpy scalars to Python
-            # natives, so device and host to_rdd() rows are interchangeable.
-            return rdd.map(_reorder_row(idx))
+            # natives (a date's days to its datetime.date), so device and
+            # host to_rdd() rows are interchangeable.
+            return rdd.map(_reorder_row(idx, days))
         if compiled.layout == "blocks":
             from vega_tpu.frame import physical as P
 
@@ -330,22 +356,36 @@ class DataFrame:
         return compiled.rdd
 
 
+def _as_days(col: np.ndarray) -> np.ndarray:
+    """A host-tier row column of dates (datetime.date objects, as rows
+    carry them) as datetime64[D], the type the device tier collects."""
+    import datetime
+
+    if col.dtype.kind == "O" and len(col) and all(
+            isinstance(x, datetime.date)
+            and not isinstance(x, datetime.datetime) for x in col):
+        return col.astype("datetime64[D]")
+    return col
+
+
 def _pyval(x):
     """numpy scalar -> Python native; object-column values pass through."""
     return x.item() if hasattr(x, "item") else x
 
 
-def _scalar_to_tuple(v):
-    return (_pyval(v),)
-
-
-def _reorder_row(idx: List[int]):
+def _reorder_row(idx: List[int], days=()):
     def run(row):
         if not isinstance(row, tuple):
             row = (row,)
-        return tuple(_pyval(row[i]) for i in idx)
+        return tuple(_as_date(row[i]) if j in days else _pyval(row[i])
+                     for j, i in enumerate(idx))
 
     return run
+
+
+def _as_date(v):
+    """int32 days since 1970-01-01 -> datetime.date."""
+    return np.datetime64(int(v), "D").item()
 
 
 class GroupedFrame:

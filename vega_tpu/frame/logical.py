@@ -12,6 +12,7 @@ predicate pushdown (see _exact_under_narrowing)."""
 
 from __future__ import annotations
 
+import datetime
 from typing import List, Optional, Sequence, Tuple
 
 from vega_tpu.errors import VegaError
@@ -66,6 +67,25 @@ class ColumnsScan(LogicalPlan):
 
     def describe(self) -> str:
         return f"ColumnsScan([{','.join(self.data)}])"
+
+
+class CachedScan(LogicalPlan):
+    """A cached frame (DataFrame.cache()): `child` is the plan it caches;
+    the planner lowers it once per tier into `cache` (planner.FrameCache)
+    and every later compile reads that same lineage, whose device node
+    keeps its materialized block. A leaf to the rewrites: what it holds was
+    fixed when cache() was called, so nothing is pruned from it or pushed
+    into it."""
+
+    def __init__(self, child: LogicalPlan, cache):
+        self.child = child
+        self.cache = cache
+
+    def columns(self) -> List[str]:
+        return self.child.columns()
+
+    def describe(self) -> str:
+        return f"CachedScan[{','.join(self.columns())}]"
 
 
 class Project(LogicalPlan):
@@ -265,6 +285,13 @@ def prune_columns(plan: LogicalPlan,
                 f"{plan.path!r} has {plan.all_columns}")
         return ParquetScan(plan.path, plan.all_columns, kept,
                            plan.predicate, plan.num_partitions)
+    if isinstance(plan, CachedScan):
+        missing = (required or set()) - set(plan.columns())
+        if missing:
+            raise VegaError(
+                f"unknown column(s) {sorted(missing)} — frame has "
+                f"{plan.columns()}")
+        return plan
     if isinstance(plan, ColumnsScan):
         if required is None:
             return plan
@@ -299,10 +326,24 @@ def _as_pushdown(e: Expr) -> Optional[tuple]:
     left, right, op = e.left, e.right, e.op
     if isinstance(left, Lit) and isinstance(right, Col):
         left, right, op = right, left, flip[op]
-    if isinstance(left, Col) and isinstance(right, Lit) \
-            and isinstance(right.value, (int, float, str, bytes, bool)):
-        return (left.name, op, right.value)
+    if isinstance(left, Col) and isinstance(right, Lit):
+        value = _as_date(right.value)
+        if isinstance(value, (int, float, str, bytes, bool,
+                              datetime.date)):
+            return (left.name, op, value)
     return None
+
+
+def _as_date(value):
+    """A day literal (datetime64[D]) as the datetime.date a parquet
+    date32 column's statistics compare with; anything else as it is."""
+    import numpy as np
+
+    if isinstance(value, np.datetime64) \
+            and np.datetime_data(value.dtype)[0] == "D" \
+            and not np.isnat(value):
+        return value.item()
+    return value
 
 
 def _pushable_path(plan: LogicalPlan):
@@ -331,14 +372,17 @@ def _pushable_path(plan: LogicalPlan):
     return None, None, None
 
 
-def _exact_under_narrowing(scan: ParquetScan, column: str) -> bool:
+def _exact_under_narrowing(scan: ParquetScan, column: str,
+                           value=None) -> bool:
     """True when comparisons on this scan column give the same answer in
     the reader (raw file values) and in a device stage (after the
     documented dtype narrowing). Floats narrow f64->f32 on device, so a
     reader-side f64 compare can keep a row a device-side f32 compare
     would drop — pushing such a conjunct would make pushdown observable.
     Ints/bools/objects are exact (out-of-range ints never reach the
-    device: the source falls back to the host tier first). Metadata-only
+    device: the source falls back to the host tier first), and so is a
+    date column against a day literal (int32 days on device); a date
+    literal against anything else stays in the plan. Metadata-only
     (cached parquet footer); unknown dtypes stay conservative."""
     try:
         import numpy as np
@@ -348,7 +392,11 @@ def _exact_under_narrowing(scan: ParquetScan, column: str) -> bool:
         dt = np.dtype(parquet_schema(scan.path)[column])
     except Exception:  # noqa: BLE001 — no metadata: don't push
         return False
-    return dt.kind in ("i", "u", "b", "O")
+    is_day = isinstance(value, datetime.date) \
+        and not isinstance(value, datetime.datetime)
+    if dt == np.dtype("datetime64[D]"):
+        return is_day  # int32 days on device: a day compares exactly
+    return dt.kind in ("i", "u", "b", "O") and not is_day
 
 
 def push_predicates(plan: LogicalPlan) -> LogicalPlan:
@@ -366,7 +414,8 @@ def push_predicates(plan: LogicalPlan) -> LogicalPlan:
             for c in _conjuncts(plan.predicate):
                 p = _as_pushdown(c)
                 if p is not None and p[0] in mapping \
-                        and _exact_under_narrowing(scan, mapping[p[0]]):
+                        and _exact_under_narrowing(scan, mapping[p[0]],
+                                                   p[2]):
                     pushed.append((mapping[p[0]], p[1], p[2]))
                 else:
                     residual.append(c)

@@ -8,9 +8,11 @@ lineages (columnar block stages, group-agg pivots, tuple combiners).
 
 Dtype contract at the device boundary (the same degrade dense_from_numpy
 applies): int64/uint64 columns whose values fit int32 narrow to int32;
-float64 narrows to float32; bool widens to int32; anything else — object
-dtypes, out-of-range int64 — makes the PLANNER compile the host tier
-instead (silent fallback, never an error)."""
+float64 narrows to float32; bool widens to int32; a date (numpy
+datetime64[D], parquet date32) becomes int32 days since 1970-01-01;
+anything else — object dtypes, out-of-range int64, datetimes finer than a
+day — makes the PLANNER compile the host tier instead (silent fallback,
+never an error)."""
 
 from __future__ import annotations
 
@@ -37,6 +39,8 @@ def coerce_dtype(np_dtype) -> str:
 
     Unicode/bytes dtypes return "dict32": the column lowers to an int32
     dictionary CODE column plus a Block.dicts sidecar (tpu/dict_encoding);
+    datetime64[D] returns "date32": int32 days (an int64 range check
+    first: NaT and dates past int32's days stay on the host);
     object dtypes stay host-only at the dtype level — whether an object
     column is all-strings needs a value scan, which only the
     sources (coerced_dtype / make_*_source) may pay."""
@@ -49,6 +53,8 @@ def coerce_dtype(np_dtype) -> str:
         return "int64?"  # needs a value-range check (fits-int32 proof)
     if dt.kind == "f":
         return "float32"
+    if dt == np.dtype("datetime64[D]"):
+        return "date32"
     if dt.kind in ("U", "S"):
         from vega_tpu.tpu import dict_encoding
 
@@ -62,8 +68,8 @@ def coerce_dtype(np_dtype) -> str:
 def coerced_dtype(name: str, col: np.ndarray) -> Tuple[np.dtype, bool]:
     """(device dtype one host column will coerce to, is_dictionary) —
     CHECK only (dtype kind + the int64 range proof + the all-str object
-    scan), no copy; the astype/encode itself runs at materialization.
-    Raises HostFallback when the host tier must serve."""
+    scan), no copy; the astype/encode itself runs at materialization (a
+    date's to `days`). Raises HostFallback when the host tier must serve."""
     from vega_tpu.tpu import dict_encoding
 
     col = np.asarray(col)
@@ -81,13 +87,26 @@ def coerced_dtype(name: str, col: np.ndarray) -> Tuple[np.dtype, bool]:
     kind = coerce_dtype(col.dtype)
     if kind == "dict32":
         return np.dtype(np.int32), True
-    if kind == "int64?":
+    if kind in ("int64?", "date32"):
         info = np.iinfo(np.int32)
-        if len(col) and (col.min() < info.min or col.max() > info.max):
+        raw = col.view(np.int64) if kind == "date32" else col
+        if len(raw) and (raw.min() < info.min or raw.max() > info.max):
             raise HostFallback(
-                f"column {name!r} holds int64 values beyond int32 range")
+                f"column {name!r} holds values beyond int32 range")
         kind = "int32"
     return np.dtype(kind), False
+
+
+def days(col: np.ndarray) -> np.ndarray:
+    """A datetime64[D] column as the device holds it: int32 days since
+    1970-01-01 (coerced_dtype proved the range)."""
+    return np.asarray(col).view(np.int64).astype(np.int32)
+
+
+def date_literal(value) -> np.int32:
+    """A day literal (datetime.date, datetime64[D]) as int32 days since
+    1970-01-01, the device's form of a date column."""
+    return np.int32(np.datetime64(value, "D").astype(np.int64))
 
 
 # ---------------------------------------------------------------------------
@@ -116,11 +135,14 @@ def make_columns_source(ctx, data: Dict[str, np.ndarray],
     dtypes = {}
     dict_bns = set()   # block names that are dictionary (string) columns
     dict_fns = set()   # same set, frame-name side (planner gates)
+    date_fns = set()   # frame names of date columns (int32 days)
     for fn, bn in names:
         dtypes[bn], is_dict = coerced_dtype(fn, data[fn])
         if is_dict:
             dict_bns.add(bn)
             dict_fns.add(fn)
+        elif np.asarray(data[fn]).dtype.kind == "M":
+            date_fns.add(fn)
     name_pairs = list(names)
     enc_memo: Dict[str, tuple] = {}  # bn -> (codes, sorted values)
 
@@ -135,6 +157,7 @@ def make_columns_source(ctx, data: Dict[str, np.ndarray],
 
     class _ColumnsDenseSource(DenseRDD):
         _frame_dict_cols = frozenset(dict_fns)
+        _frame_date_cols = frozenset(date_fns)
 
         def _schema(self):
             return tuple((bn, jnp.dtype(dtypes[bn]))
@@ -157,6 +180,8 @@ def make_columns_source(ctx, data: Dict[str, np.ndarray],
             for fn, bn in name_pairs:
                 if bn in dict_bns:
                     cols[bn], dicts[bn] = _encoded(fn, bn)
+                elif fn in date_fns:
+                    cols[bn] = days(data[fn])
                 else:
                     cols[bn] = np.asarray(data[fn]).astype(dtypes[bn],
                                                            copy=False)
@@ -181,6 +206,7 @@ def make_parquet_source(ctx, path: str, columns: List[str],
                                      iter_parquet_batches,
                                      parquet_column_minmax,
                                      parquet_column_nulls,
+                                     parquet_date_columns,
                                      parquet_string_columns)
     from vega_tpu.tpu import mesh as mesh_lib
     from vega_tpu.tpu.dense_rdd import DenseRDD
@@ -188,6 +214,7 @@ def make_parquet_source(ctx, path: str, columns: List[str],
     import jax.numpy as jnp
 
     string_cols = parquet_string_columns(path)
+    date_cols = parquet_date_columns(path)
     for nm, _op, _lit in predicate:
         if nm in string_cols:
             # A pushed-down conjunct evaluates as a numpy mask inside the
@@ -199,6 +226,7 @@ def make_parquet_source(ctx, path: str, columns: List[str],
     out_dtypes = {}
     dict_bns = set()
     dict_fns = set()
+    date_fns = set()
     for fn, bn in names:
         if fn in string_cols:
             from vega_tpu.tpu import dict_encoding
@@ -219,6 +247,15 @@ def make_parquet_source(ctx, path: str, columns: List[str],
             dict_bns.add(bn)
             dict_fns.add(fn)
             continue
+        if fn in date_cols:
+            nulls = parquet_column_nulls(path, fn)
+            if nulls is None or nulls > 0:
+                raise HostFallback(
+                    f"parquet date column {fn!r} has nulls (or no "
+                    "null-count statistics); int32 days have no null slot")
+            out_dtypes[bn] = np.dtype(np.int32)
+            date_fns.add(fn)
+            continue
         kind = coerce_dtype(dtypes[fn])
         if kind == "dict32":
             # parquet_string_columns covers arrow string types; a 'U'/'S'
@@ -226,6 +263,10 @@ def make_parquet_source(ctx, path: str, columns: List[str],
             raise HostFallback(
                 f"parquet column {fn!r}: string dtype without an arrow "
                 "string type — host tier serves it")
+        if kind == "date32":
+            raise HostFallback(
+                f"parquet column {fn!r}: a day dtype without an arrow "
+                "date32 type — host tier serves it")
         if kind == "int64?":
             mm = parquet_column_minmax(path, fn)
             info = np.iinfo(np.int32)
@@ -237,44 +278,46 @@ def make_parquet_source(ctx, path: str, columns: List[str],
         out_dtypes[bn] = np.dtype(kind)
     files = discover_parquet_files(path)
     name_pairs = list(names)
-    enc_memo: Dict[str, np.ndarray] = {}  # bn -> sorted dictionary
+    enc_memo: Dict[str, object] = {}  # bn -> sorted dictionary
 
-    def _read_encoded():
-        """One pass over the files; string columns arrive as per-batch
-        (codes, values) pairs off the arrow dictionary pages (no
-        object-array pivot) and are remapped onto ONE sorted dictionary
-        per column."""
+    def _encode(bn: str, chunks: list):
+        """One string column's arrow chunks -> its codes, over the one
+        sorted dictionary of this source (built once: dict_encoding)."""
         from vega_tpu.tpu import dict_encoding
 
+        codes, values = dict_encoding.encode_arrow(chunks)
+        if bn in enc_memo and len(enc_memo[bn]) == len(values) \
+                and enc_memo[bn].arrow.equals(values.arrow):
+            values = enc_memo[bn]  # the dictionary _dicts() handed out
+        enc_memo[bn] = values
+        return codes
+
+    def _read_encoded():
+        """One pass over the files; string columns arrive as arrow
+        dictionary arrays off the file's dictionary pages (no object-array
+        pivot) and are coded onto ONE sorted dictionary per column; dates
+        as int32 days."""
         parts: Dict[str, list] = {fn: [] for fn, _bn in name_pairs}
         for batch in iter_parquet_batches(files, columns, predicate,
                                           arrow_columns=dict_fns):
             for fn, _bn in name_pairs:
                 parts[fn].append(batch[fn])
         cols = {}
-        dicts = {}
         for fn, bn in name_pairs:
             if bn in dict_bns:
-                piece_vals = [v for _c, v in parts[fn]]
-                merged = (np.unique(np.concatenate(piece_vals))
-                          if piece_vals else np.zeros(0, "<U1"))
-                merged = enc_memo.setdefault(bn, merged)
-                if piece_vals:
-                    cols[bn] = np.concatenate([
-                        np.searchsorted(merged, v).astype(
-                            dict_encoding.CODE_DTYPE)[c]
-                        for c, v in parts[fn]])
-                else:
-                    cols[bn] = np.zeros(0, dict_encoding.CODE_DTYPE)
-                dicts[bn] = merged
-            else:
-                stacked = (np.concatenate(parts[fn]) if parts[fn]
-                           else np.empty((0,), dtypes[fn]))
-                cols[bn] = stacked.astype(out_dtypes[bn], copy=False)
-        return cols, (dicts or None)
+                cols[bn] = _encode(bn, parts[fn])
+                parts[fn] = None  # the arrow chunks go before the next
+                continue
+            stacked = (np.concatenate(parts[fn]) if parts[fn]
+                       else np.empty((0,), dtypes[fn]))
+            parts[fn] = None
+            cols[bn] = (days(stacked) if fn in date_fns
+                        else stacked.astype(out_dtypes[bn], copy=False))
+        return cols, ({bn: enc_memo[bn] for bn in dict_bns} or None)
 
     class _ParquetDenseSource(DenseRDD):
         _frame_dict_cols = frozenset(dict_fns)
+        _frame_date_cols = frozenset(date_fns)
 
         def _schema(self):
             return tuple((bn, jnp.dtype(out_dtypes[bn]))
@@ -291,26 +334,27 @@ def make_parquet_source(ctx, path: str, columns: List[str],
                 # Graph-build consumers (keyed-op unification) need the
                 # dictionaries before an action: one column-pruned read
                 # of JUST the string columns, memoized so _materialize
-                # reuses the identical sorted dictionary.
-                from vega_tpu.tpu import dict_encoding
-
+                # hands out the identical dictionary objects.
                 sub = [fn for fn, bn in name_pairs if bn in dict_bns]
                 pieces: Dict[str, list] = {fn: [] for fn in sub}
                 for batch in iter_parquet_batches(
                         files, sub, predicate, arrow_columns=set(sub)):
                     for fn in sub:
-                        pieces[fn].append(batch[fn][1])
+                        pieces[fn].append(batch[fn])
                 for fn, bn in name_pairs:
                     if bn in dict_bns:
-                        vals = pieces[fn]
-                        enc_memo[bn] = (np.unique(np.concatenate(vals))
-                                        if vals else np.zeros(0, "<U1"))
+                        _encode(bn, pieces[fn])
             return {bn: enc_memo[bn] for bn in dict_bns}
 
         def _materialize(self):
+            import pyarrow as pa
+
             from vega_tpu.tpu import block as block_lib
 
             cols, dicts = _read_encoded()
+            # the read's arrow buffers are garbage now; arrow's allocator
+            # keeps freed pages, as much again as the table, unless told
+            pa.default_memory_pool().release_unused()
             return block_lib.from_numpy(cols, self.mesh, wide_values=False,
                                         dicts=dicts)
 

@@ -35,8 +35,10 @@ import numpy as np
 from vega_tpu.errors import VegaError
 from vega_tpu.frame import logical as L
 from vega_tpu.frame import physical as P
-from vega_tpu.frame.expr import _AGG_MONOID, Col, Expr, Lit
+from vega_tpu.frame.expr import _AGG_MONOID, BinOp, Col, Expr, Lit, \
+    UnaryOp
 from vega_tpu.frame.physical import HostFallback
+from vega_tpu.tpu import spans
 
 DEFAULT_OPTIONS = {
     "fuse": True,        # whole-stage fusion (False: one program per verb)
@@ -49,9 +51,10 @@ DEFAULT_OPTIONS = {
 
 # Device-lowering fallback observability: every compile that lands on the
 # host tier because the device trace rejected the plan bumps the counter
-# and records why. Tests (and the strings A/B) use it to PROVE a plan
-# compiled to the device tier — "it returned the right rows" cannot
-# distinguish the tiers, the counter can.
+# (and the profiler session's `frame_fallbacks` count) and records why.
+# Tests (and the strings A/B) use it to PROVE a plan compiled to the
+# device tier — "it returned the right rows" cannot distinguish the tiers,
+# the counter can.
 _FALLBACKS = {"count": 0, "last": None}
 
 
@@ -72,7 +75,8 @@ class Compiled:
 
     def __init__(self, kind: str, rdd, cols: List[str],
                  out: List[Tuple[str, str]], layout: str,
-                 limit: Optional[int], plan, notes: List[str]):
+                 limit: Optional[int], plan, notes: List[str],
+                 dates=()):
         self.kind = kind          # "device" | "host"
         self.rdd = rdd
         self.cols = cols          # frame output columns, in order
@@ -81,6 +85,7 @@ class Compiled:
         self.limit = limit
         self.plan = plan
         self.notes = notes
+        self.dates = set(dates)   # device: output columns held as days
 
     def explain(self) -> str:
         head = f"== physical: {self.kind} tier =="
@@ -91,6 +96,14 @@ class Compiled:
 
 
 def compile_plan(ctx, plan: L.LogicalPlan, options: dict) -> Compiled:
+    """The logical plan as a lazy lineage, in the `frame_plan` span (which
+    yields to the spans of what planning reads: a source's dictionary
+    build, a dictionary merge, a closure's fingerprint)."""
+    with spans.span("frame_plan", yields=True):
+        return _compile_plan(ctx, plan, options)
+
+
+def _compile_plan(ctx, plan: L.LogicalPlan, options: dict) -> Compiled:
     options = {**DEFAULT_OPTIONS, **(options or {})}
     limit = None
     while isinstance(plan, L.Limit):
@@ -110,6 +123,8 @@ def compile_plan(ctx, plan: L.LogicalPlan, options: dict) -> Compiled:
                     f"lowering: {e}") from e
             _FALLBACKS["count"] += 1
             _FALLBACKS["last"] = str(e)
+            # vegalint: ignore[VG013] — a tally counter, not an RDD action
+            spans.count("frame_fallbacks")
             notes.append(f"host tier: {e}")
     else:
         notes.append("host tier: requested via hint")
@@ -168,7 +183,7 @@ class _DState:
     of the current stage."""
 
     def __init__(self, node, colmap: List[Tuple[str, str]],
-                 dict_cols=()):
+                 dict_cols=(), date_cols=()):
         self.node = node
         self.colmap = list(colmap)
         self.steps: List[tuple] = []
@@ -177,6 +192,9 @@ class _DState:
         # int32 codes): codes support equality/order/passthrough, never
         # arithmetic — _flush gates any computing expression over them.
         self.dict_cols = set(dict_cols)
+        # Frame columns held as int32 days (dates): they pass through, and
+        # compare with each other and with day literals, nothing else.
+        self.date_cols = set(date_cols)
 
 
 def _step_token(step) -> tuple:
@@ -263,11 +281,38 @@ def _flush(st: _DState, out_pairs: List[Tuple[str, Expr]], fused: bool):
                 f"expression over string column(s) "
                 f"{sorted(_refs(e) & dict_live)} computes on "
                 "dictionary codes; host tier evaluates it")
+    # Date columns (int32 days) through the stage: they pass through, and
+    # compare with each other and with day literals, which lower to the
+    # same days; anything else over a date computes on the host tier,
+    # where a date stays a date.
+    date_live = set(st.date_cols)
+
+    def _check_days(e) -> None:
+        if not isinstance(e, Col) and not _dates_compared_only(e, date_live):
+            raise HostFallback(
+                f"expression {e!r} computes on a date column or a day "
+                "literal other than by comparing days; host tier "
+                "evaluates it")
+
+    for kind, payload in steps:
+        if kind == "project":
+            for _nm, e in payload:
+                _check_days(e)
+            date_live = {nm for nm, e in payload
+                         if isinstance(e, Col) and e.name in date_live}
+        else:
+            _check_days(payload)
+    for _bn, e in out_pairs:
+        _check_days(e)
+    dev_steps = [(kind, [(nm, _lower_days(e)) for nm, e in payload]
+                  if kind == "project" else _lower_days(payload))
+                 for kind, payload in steps]
+    dev_out = [(bn, _lower_days(e)) for bn, e in out_pairs]
 
     def cols_fn(cols, count):
         cap = cols[in_names[0]].shape[0]
         env = {fn: cols[bn] for fn, bn in colmap}
-        for kind, payload in steps:
+        for kind, payload in dev_steps:
             if kind == "project":
                 env = {nm: _dev_broadcast(evaluate(e, env), cap, jnp)
                        for nm, e in payload}
@@ -277,7 +322,7 @@ def _flush(st: _DState, out_pairs: List[Tuple[str, Expr]], fused: bool):
                     & kernels.valid_mask(cap, count)
                 env, count = kernels.compact(env, keep, cap)
         out = {bn: _dev_broadcast(evaluate(e, env), cap, jnp)
-               for bn, e in out_pairs}
+               for bn, e in dev_out}
         return out, count
 
     try:
@@ -302,17 +347,72 @@ def _flush(st: _DState, out_pairs: List[Tuple[str, Expr]], fused: bool):
                              dict_renames=dict_renames)
 
 
-def _dicts_after(st: _DState, out_cols: List[str]) -> set:
-    """Frame columns still dictionary-encoded AFTER the pending steps:
-    a dict column survives a project only as a bare Col passthrough
-    (anything else already raises in _flush), and filters never change
-    column identity."""
-    live = set(st.dict_cols)
+def _passed_through(st: _DState, out_cols: List[str], live) -> set:
+    """The columns of `live` that are still what they were AFTER the
+    pending steps: a column survives a project only as a bare Col
+    passthrough (anything else over a dictionary or a date already raises
+    in _flush), and filters never change column identity."""
+    live = set(live)
     for kind, payload in st.steps:
         if kind == "project":
             live = {nm for nm, e in payload
                     if isinstance(e, Col) and e.name in live}
     return {c for c in out_cols if c in live}
+
+
+def _dicts_after(st: _DState, out_cols: List[str]) -> set:
+    """Frame columns still dictionary-encoded after the pending steps."""
+    return _passed_through(st, out_cols, st.dict_cols)
+
+
+def _dates_after(st: _DState, out_cols: List[str]) -> set:
+    """Frame columns still int32 days after the pending steps."""
+    return _passed_through(st, out_cols, st.date_cols)
+
+
+_COMPARE = ("==", "!=", "<", "<=", ">", ">=")
+
+
+def _is_day(value) -> bool:
+    import datetime
+
+    if isinstance(value, np.datetime64):
+        return np.datetime_data(value.dtype)[0] == "D"
+    return isinstance(value, datetime.date) \
+        and not isinstance(value, datetime.datetime)
+
+
+def _dates_compared_only(e: Expr, dates: set) -> bool:
+    """True when every date column and day literal in `e` is an operand of
+    a comparison of two such: what int32 days answer as dates do."""
+
+    def day(x) -> bool:
+        return (isinstance(x, Col) and x.name in dates) \
+            or (isinstance(x, Lit) and _is_day(x.value))
+
+    if isinstance(e, BinOp):
+        if e.op in _COMPARE and (day(e.left) or day(e.right)):
+            return day(e.left) and day(e.right)
+        return _dates_compared_only(e.left, dates) \
+            and _dates_compared_only(e.right, dates)
+    if isinstance(e, UnaryOp):
+        return _dates_compared_only(e.operand, dates)
+    if isinstance(e, Lit):
+        return not _is_day(e.value)
+    refs: set = set()
+    e.references(refs)
+    return not refs & dates
+
+
+def _lower_days(e: Expr) -> Expr:
+    """`e` with every day literal as its int32 days (P.date_literal)."""
+    if isinstance(e, Lit) and _is_day(e.value):
+        return Lit(P.date_literal(e.value))
+    if isinstance(e, BinOp):
+        return BinOp(e.op, _lower_days(e.left), _lower_days(e.right))
+    if isinstance(e, UnaryOp):
+        return UnaryOp(e.op, _lower_days(e.operand))
+    return e
 
 
 def _key_dtype(node, allowed) -> None:
@@ -372,7 +472,8 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         names = [(fn, _sanitize(fn, taken)) for fn in plan.data]
         node = P.make_columns_source(ctx, plan.data, names)
         st = _DState(node, names,
-                     dict_cols=getattr(node, "_frame_dict_cols", ()))
+                     dict_cols=getattr(node, "_frame_dict_cols", ()),
+                     date_cols=getattr(node, "_frame_date_cols", ()))
         st.est_rows = len(next(iter(plan.data.values()))) if plan.data \
             else 0
         return st
@@ -391,7 +492,8 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         node = P.make_parquet_source(ctx, plan.path, cols, plan.predicate,
                                      names, dtypes)
         st = _DState(node, names,
-                     dict_cols=getattr(node, "_frame_dict_cols", ()))
+                     dict_cols=getattr(node, "_frame_dict_cols", ()),
+                     date_cols=getattr(node, "_frame_date_cols", ()))
         try:
             from vega_tpu.io.readers import parquet_num_rows
 
@@ -399,6 +501,8 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         except Exception:  # noqa: BLE001 — estimate only
             st.est_rows = None
         return st
+    if isinstance(plan, L.CachedScan):
+        return plan.cache.device(ctx, options, notes)
     if isinstance(plan, L.Project):
         st = _lower_device(ctx, plan.child, options, notes)
         st.steps.append(("project", list(plan.outputs)))
@@ -415,11 +519,21 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         st = _lower_device(ctx, plan.child, options, notes)
         specs, slots = _agg_specs(plan)
         live = _dicts_after(st, plan.child.columns())
+        dlive = _dates_after(st, plan.child.columns())
         ops = [m for _bn, _e, m in specs]
         dict_specs = set()
+        date_specs = set()
         for bn, e, m in specs:
             refs: set = set()
             e.references(refs)
+            if refs & dlive:
+                # min/max of a date column is a date; any other aggregate
+                # over int32 days has no date meaning on either tier
+                if m not in ("min", "max") or not isinstance(e, Col):
+                    raise HostFallback(
+                        f"aggregate '{m}' over date column(s) "
+                        f"{sorted(refs & dlive)}; host tier aggregates it")
+                date_specs.add(bn)
             if refs & live:
                 # Rank codes make min/max of a string column sound on
                 # device; every other monoid would fold dictionary codes.
@@ -449,7 +563,9 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         out = _DState(red, [(plan.key, "k")] + [
             (bn, bn) for bn, _e, _m in specs],
             dict_cols=(({plan.key} if plan.key in live else set())
-                       | dict_specs))
+                       | dict_specs),
+            date_cols=(({plan.key} if plan.key in dlive else set())
+                       | date_specs))
         out.est_rows = st.est_rows
         # Mean finalization (and companion drop) rides the NEXT stage.
         proj = [(plan.key, Col(plan.key))]
@@ -471,36 +587,53 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         rst = _lower_device(ctx, plan.right, options, notes)
         lvals = [c for c in plan.left.columns() if c != plan.on]
         rvals = [c for c in plan.right.columns() if c != plan.on]
-        if len(lvals) != 1 or len(rvals) != 1:
+        ldict = _dicts_after(lst, plan.left.columns())
+        rdict = _dicts_after(rst, plan.right.columns())
+        ldate = _dates_after(lst, plan.left.columns())
+        rdate = _dates_after(rst, plan.right.columns())
+        if (plan.on in ldate) != (plan.on in rdate):
             raise HostFallback(
-                "device join needs exactly one value column per side "
-                f"(have {lvals} x {rvals}); host tier joins the rest")
-        lnode = _flush(lst, [("k", Col(plan.on)), ("v", Col(lvals[0]))],
+                f"join key {plan.on!r} is a date on one side only; host "
+                "tier joins it")
+        if plan.how != "inner" and (plan.fill_value is None
+                                    or set(rvals) & (rdict | rdate)):
+            # The kernel fills an unmatched row's right columns with one
+            # scalar: no None, and no string or date a code or a day
+            # would decode to.
+            raise HostFallback(
+                "left join whose fill has no device form (None, or a "
+                "string or date right column); host tier joins it")
+        # Value columns by position (l0.., r0..): the join keeps each
+        # side's names, and positional names can neither collide nor
+        # take the canonical VALUE's lv/rv renames.
+        lnames = [f"l{i}" for i in range(len(lvals))]
+        rnames = [f"r{i}" for i in range(len(rvals))]
+        lnode = _flush(lst, [("k", Col(plan.on))]
+                       + [(bn, Col(c)) for bn, c in zip(lnames, lvals)],
                        bool(options["fuse"]))
-        rnode = _flush(rst, [("k", Col(plan.on)), ("v", Col(rvals[0]))],
+        rnode = _flush(rst, [("k", Col(plan.on))]
+                       + [(bn, Col(c)) for bn, c in zip(rnames, rvals)],
                        bool(options["fuse"]))
         _key_dtype(lnode, ("int32",))
         _key_dtype(rnode, ("int32",))
-        exchange = _pick_exchange(ctx, options, lst, 2, notes)
-        if plan.how == "inner":
-            joined = lnode.join(rnode, exchange=exchange)
-        else:
-            joined = lnode.left_outer_join(
-                rnode, fill_value=plan.fill_value, exchange=exchange)
-        from vega_tpu.tpu.dense_rdd import DenseRDD
+        exchange = _pick_exchange(ctx, options, lst,
+                                  1 + len(lvals) + len(rvals), notes)
+        from vega_tpu.tpu.dense_rdd import dense_join
 
-        if not isinstance(joined, DenseRDD):
-            raise HostFallback("join degraded to the host path")
-        notes.append(f"join: device sort-merge ({plan.how})")
-        llive = _dicts_after(lst, plan.left.columns())
-        rlive = _dicts_after(rst, plan.right.columns())
-        out = _DState(joined, [(plan.on, "k"), (lvals[0], "lv"),
-                               (rvals[0], "rv")],
-                      dict_cols=(({plan.on} if plan.on in llive else set())
-                                 | ({lvals[0]} if lvals[0] in llive
-                                    else set())
-                                 | ({rvals[0]} if rvals[0] in rlive
-                                    else set())))
+        joined = dense_join(lnode, rnode, outer=plan.how != "inner",
+                            fill_value=plan.fill_value, exchange=exchange)
+        if joined is None:
+            raise HostFallback(
+                f"join key {plan.on!r} has no common device form on the "
+                "two sides (strings against numbers, or key dtypes differ)")
+        notes.append(f"join: device sort-merge ({plan.how}), "
+                     f"{len(lvals)} x {len(rvals)} value columns")
+        out = _DState(joined, [(plan.on, "k")] + list(zip(lvals, lnames))
+                      + list(zip(rvals, rnames)),
+                      dict_cols=(({plan.on} | set(lvals)) & ldict)
+                      | (set(rvals) & rdict),
+                      date_cols=(({plan.on} | set(lvals)) & ldate)
+                      | (set(rvals) & rdate))
         out.est_rows = lst.est_rows
         return out
     if isinstance(plan, L.Sort):
@@ -517,7 +650,8 @@ def _lower_device(ctx, plan: L.LogicalPlan, options: dict,
         notes.append("sort: device sample-sort exchange")
         out = _DState(sorted_node, [(plan.by, "k")] + list(
             zip(others, [bn for bn, _e in pairs[1:]])),
-            dict_cols=_dicts_after(st, plan.columns()))
+            dict_cols=_dicts_after(st, plan.columns()),
+            date_cols=_dates_after(st, plan.columns()))
         out.est_rows = st.est_rows
         return out
     raise HostFallback(f"no device lowering for {type(plan).__name__}")
@@ -531,7 +665,8 @@ def _unfused_break(st: _DState, cols: List[str], options: dict) -> _DState:
     pairs = [(_sanitize(c, taken), Col(c)) for c in cols]
     node = _flush(st, pairs, fused=False)
     out = _DState(node, list(zip(cols, [bn for bn, _e in pairs])),
-                  dict_cols=_dicts_after(st, cols))
+                  dict_cols=_dicts_after(st, cols),
+                  date_cols=_dates_after(st, cols))
     out.est_rows = st.est_rows
     return out
 
@@ -554,10 +689,9 @@ def _traced_tuple_combiner(ops: List[str]):
     return combine
 
 
-def _compile_device(ctx, plan: L.LogicalPlan, options: dict,
-                    limit: Optional[int], notes: List[str]) -> Compiled:
-    st = _lower_device(ctx, plan, options, notes)
-    cols = plan.columns()
+def _settled(st: _DState, cols: List[str], options: dict):
+    """(node, [(frame name, block name)], dictionary columns, date columns)
+    of `cols` once the pending steps are flushed."""
     if st.steps:
         taken: set = set()
         pairs = [(_sanitize(c, taken), Col(c)) for c in cols]
@@ -567,7 +701,63 @@ def _compile_device(ctx, plan: L.LogicalPlan, options: dict,
         node = st.node
         cm = dict(st.colmap)
         out = [(c, cm[c]) for c in cols]
-    return Compiled("device", node, cols, out, "block", limit, plan, notes)
+    return node, out, _dicts_after(st, cols), _dates_after(st, cols)
+
+
+def _compile_device(ctx, plan: L.LogicalPlan, options: dict,
+                    limit: Optional[int], notes: List[str]) -> Compiled:
+    st = _lower_device(ctx, plan, options, notes)
+    cols = plan.columns()
+    node, out, _dicts, dates = _settled(st, cols, options)
+    return Compiled("device", node, cols, out, "block", limit, plan, notes,
+                    dates=dates)
+
+
+class FrameCache:
+    """What a cached frame (DataFrame.cache(), logical.CachedScan) lowers
+    to: its plan's lineage, built at the first compile on each tier and
+    handed to every later one, so the device node — and the block it
+    materializes once, under the HBM LRU like any DenseRDD's — is the same
+    object from action to action, and a later action reads no file. A
+    plan with no device lowering records why, and every device compile
+    falls back for that reason."""
+
+    def __init__(self, plan: L.LogicalPlan):
+        self.plan = plan
+        self._device = None  # (node, colmap, dicts, dates, est) | fallback
+        self._host = None    # (rdd, layout, cols)
+
+    def _optimized(self, options: dict) -> L.LogicalPlan:
+        pushdown = bool(options["pushdown"])
+        return L.optimize(self.plan, pushdown=pushdown) if pushdown \
+            else self.plan
+
+    def device(self, ctx, options: dict, notes: List[str]) -> _DState:
+        if self._device is None:
+            try:
+                st = _lower_device(ctx, self._optimized(options), options,
+                                   notes)
+                node, out, dicts, dates = _settled(st, self.plan.columns(),
+                                                   options)
+                node.cache()
+                self._device = (node, out, dicts, dates, st.est_rows)
+            except HostFallback as e:
+                self._device = e
+        if isinstance(self._device, HostFallback):
+            raise HostFallback(f"cached frame: {self._device}")
+        node, out, dicts, dates, est = self._device
+        notes.append("cached frame: its resident lineage")
+        st = _DState(node, out, dict_cols=dicts, date_cols=dates)
+        st.est_rows = est
+        return st
+
+    def host(self, ctx, options: dict) -> "_HState":
+        if self._host is None:
+            st = _host_flush_blocks(
+                _lower_host(ctx, self._optimized(options), options))
+            st.rdd.cache()
+            self._host = (st.rdd, st.layout, st.cols)
+        return _host_state(*self._host)
 
 
 # ---------------------------------------------------------------------------
@@ -618,6 +808,8 @@ def _lower_host(ctx, plan: L.LogicalPlan, options: dict) -> _HState:
                   for i in range(max(1, -(-n // per) if n else 1))]
         return _host_state(ctx.parallelize(chunks, len(chunks)),
                            "blocks", cols)
+    if isinstance(plan, L.CachedScan):
+        return plan.cache.host(ctx, options)
     if isinstance(plan, L.ParquetScan):
         from vega_tpu.io.readers import ParquetColumnReader
 

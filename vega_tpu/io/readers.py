@@ -209,6 +209,15 @@ def _row_group_may_match(meta_rg, col_index: dict, predicate) -> bool:
     return True
 
 
+_FILES_READ = [0]
+
+
+def parquet_files_read() -> int:
+    """Parquet files whose rows iter_parquet_batches has read, in this
+    process: a cached frame's later actions leave it where it was."""
+    return _FILES_READ[0]
+
+
 def iter_parquet_batches(paths: List[str], columns: Optional[List[str]],
                          predicate=None, batch_rows: int = 1 << 20,
                          arrow_columns=None):
@@ -216,13 +225,14 @@ def iter_parquet_batches(paths: List[str], columns: Optional[List[str]],
     pushdown applied inside the reader. Columns the query never names and
     rows no conjunct can accept never leave the file layer.
 
-    Columns named in `arrow_columns` skip the numpy pivot: each is
-    dictionary-encoded ON THE ARROW SIDE (string columns ride the file's
-    dictionary pages straight through — no per-row Python objects) and
-    yielded as a `(codes int32, values '<U') numpy pair` instead of a
-    flat array. Predicate columns are excluded — the conjunct mask
+    Columns named in `arrow_columns` (string columns) skip the numpy pivot:
+    each is read as an arrow dictionary array (the file's dictionary pages
+    straight through, no per-row Python objects) and yielded as that arrow
+    array, for tpu/dict_encoding.encode_arrow to build one sorted
+    dictionary from. Predicate columns are excluded — the conjunct mask
     evaluates on numpy values."""
     import numpy as np
+    import pyarrow as pa
     import pyarrow.parquet as pq
 
     predicate = list(predicate or ())
@@ -235,7 +245,8 @@ def iter_parquet_batches(paths: List[str], columns: Optional[List[str]],
         extra = [nm for nm, _op, _v in predicate if nm not in columns]
         read_cols = list(columns) + sorted(set(extra))
     for path in paths:
-        pf = pq.ParquetFile(path)
+        _FILES_READ[0] += 1
+        pf = pq.ParquetFile(path, read_dictionary=sorted(arrow_columns))
         names = pf.schema_arrow.names
         col_index = {nm: i for i, nm in enumerate(names)}
         if predicate:
@@ -245,29 +256,28 @@ def iter_parquet_batches(paths: List[str], columns: Optional[List[str]],
             if not groups:
                 continue
         else:
-            groups = None  # all
-        for batch in pf.iter_batches(batch_size=batch_rows,
-                                     columns=read_cols, row_groups=groups):
+            groups = range(pf.metadata.num_row_groups)
+        # A row group a read: its batches share the group's dictionary
+        # pages (iter_batches hands every batch a copy of a column's whole
+        # dictionary, which a high-cardinality string column multiplies by
+        # the batches of a group).
+        for batch in (b for g in groups
+                      for b in pf.read_row_group(g, columns=read_cols)
+                      .to_batches(max_chunksize=batch_rows)):
             block = {}
             for i, name in enumerate(batch.schema.names):
                 col = batch.column(i)
-                if name in arrow_columns:
-                    enc = col.dictionary_encode()
-                    codes = np.asarray(
-                        enc.indices.to_numpy(zero_copy_only=False)
-                    ).astype(np.int32, copy=False)
-                    vals = np.asarray(enc.dictionary).astype(np.str_)
-                    block[name] = (codes, vals)
-                else:
-                    block[name] = col.to_numpy(zero_copy_only=False)
+                block[name] = (col if name in arrow_columns
+                               else col.to_numpy(zero_copy_only=False))
             if predicate:
                 mask = None
                 for nm, op, lit in predicate:
                     m = _PRED_OPS[op](block[nm], lit)
                     mask = m if mask is None else (mask & m)
                 if mask is not None and not np.all(mask):
+                    keep = pa.array(np.asarray(mask, dtype=bool))
                     block = {
-                        nm: ((c[0][mask], c[1]) if nm in arrow_columns
+                        nm: (c.filter(keep) if nm in arrow_columns
                              else c[mask])
                         for nm, c in block.items()
                     }
@@ -290,6 +300,7 @@ _META_CACHE_MAX = 1024
 def _file_meta(path: str) -> dict:
     import os as _os
 
+    import numpy as np
     import pyarrow.parquet as pq
 
     st = _os.stat(path)
@@ -328,12 +339,20 @@ def _file_meta(path: str) -> dict:
                 break
             total += stats.null_count
         nulls[name] = total
+    # a column written from an arrow dictionary array keeps that type in
+    # the file's arrow schema: its values' type is the column's
+    types = {f.name: (f.type.value_type if pa.types.is_dictionary(f.type)
+                      else f.type) for f in pf.schema_arrow}
+    dates = {nm for nm, t in types.items() if pa.types.is_date32(t)}
     meta = {
-        "schema": {f.name: f.type.to_pandas_dtype()
-                   for f in pf.schema_arrow},
-        "strings": {f.name for f in pf.schema_arrow
-                    if pa.types.is_string(f.type)
-                    or pa.types.is_large_string(f.type)},
+        # a date32 column is whole days: datetime64[D], not arrow's
+        # pandas default of datetime64[ms]
+        "schema": {nm: (np.dtype("datetime64[D]") if nm in dates
+                        else t.to_pandas_dtype())
+                   for nm, t in types.items()},
+        "dates": dates,
+        "strings": {nm for nm, t in types.items()
+                    if pa.types.is_string(t) or pa.types.is_large_string(t)},
         "num_rows": m.num_rows,
         "minmax": minmax,
         "nulls": nulls,
@@ -365,6 +384,15 @@ def parquet_string_columns(path: str) -> set:
     out: set = set()
     for f in discover_parquet_files(path):
         out |= _file_meta(f)["strings"]
+    return out
+
+
+def parquet_date_columns(path: str) -> set:
+    """Column names with an arrow date32 type (whole days), from metadata
+    only: the device holds them as int32 days since 1970-01-01."""
+    out: set = set()
+    for f in discover_parquet_files(path):
+        out |= _file_meta(f)["dates"]
     return out
 
 

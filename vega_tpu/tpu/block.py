@@ -114,7 +114,9 @@ def _decode_dict_cols(cols: dict, dicts) -> dict:
     '.lo' pair, so the two decodes touch disjoint columns."""
     if not dicts:
         return cols
-    return {name: (dicts[name][np.asarray(col)] if name in dicts else col)
+    from vega_tpu.tpu.dict_encoding import decode_codes
+
+    return {name: (decode_codes(col, dicts[name]) if name in dicts else col)
             for name, col in cols.items()}
 
 

@@ -2643,11 +2643,13 @@ def _align_keys(a: DenseRDD, b: DenseRDD):
 
 class _DictUnification:
     """Shared host-side dictionary merge for one binary op: both sides'
-    _DictUnifyRDD wrappers reference ONE instance, so the merge runs once
-    and the sides agree bit-identically on the unified code space. The
-    merge itself (np.union1d + searchsorted remap tables,
-    dict_encoding.merge_dicts) is lazy — graph construction stays cheap
-    until a wrapper actually needs the tables."""
+    _DictUnifyRDD wrappers reference ONE instance, so the sides agree
+    bit-identically on the unified code space. The merge itself
+    (dict_encoding.merge_dicts: the sorted union and the remap tables) is
+    lazy — graph construction stays cheap until a wrapper needs the
+    tables — and runs once per pair of dictionaries: merge_dicts memoizes
+    it on their identity, so the unification every action of a frame over
+    cached sources builds anew costs a lookup (in the `dict_unify` span)."""
 
     def __init__(self, left_dicts, right_dicts, names):
         self.names = tuple(names)
@@ -2659,13 +2661,14 @@ class _DictUnification:
         """(merged, left_maps, right_maps): per-name merged sorted
         dictionary plus int32 remap tables (old code -> merged code)."""
         if self._memo is None:
-            from vega_tpu.tpu import dict_encoding
-
             merged, lmaps, rmaps = {}, {}, {}
-            for nm in self.names:
-                m, lt, rt = dict_encoding.merge_dicts(
-                    self._left[nm], self._right[nm])
-                merged[nm], lmaps[nm], rmaps[nm] = m, lt, rt
+            with spans.span("dict_unify") as sp:
+                for nm in self.names:
+                    m, lt, rt = dict_encoding.merge_dicts(
+                        self._left[nm], self._right[nm])
+                    merged[nm], lmaps[nm], rmaps[nm] = m, lt, rt
+                sp.nbytes = sum(lmaps[nm].nbytes + rmaps[nm].nbytes
+                                for nm in self.names)
             self._memo = (merged, lmaps, rmaps)
         return self._memo
 
@@ -2685,13 +2688,15 @@ class _DictUnification:
 class _DictUnifyRDD(_NarrowRDD):
     """Remap one side's dictionary codes onto the shared merged
     dictionary: ONE device gather through a staged remap table per
-    unified column. The staged table capacity is a REAL capacity
-    (Configuration.dense_dict_capacity): a valid code at or past the
-    staged prefix sets the device overflow flag — checked on the RAW
-    codes — and the driver retries with the capacity doubled. Monotonic
-    remap (sorted dicts in, sorted merge out), so per-shard key order
-    survives; hash placement does NOT (the codes hashed into buckets
-    changed), hence the default hash_placed False."""
+    unified column (the `dict_remap` stage). A table stages whole, at
+    Configuration.dense_dict_capacity entries or the next power of two
+    that holds it, so its shape repeats for as long as the dictionaries
+    do and an action over cached sources mints nothing. The device still
+    flags any valid code outside its table — checked on the RAW codes —
+    and the driver refuses the block: such a code is outside its
+    dictionary. Monotonic remap (sorted dicts in, sorted merge out), so
+    per-shard key order survives; hash placement does NOT (the codes
+    hashed into buckets changed), hence the default hash_placed False."""
 
     _chainable = False  # own program (replicated table operands)
 
@@ -2699,7 +2704,7 @@ class _DictUnifyRDD(_NarrowRDD):
         super().__init__(parent, parent._schema())
         self._unif = unif
         self._side = side
-        self._dict_retries = 0  # overflow->grown-capacity rounds (tests)
+        self._table_capacity = 0  # entries each staged table held (tests)
         self._user_fn = ("dict_unify", side, unif.token())
 
     def _dicts(self):
@@ -2718,8 +2723,6 @@ class _DictUnifyRDD(_NarrowRDD):
         self.parent._settle_placement()
 
     def _materialize(self) -> Block:
-        from vega_tpu.tpu import dict_encoding
-
         blk = self.parent.block()
         _, lmaps, rmaps = self._unif.tables()
         side_tables = lmaps if self._side == 0 else rmaps
@@ -2727,20 +2730,20 @@ class _DictUnifyRDD(_NarrowRDD):
         if not names:
             return blk
         in_names = list(blk.cols)
-        cap_tab = max(128, dict_encoding.dict_capacity())
-        table_n = max(len(side_tables[nm]) for nm in names)
-        for _round in range(8):
-            staged_n = tuple(min(len(side_tables[nm]), cap_tab)
-                             for nm in names)
-            tabs = []
-            for nm, sn in zip(names, staged_n):
-                t = np.zeros(cap_tab, dtype=np.int32)
-                t[:sn] = side_tables[nm][:sn]
-                tabs.append(mesh_lib.host_put(
-                    t, mesh_lib.replicated_spec(self.mesh)))
-            n_tab = len(names)
+        staged_n = tuple(len(side_tables[nm]) for nm in names)
+        cap_tab = max(128, dict_encoding.dict_capacity(),
+                      1 << max(0, max(staged_n) - 1).bit_length())
+        self._table_capacity = cap_tab
+        tabs = []
+        for nm, sn in zip(names, staged_n):
+            t = np.zeros(cap_tab, dtype=np.int32)
+            t[:sn] = side_tables[nm]
+            tabs.append(mesh_lib.host_put(
+                t, mesh_lib.replicated_spec(self.mesh)))
+        n_tab = len(names)
 
-            def prog_fn(*args):
+        def prog_fn(*args):
+            with spans.stage("dict_remap"):
                 tables = dict(zip(names, args[:n_tab]))
                 counts = args[n_tab]
                 cols = dict(zip(in_names, args[n_tab + 1:]))
@@ -2751,40 +2754,37 @@ class _DictUnifyRDD(_NarrowRDD):
                 out = dict(cols)
                 for nm, sn in zip(names, staged_n):
                     codes = cols[nm]
-                    # Overflow checked on the RAW codes (never the
-                    # clamped gather index): any valid code past the
-                    # staged prefix means the table was truncated.
-                    bad = valid & ((codes < 0)
-                                   | (codes >= jnp.int32(sn)))
+                    # Checked on the RAW codes (never the clamped gather
+                    # index): a valid code past its table is outside its
+                    # dictionary.
+                    bad = valid & ((codes < 0) | (codes >= jnp.int32(sn)))
                     flag = flag | jnp.any(bad).astype(jnp.int32)
                     out[nm] = jnp.take(
                         tables[nm], jnp.clip(codes, 0, cap_tab - 1))
                 return ((flag.reshape(1),)
                         + tuple(out[nm] for nm in in_names))
 
-            prog = _cached_program(
-                ("dict_remap", self.mesh, tuple(in_names), tuple(names),
-                 cap_tab, staged_n, blk.capacity),
-                lambda: _shard_program(
-                    self.mesh, prog_fn,
-                    tuple([_REPL] * n_tab) + (_SPEC,) * (1 + len(in_names)),
-                    (_SPEC,) * (1 + len(in_names)),
-                ),
-            )
-            outs = prog(*tabs, blk.counts,
-                        *[blk.cols[nm] for nm in in_names])
-            flag = np.asarray(mesh_lib.host_get(outs[0]))
-            if not flag.any():
-                return Block(
-                    cols=dict(zip(in_names, outs[1:])),
-                    counts=blk.counts, capacity=blk.capacity,
-                    mesh=self.mesh, counts_host=blk.counts_host,
-                )
-            self._dict_retries += 1
-            cap_tab *= 2
-        raise VegaError(
-            f"dictionary remap overflowed {table_n} entries after 8 "
-            "capacity-doubling retries — raise dense_dict_capacity"
+        prog = _cached_program(
+            ("dict_remap", self.mesh, tuple(in_names), tuple(names),
+             cap_tab, staged_n, blk.capacity),
+            lambda: _shard_program(
+                self.mesh, prog_fn,
+                tuple([_REPL] * n_tab) + (_SPEC,) * (1 + len(in_names)),
+                (_SPEC,) * (1 + len(in_names)),
+            ),
+        )
+        outs = prog(*tabs, blk.counts, *[blk.cols[nm] for nm in in_names])
+        flag, counts = mesh_lib.host_get((outs[0], blk.counts))  # one RTT
+        if np.asarray(flag).any():
+            raise VegaError(
+                f"dictionary remap of {names}: a code outside its "
+                f"dictionary of {staged_n} entries")
+        spans.count("dict_remap_rows", int(np.asarray(counts).sum()) * n_tab)
+        spans.count("dict_remap_table_entries", sum(staged_n))
+        return Block(
+            cols=dict(zip(in_names, outs[1:])),
+            counts=blk.counts, capacity=blk.capacity,
+            mesh=self.mesh, counts_host=blk.counts_host,
         )
 
 
@@ -2871,6 +2871,21 @@ def dense_pipeline(parent: DenseRDD, cols_fn, out_schema, token,
     parent column through unchanged to that parent column's name."""
     return _ColsPipelineRDD(parent, cols_fn, out_schema, token, fused=fused,
                             dict_renames=dict_renames)
+
+
+def dense_join(left: DenseRDD, right: DenseRDD, outer: bool = False,
+               fill_value=0, exchange: Optional[str] = None):
+    """The device sort-merge join of two named pair blocks (the frame
+    planner's join): KEY plus any number of value columns a side, each
+    output column under its side's name, so the two sides' value names
+    must differ and must not be `v`, `lv` or `rv`. Dictionary keys are
+    unified first. None where only the host tier matches the keys
+    faithfully (mismatched key dtypes, strings against numbers)."""
+    pair = _align_keys(left, right)
+    if pair is None:
+        return None
+    return _with_exchange(
+        _JoinRDD(*pair, outer=outer, fill_value=fill_value), exchange)
 
 
 # ---------------------------------------------------------------------------
@@ -3933,9 +3948,8 @@ class _ReduceByKeyRDD(_ExchangeRDD):
                 # the rebuilt codes stay in the lineage's code space —
                 # from_numpy minting a fresh local dictionary here would
                 # diverge from what _dicts() reports downstream.
-                out_cols[KEY] = np.searchsorted(
-                    kdict, np.asarray(list(slot_of), dtype=kdict.dtype),
-                ).astype(dict_encoding.CODE_DTYPE)
+                out_cols[KEY] = dict_encoding.lookup_codes(
+                    kdict, list(slot_of))
             else:
                 out_cols[KEY] = np.asarray(list(slot_of), dtype=keys.dtype)
         for nm in vnames:

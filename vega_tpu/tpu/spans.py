@@ -29,7 +29,12 @@ its own, and `encode wait` is the caller's wait for it), `decode` (each shard's
 valid rows written once into a result column allocated at its full length,
 an int64's two words joined in that pass, the dictionary decode of a fetched
 block), `pivot` (columns -> Python row objects), `fingerprint` (pickling a closure
-for a program-cache key).
+for a program-cache key), `frame_plan` (`planner.compile_plan`, a frame's
+logical plan to its lineage; it yields to the spans inside it, below),
+`dict_build` (a source's sorted dictionary of one string column, bytes the
+dictionary's: `dict_encoding.encode_arrow`) and `dict_unify` (the host merge
+of two dictionaries a keyed binary op unifies, memoized per pair of
+dictionaries: `dense_rdd._DictUnification.tables`).
 
 Counters: `count(name, n=1)` adds `n` to a count-only entry of the same tally
 (seconds and bytes stay 0; no annotation, nothing to nest). `exchange` (one a
@@ -46,7 +51,12 @@ group)` for `staged`, `n - 1` for `ring`, so a traced window says which
 collective program ran; one shard plans nothing and adds nothing);
 `stream_chunks` (one a chunk a streamed source builds: `tpu/stream.py`) and
 `stream_fold_rows` (the rows entering each re-reduce of a streamed reduce's
-accumulator, from host-known counts, never a fetch).
+accumulator, from host-known counts, never a fetch); `frame_fallbacks` (one a
+frame compile that the device lowering refused and the host tier took:
+`planner.fallback_count()`'s count, in the tally); `dict_remap_rows` and
+`dict_remap_table_entries` (a `_DictUnifyRDD` launch's valid rows times the
+columns it remapped, read with its overflow flag, and the entries of the
+tables it staged).
 
 Programs are rare (2-3 a run), so `programs()` is always recorded:
 {kind: {"mints", "first_call_s"}}, the second the host seconds of each minted
@@ -82,6 +92,8 @@ the innermost `vega.` scope of an instruction is its stage:
   topk              the selection of `take_ordered` / `top` (`lax.top_k`, the
                     slice of the sorted rows)
   sample            `sort_by_key`'s strided key sample (`sortsamp`)
+  dict_remap        `dense_rdd._DictUnifyRDD`'s gather of a side's string
+                    codes through its staged remap table
 
 `program_stages()` is the table: for every minted program the instructions of
 its compiled text, each with the stage its metadata names. The first call of a
@@ -99,7 +111,7 @@ import threading
 import time
 
 _lock = threading.Lock()
-_depth = threading.local()  # .n: spans open on this thread
+_depth = threading.local()  # .top: the innermost span open on this thread
 _live = False  # a span has seen the profiler session that is running
 _nested = 0
 _session: dict = {}
@@ -137,15 +149,33 @@ def _entry(name: str) -> dict:
 class span:
     """`with span(name, kind) as sp:`; `sp.on` says whether a session is
     recording, and `sp.nbytes` may be set before the block ends (on a hot
-    path, count it under `if sp.on`)."""
+    path, count it under `if sp.on`). A span made with `yields=True` covers
+    a whole phase (`frame_plan`): a span that opens inside it on its thread
+    pauses it until that span closes, so the phase's seconds and annotations
+    are what its inner spans leave, the spans stay flat, and `nested()` does
+    not count them."""
 
-    __slots__ = ("name", "kind", "nbytes", "on", "_ann", "_t0")
+    __slots__ = ("name", "kind", "nbytes", "on", "yields", "_ann", "_t0",
+                 "_seconds", "_outer")
 
-    def __init__(self, name: str, kind: str = "", nbytes: int = 0):
+    def __init__(self, name: str, kind: str = "", nbytes: int = 0,
+                 yields: bool = False):
         self.name = name
         self.kind = kind
         self.nbytes = nbytes
         self.on = False
+        self.yields = yields
+
+    def _start(self) -> None:
+        self._ann = TraceAnnotation(
+            f"vega:{self.name} {self.kind}" if self.kind
+            else "vega:" + self.name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def _pause(self) -> None:
+        self._seconds += time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
 
     def __enter__(self):
         global _live, _nested
@@ -154,34 +184,36 @@ class span:
                 _live = False
             return self
         self.on = True
-        depth = getattr(_depth, "n", 0)
-        _depth.n = depth + 1
-        if depth or not _live:
+        outer = getattr(_depth, "top", None)
+        self._outer = outer
+        _depth.top = self
+        nested = outer is not None and not outer.yields
+        if nested or not _live:
             with _lock:
-                if depth:
+                if nested:
                     _nested += 1
                 if not _live:
                     _session.clear()
                     _live = True
-        self._ann = TraceAnnotation(
-            f"vega:{self.name} {self.kind}" if self.kind
-            else "vega:" + self.name)
-        self._ann.__enter__()
-        self._t0 = time.perf_counter()
+        if outer is not None and outer.yields:
+            outer._pause()
+        self._seconds = 0.0
+        self._start()
         return self
 
     def __exit__(self, *exc):
         if not self.on:
             return False
-        seconds = time.perf_counter() - self._t0
-        self._ann.__exit__(*exc)
-        _depth.n -= 1
+        self._pause()
+        _depth.top = self._outer
+        if self._outer is not None and self._outer.yields:
+            self._outer._start()
         with _lock:
             acc = _entry(self.name)
-            _add(acc, seconds, self.nbytes)
+            _add(acc, self._seconds, self.nbytes)
             if self.kind:
                 _add(acc["by_kind"].setdefault(self.kind, _zero()),
-                     seconds, self.nbytes)
+                     self._seconds, self.nbytes)
         return False
 
 
@@ -249,7 +281,7 @@ def program_mints() -> int:
 
 STAGES = ("narrow", "named_reduce", "key_sort", "segment_reduce",
           "merge_join", "exchange_group", "exchange_send", "exchange_wire",
-          "exchange_compact", "topk", "sample")
+          "exchange_compact", "topk", "sample", "dict_remap")
 
 _STAGE_OF = re.compile(r"vega\.([a-z_]+)")
 _INSTRUCTION = re.compile(r"\s+(?:ROOT )?%?([\w.\-]+) = (.*)")
