@@ -15,6 +15,9 @@
 //                         pickle for numeric shuffle buckets
 //   hash_i64            : splitmix64 over a raw int64 buffer, bit-identical
 //                         to vega_tpu.partitioner.splitmix64 (parity oracle)
+//   rows_from_columns   : the dense tier's collect() pivot — Python row
+//                         tuples straight from decoded numpy columns, each
+//                         untracked by the cyclic collector as it is made
 //
 // Integer values accumulate in int64 (exact); if accumulation overflows
 // int64 the whole call REJECTS (returns None) and the caller redoes the
@@ -533,6 +536,204 @@ static PyObject* hash_i64(PyObject*, PyObject* args) {
   return out;
 }
 
+// ---- collect() pivot: columns -> row tuples --------------------------------
+
+// One element of a column as the Python object ndarray.tolist() makes of it
+// (new reference, nullptr with the error set).
+typedef PyObject* (*ToPy)(const char*);
+
+template <typename T>
+static PyObject* signed_to_py(const char* p) {
+  T x;
+  std::memcpy(&x, p, sizeof(T));
+  return PyLong_FromLongLong(static_cast<long long>(x));
+}
+
+template <typename T>
+static PyObject* unsigned_to_py(const char* p) {
+  T x;
+  std::memcpy(&x, p, sizeof(T));
+  return PyLong_FromUnsignedLongLong(static_cast<unsigned long long>(x));
+}
+
+template <typename T>
+static PyObject* float_to_py(const char* p) {
+  T x;
+  std::memcpy(&x, p, sizeof(T));
+  return PyFloat_FromDouble(static_cast<double>(x));
+}
+
+static PyObject* bool_to_py(const char* p) {
+  return Py_NewRef(*p ? Py_True : Py_False);
+}
+
+// The converter of a buffer's struct-module format (numpy's dtype char,
+// after an optional native byte-order prefix) at its itemsize, or nullptr
+// for every dtype this builder does not make rows of.
+static ToPy converter_of(const char* format, Py_ssize_t itemsize) {
+  if (format == nullptr) format = "B";
+  const bool little = PY_LITTLE_ENDIAN != 0;
+  if (*format == '@' || *format == '=' || (*format == '<' && little) ||
+      (*format == '>' && !little)) {
+    ++format;
+  }
+  if (format[0] == '\0' || format[1] != '\0') return nullptr;
+  switch (format[0]) {
+    case 'b': case 'h': case 'i': case 'l': case 'q':
+      switch (itemsize) {
+        case 1: return signed_to_py<int8_t>;
+        case 2: return signed_to_py<int16_t>;
+        case 4: return signed_to_py<int32_t>;
+        case 8: return signed_to_py<int64_t>;
+      }
+      return nullptr;
+    case 'B': case 'H': case 'I': case 'L': case 'Q':
+      switch (itemsize) {
+        case 1: return unsigned_to_py<uint8_t>;
+        case 2: return unsigned_to_py<uint16_t>;
+        case 4: return unsigned_to_py<uint32_t>;
+        case 8: return unsigned_to_py<uint64_t>;
+      }
+      return nullptr;
+    case 'f':
+      return itemsize == 4 ? float_to_py<float> : nullptr;
+    case 'd':
+      return itemsize == 8 ? float_to_py<double> : nullptr;
+    case '?':
+      return itemsize == 1 ? bool_to_py : nullptr;
+  }
+  return nullptr;
+}
+
+struct Column {
+  Py_buffer view;
+  ToPy to_py;
+  const char* data;
+  Py_ssize_t itemsize;
+};
+
+static void release_columns(std::vector<Column>* cols) {
+  for (Column& c : *cols) PyBuffer_Release(&c.view);
+}
+
+// A tuple of `count` elements of row `r`, or nullptr with the error set.
+// It holds ints, floats, bools or such tuples, so it cannot be in a cycle:
+// it leaves the collector's lists now, as the collector's first pass over
+// it would (_PyTuple_MaybeUntrack), and no pass ever walks it.
+static PyObject* row_tuple(const Column* cols, Py_ssize_t count,
+                           Py_ssize_t r) {
+  PyObject* tup = PyTuple_New(count);
+  if (tup == nullptr) return nullptr;
+  for (Py_ssize_t c = 0; c < count; ++c) {
+    PyObject* item = cols[c].to_py(cols[c].data + r * cols[c].itemsize);
+    if (item == nullptr) {
+      Py_DECREF(tup);
+      return nullptr;
+    }
+    PyTuple_SET_ITEM(tup, c, item);
+  }
+  PyObject_GC_UnTrack(tup);
+  return tup;
+}
+
+// rows_from_columns(columns, nest) -> list
+// columns: 1-D C-contiguous numpy arrays of one length, of a bool, integer,
+// float32 or float64 dtype in native byte order (TypeError for any other:
+// the caller keeps its tolist() path for those). Row r is the tuple of the
+// columns' elements, or with nest (two columns or more) (c0[r], (c1[r],
+// ...)), the dense join's (k, (lv, rv)); each element the object
+// ndarray.tolist() makes of it. Holds the GIL throughout: it makes Python
+// objects.
+static PyObject* rows_from_columns(PyObject*, PyObject* args) {
+  PyObject* columns;
+  int nest;
+  if (!PyArg_ParseTuple(args, "Op", &columns, &nest)) return nullptr;
+  PyObject* seq = PySequence_Fast(columns, "expected a sequence of arrays");
+  if (seq == nullptr) return nullptr;
+  const Py_ssize_t n_cols = PySequence_Fast_GET_SIZE(seq);
+  if (nest && n_cols < 2) {
+    Py_DECREF(seq);
+    PyErr_SetString(PyExc_ValueError, "nest needs two columns or more");
+    return nullptr;
+  }
+  std::vector<Column> cols;
+  cols.reserve(static_cast<size_t>(n_cols));
+  Py_ssize_t n = 0;
+  for (Py_ssize_t c = 0; c < n_cols; ++c) {
+    cols.emplace_back();
+    Column& last = cols.back();
+    if (PyObject_GetBuffer(PySequence_Fast_GET_ITEM(seq, c), &last.view,
+                           PyBUF_STRIDES | PyBUF_FORMAT) < 0) {
+      // numpy exports no buffer of a datetime column, for one: a dtype
+      // this builder does not take
+      cols.pop_back();
+      PyErr_Clear();
+      release_columns(&cols);
+      Py_DECREF(seq);
+      PyErr_SetString(PyExc_TypeError, "column exports no buffer");
+      return nullptr;
+    }
+    last.to_py = converter_of(last.view.format, last.view.itemsize);
+    last.data = static_cast<const char*>(last.view.buf);
+    last.itemsize = last.view.itemsize;
+    const bool flat = last.view.ndim == 1 &&
+        (last.view.strides == nullptr ||
+         last.view.strides[0] == last.view.itemsize);
+    if (last.to_py == nullptr || !flat) {
+      PyErr_Format(PyExc_TypeError,
+                   "column %zd: no rows of format '%s'%s", c,
+                   last.view.format ? last.view.format : "B",
+                   flat ? "" : ", not a contiguous 1-D column");
+      release_columns(&cols);
+      Py_DECREF(seq);
+      return nullptr;
+    }
+    const Py_ssize_t len = last.view.shape[0];
+    if (c > 0 && len != n) {
+      release_columns(&cols);
+      Py_DECREF(seq);
+      PyErr_SetString(PyExc_ValueError, "columns differ in length");
+      return nullptr;
+    }
+    n = len;
+  }
+  Py_DECREF(seq);
+
+  PyObject* out = PyList_New(n);
+  if (out == nullptr) {
+    release_columns(&cols);
+    return nullptr;
+  }
+  const Column* cs = cols.data();
+  for (Py_ssize_t r = 0; r < n; ++r) {
+    PyObject* row;
+    if (nest) {
+      PyObject* head = cs[0].to_py(cs[0].data + r * cs[0].itemsize);
+      PyObject* tail = head == nullptr ? nullptr
+                                       : row_tuple(cs + 1, n_cols - 1, r);
+      row = tail == nullptr ? nullptr : PyTuple_New(2);
+      if (row == nullptr) {
+        Py_XDECREF(head);
+        Py_XDECREF(tail);
+      } else {
+        PyTuple_SET_ITEM(row, 0, head);
+        PyTuple_SET_ITEM(row, 1, tail);
+        PyObject_GC_UnTrack(row);
+      }
+    } else {
+      row = row_tuple(cs, n_cols, r);
+    }
+    if (row == nullptr) {
+      Py_DECREF(out);  // its unfilled slots are NULL, which it skips
+      release_columns(&cols);
+      return nullptr;
+    }
+    PyList_SET_ITEM(out, r, row);
+  }
+  release_columns(&cols);
+  return out;
+}
+
 static PyMethodDef kMethods[] = {
     {"bucket_reduce_pairs", bucket_reduce_pairs, METH_VARARGS,
      "One-pass hash-bucket + combine over (int, number) pairs."},
@@ -552,6 +753,8 @@ static PyMethodDef kMethods[] = {
      "Encode (int, number) pairs to packed rows."},
     {"hash_i64", hash_i64, METH_VARARGS,
      "splitmix64 % n_buckets over an int64 buffer."},
+    {"rows_from_columns", rows_from_columns, METH_VARARGS,
+     "Row tuples (flat, or (c0, (c1, ...)) with nest) from numpy columns."},
     {nullptr, nullptr, 0, nullptr},
 };
 

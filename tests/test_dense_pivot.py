@@ -149,9 +149,51 @@ def test_pivot_rows_equal_the_per_row_expression(dctx, session, shape):
     assert spans.nested() == 0
 
 
+# the shapes whose collect() makes tuples of columns (_pivot_rows)
+TUPLE_SHAPES = ["join", "join_int64_wide_values", "pairs", "wide_rows"]
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_pivot_rows_without_the_native_module(dctx, session, monkeypatch,
+                                              shape):
+    """With no compiled module every pivot takes tolist() and zip: the same
+    rows and types, counted under pivot_python_rows."""
+    from vega_tpu import native
+    from vega_tpu.tpu import spans
+
+    monkeypatch.setattr(native, "get", lambda: None)
+    spans.new_session()
+    with session:
+        got, expected = SHAPES[shape](dctx)
+    assert got == expected
+    assert _types(got) == _types(expected)
+    tally = spans.session()
+    assert "pivot_native_rows" not in tally
+    if shape in TUPLE_SHAPES:
+        assert tally["pivot_python_rows"]["count"] == len(got)
+    else:
+        assert "pivot_python_rows" not in tally
+
+
+@pytest.mark.parametrize("shape", TUPLE_SHAPES)
+def test_pivot_rows_are_native_where_the_module_loads(dctx, session, shape):
+    from vega_tpu import native
+    from vega_tpu.tpu import spans
+
+    if native.get() is None:
+        pytest.skip("native build unavailable")
+    spans.new_session()
+    with session:
+        got, _expected = SHAPES[shape](dctx)
+    tally = spans.session()
+    assert tally["pivot_native_rows"]["count"] == len(got) > 0
+    assert "pivot_python_rows" not in tally
+    assert not any(gc.is_tracked(row) for row in got)
+
+
 def test_join_rows_are_c_level_iterators():
-    """No generator frame a row: `_rows` hands `collect` and `compute` a
-    zip of a zip."""
+    """No generator frame a row: `_rows` hands `compute` (and `collect`
+    where the native builder is absent) a zip of a zip."""
     from vega_tpu.tpu.dense_rdd import _JoinRDD
 
     cols = {"k": np.array([3, 4], np.int64),

@@ -121,6 +121,109 @@ def test_ops():
         assert dict(nat.merge_encoded([(b, 0) for b in blobs], op)) == {1: expected}
 
 
+ROW_DTYPES = ["int8", "int16", "int32", "int64", "uint8", "uint16", "uint32",
+              "uint64", "float32", "float64", "bool"]
+
+
+def _column(dtype: str, seed: int, n: int = 61) -> np.ndarray:
+    """n elements of `dtype`: its extremes and, for floats, NaN, both
+    infinities, both zeros and a subnormal, then seeded draws."""
+    rng = np.random.default_rng(seed)
+    dt = np.dtype(dtype)
+    if dt == np.bool_:
+        return rng.integers(0, 2, n).astype(bool)
+    if dt.kind in "iu":
+        info = np.iinfo(dt)
+        edges = [info.min, info.max, 0, 1, info.max - 1]
+        if dt.kind == "u":
+            edges += [info.max // 2 + 1]  # 2**63 for uint64: no int64 holds it
+        draws = rng.integers(info.min, info.max, n - len(edges),
+                             dtype=dt, endpoint=True)
+        col = np.concatenate([np.array(edges, dt), draws])
+    else:
+        info = np.finfo(dt)
+        edges = [np.nan, np.inf, -np.inf, -0.0, 0.0, info.smallest_subnormal,
+                 -info.smallest_subnormal, info.max, info.min, info.tiny]
+        draws = (rng.standard_normal(n - len(edges)) * 1e3).astype(dt)
+        col = np.concatenate([np.array(edges, dt), draws])
+    return rng.permutation(col)
+
+
+def _same(got, want) -> bool:
+    """Equal in structure, Python type and value, NaN where NaN is, and
+    each zero's sign."""
+    if type(got) is not type(want):
+        return False
+    if isinstance(want, tuple):
+        return len(got) == len(want) and all(map(_same, got, want))
+    if isinstance(want, float):
+        if want != want:
+            return got != got
+        return got == want and np.copysign(1, got) == np.copysign(1, want)
+    return got == want
+
+
+def _tuples(row):
+    yield row
+    for e in row:
+        if isinstance(e, tuple):
+            yield from _tuples(e)
+
+
+ROW_SHAPES = ([(None, 0, False)]
+              + [(dt, n_cols, nest) for dt in ROW_DTYPES
+                 for n_cols, nest in ((1, False), (3, False), (3, True))])
+
+
+@pytest.mark.parametrize("dtype,n_cols,nest", ROW_SHAPES)
+def test_rows_from_columns_equal_tolist_rows(dtype, n_cols, nest):
+    """The collect() pivot's rows: what zip over tolist() lists makes, in
+    value and in Python type, and not one tuple left to the collector."""
+    import gc
+
+    cols = [_column(dtype, seed) for seed in range(n_cols)]
+    lists = [c.tolist() for c in cols]
+    want = (list(zip(lists[0], zip(*lists[1:]))) if nest
+            else list(zip(*lists)))
+    got = nat.rows_from_columns(cols, nest)
+    assert type(got) is list and gc.is_tracked(got)
+    assert len(got) == len(want) == (len(cols[0]) if cols else 0)
+    assert all(map(_same, got, want))
+    assert not any(gc.is_tracked(t) for row in got for t in _tuples(row))
+    assert all(map(_same, native.rows_from_columns(cols, nest), want))
+
+
+def _refused():
+    base = np.arange(8, dtype=np.int64)
+    return {
+        "object": np.array([1, "a"] * 4, dtype=object),
+        "unicode": np.array(["k%d" % i for i in range(8)]),
+        "float16": base.astype(np.float16),
+        "not_contiguous": np.arange(16, dtype=np.int64)[::2],
+        "datetime": base.astype("datetime64[D]"),
+        "big_endian": base.astype(">i8"),
+        "two_dimensional": base.reshape(4, 2),
+    }
+
+
+@pytest.mark.parametrize("kind", sorted(_refused()))
+def test_rows_from_columns_refuses_other_dtypes(kind):
+    """A column this builder makes no rows of is a TypeError, which the
+    wrapper turns into None: the caller keeps its tolist() rows."""
+    cols = [np.arange(8, dtype=np.int32), _refused()[kind]]
+    for nest in (False, True):
+        with pytest.raises(TypeError):
+            nat.rows_from_columns(cols, nest)
+        assert native.rows_from_columns(cols, nest) is None
+
+
+def test_rows_from_columns_refuses_ragged_and_short_nests():
+    with pytest.raises(ValueError):
+        nat.rows_from_columns([np.arange(3), np.arange(4)], False)
+    with pytest.raises(ValueError):
+        nat.rows_from_columns([np.arange(3)], True)
+
+
 def test_host_shuffle_native_path_equivalence(ctx):
     """reduce_by_key through the native fast path matches combine_by_key
     through the Python path, including key placement for downstream

@@ -47,6 +47,23 @@ def decode(blob: bytes, is_int: bool):
     return decode_pairs_py(blob, is_int)
 
 
+def rows_from_columns(columns, nest: bool = False):
+    """The row tuples of 1-D numpy columns, built by the compiled module:
+    `list(zip(*[c.tolist() for c in columns]))`, or with `nest`
+    `(c0, (c1, ...))` rows, equal in value and Python type, with no list
+    a column in between and every tuple already untracked by the cyclic
+    collector. None when the module is absent or a column's dtype is not
+    a bool, integer, float32 or float64 one (strings, objects, dates): the
+    caller then makes the rows itself."""
+    nat = get()
+    if nat is None:
+        return None
+    try:
+        return nat.rows_from_columns(columns, nest)
+    except TypeError:
+        return None
+
+
 def merge_encoded_py(flagged_blobs, op_name: str):
     """Pure-Python equivalent of _vega_native.merge_encoded."""
     op = _PY_OPS[op_name]

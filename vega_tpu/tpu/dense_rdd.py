@@ -1501,8 +1501,8 @@ class DenseRDD(RDD):
             if names == [VALUE]:
                 return cols[VALUE].tolist()
             if set(names) == {KEY, VALUE}:
-                return list(zip(cols[KEY].tolist(), cols[VALUE].tolist()))
-            return list(zip(*[cols[n].tolist() for n in names]))
+                names = [KEY, VALUE]
+            return _pivot_rows([cols[n] for n in names], nest=False)
 
     def collect_arrays(self) -> dict:
         """Columnar collect — no per-row Python objects."""
@@ -4499,7 +4499,7 @@ class _JoinRDD(_ExchangeRDD):
     def collect(self) -> list:
         cols = self.block().to_numpy()
         with spans.span("pivot"):
-            return list(self._rows(cols))
+            return _pivot_rows([cols[KEY], cols["lv"], cols["rv"]], nest=True)
 
     def count(self) -> int:
         return self.block().num_rows
@@ -4999,3 +4999,22 @@ def _infer_named_op(func) -> Optional[str]:
     from vega_tpu.rdd.pair import _infer_named_op as _host_infer
 
     return _host_infer(func)
+
+
+def _pivot_rows(columns: list, nest: bool) -> list:
+    """collect()'s rows of decoded columns, flat or `(c0, (c1, ...))`: the
+    native builder's (one pass, no list a column, every tuple already
+    untracked, so the collection their allocations schedule walks none of
+    them), else a C-level zip over tolist() lists, which string, object
+    and date columns take. `pivot_native_rows` / `pivot_python_rows` count
+    which made them."""
+    from vega_tpu import native
+
+    rows = native.rows_from_columns(columns, nest)
+    if rows is None:
+        lists = [c.tolist() for c in columns]
+        rows = list(zip(lists[0], zip(*lists[1:])) if nest else zip(*lists))
+        spans.count("pivot_python_rows", len(rows))
+    else:
+        spans.count("pivot_native_rows", len(rows))
+    return rows

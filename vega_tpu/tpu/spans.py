@@ -56,7 +56,10 @@ frame compile that the device lowering refused and the host tier took:
 `planner.fallback_count()`'s count, in the tally); `dict_remap_rows` and
 `dict_remap_table_entries` (a `_DictUnifyRDD` launch's valid rows times the
 columns it remapped, read with its overflow flag, and the entries of the
-tables it staged).
+tables it staged); `pivot_native_rows` and `pivot_python_rows` (the rows a
+`collect()` pivot of tuples made with the native builder, and those it made
+with tolist() and zip because the module was absent or a column's dtype is
+not a number's: `dense_rdd._pivot_rows`).
 
 Programs are rare (2-3 a run), so `programs()` is always recorded:
 {kind: {"mints", "first_call_s"}}, the second the host seconds of each minted
