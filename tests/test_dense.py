@@ -895,6 +895,176 @@ def test_block_to_numpy_makes_no_whole_column_temporary(n_shards):
     assert peak <= 1.25 * returned, (peak, returned)
 
 
+def _fill_case_block(name, cap=2048):
+    """(block, reference columns) for one case of the threaded fill: host
+    columns of the valid rows, shard after shard, stored as a device block
+    a shard a device (an int64 column as its two words), a sentinel in every
+    padding row."""
+    from vega_tpu.tpu import block as block_lib
+    from vega_tpu.tpu import mesh as mesh_lib
+
+    rng = np.random.RandomState(len(name))
+    counts = {"four_shards_uneven": [700, 0, 333, 2048],
+              "dict_beside_numeric": [900, 41, 0, 1200]}.get(name, [1500])
+    n = sum(counts)
+    wide = rng.randint(_I64_MIN, _I64_MAX, size=n, dtype=np.int64)
+    wide[:4] = [_I64_MIN, -1, 2**31, _I64_MAX]  # negative, beyond int32
+    floats = rng.rand(n).astype(np.float32)
+    floats[:3] = [np.nan, -0.0, np.inf]  # a copy keeps every bit
+    words = np.array(["ash", "birch", "cedar", "fir", "oak"])
+    ref = {
+        "wide_key": {"k": wide},
+        "wide_value": {"k": rng.randint(-50, 50, n).astype(np.int32),
+                       "w": rng.randint(-2**40, 2**40, n, dtype=np.int64)},
+        "plain_columns": {"k": rng.randint(-2**31, 2**31 - 1,
+                                           n).astype(np.int32),
+                          "v": floats,
+                          "m": rng.randint(-9, 9, (n, 3)).astype(np.int32)},
+        "four_shards_uneven": {"k": wide, "v": floats,
+                               "m": rng.rand(n, 2).astype(np.float32)},
+        "dict_beside_numeric": {"k": wide,
+                                "s": words[rng.randint(0, 5, n)],
+                                "v": floats},
+    }[name]
+    stored = {}
+    for col, src in ref.items():
+        if src.dtype == np.int64:
+            stored[col], stored[col + ".lo"] = block_lib.encode_i64(src)
+        elif src.dtype.kind == "U":
+            stored[col] = np.searchsorted(words, src).astype(np.int32)
+        else:
+            stored[col] = src
+    mesh = mesh_lib.make_mesh(len(counts))
+    cols, at = {}, np.concatenate([[0], np.cumsum(counts)])
+    for col, src in stored.items():
+        dst = np.full((len(counts) * cap,) + src.shape[1:], -7, src.dtype)
+        for s, c in enumerate(counts):
+            dst[s * cap:s * cap + c] = src[at[s]:at[s + 1]]
+        cols[col] = mesh_lib.host_put(dst, mesh_lib.shard_spec(mesh))
+    counts = np.asarray(counts, np.int32)
+    blk = block_lib.Block(cols=cols, counts=counts, capacity=cap, mesh=mesh,
+                          counts_host=counts,
+                          dicts={"s": words} if "s" in ref else None)
+    return blk, ref
+
+
+def _to_numpy_on_threads(blk, monkeypatch, threshold):
+    """blk.to_numpy() with the threaded fill's threshold at `threshold`
+    bytes, and for each run of rows whether the calling thread wrote it
+    ("caller") or the name of the thread that did."""
+    import threading
+
+    from vega_tpu.tpu import block as block_lib
+
+    seen, fill, caller = [], block_lib._fill, threading.current_thread()
+
+    def spy(run):
+        t = threading.current_thread()
+        seen.append("caller" if t is caller else t.name)
+        fill(run)
+
+    with monkeypatch.context() as m:
+        m.setattr(block_lib, "_THREADED_FILL_BYTES", threshold)
+        m.setattr(block_lib, "_fill", spy)
+        return blk.to_numpy(), seen
+
+
+def _assert_same_bytes(got, want):
+    assert list(got) == list(want)
+    for name in want:
+        assert got[name].dtype == want[name].dtype, name
+        assert got[name].shape == want[name].shape, name
+        assert got[name].tobytes() == want[name].tobytes(), name
+
+
+@pytest.mark.parametrize("case", ["wide_key", "wide_value", "plain_columns",
+                                  "four_shards_uneven",
+                                  "dict_beside_numeric"])
+def test_block_to_numpy_threaded_fill_is_byte_identical(case, monkeypatch):
+    """Above the threshold to_numpy writes runs of rows on the decode pool,
+    cut inside shards (runs of about 1 KB here, decode_i64's chunks of 64
+    rows inside them): the same bytes as the inline loop and as the host
+    columns the block was built from, on the pool's threads only."""
+    from vega_tpu.tpu import block as block_lib
+
+    blk, ref = _fill_case_block(case)
+    inline, on = _to_numpy_on_threads(blk, monkeypatch, 1 << 62)
+    assert set(on) == {"caller"} and len(on) == len(ref) * sum(
+        1 for c in blk.counts_np if c)
+    monkeypatch.setattr(block_lib, "_FILL_TASK_BYTES", 1000)
+    monkeypatch.setattr(block_lib, "_DECODE_CHUNK_ROWS", 64)
+    threaded, on = _to_numpy_on_threads(blk, monkeypatch, 1)
+    assert len(on) > 2 * len(inline)
+    assert all(name.startswith("vega-decode") for name in on)
+    _assert_same_bytes(threaded, inline)
+    _assert_same_bytes(threaded, ref)
+
+
+@pytest.mark.parametrize("side", ["just_below", "at"])
+def test_block_to_numpy_threshold_decides_the_path(side, monkeypatch):
+    """A result one byte under the threshold is filled inline on the calling
+    thread; one of exactly the threshold's bytes on the pool; the bytes are
+    the same."""
+    blk, ref = _fill_case_block("four_shards_uneven")
+    nbytes = sum(a.nbytes for a in ref.values())
+    got, on = _to_numpy_on_threads(
+        blk, monkeypatch, nbytes + 1 if side == "just_below" else nbytes)
+    if side == "just_below":
+        assert set(on) == {"caller"}
+    else:
+        assert on and all(name.startswith("vega-decode") for name in on)
+    _assert_same_bytes(got, ref)
+
+
+def test_block_to_numpy_threaded_from_many_callers(monkeypatch):
+    """More callers than the pool has threads, with a short switch interval:
+    the lazily made pool is made once, and each caller's threaded fill comes
+    back whole while the others share the pool."""
+    import sys
+    import threading
+
+    from vega_tpu.tpu import block as block_lib
+
+    blocks = [_fill_case_block(case) for case in
+              ("wide_key", "four_shards_uneven", "dict_beside_numeric")]
+    made, pool_cls = [], block_lib.ThreadPoolExecutor
+
+    def counted(*args, **kwargs):
+        made.append(pool_cls(*args, **kwargs))
+        return made[-1]
+
+    monkeypatch.setattr(block_lib, "_fill_pool", None)
+    monkeypatch.setattr(block_lib, "ThreadPoolExecutor", counted)
+    monkeypatch.setattr(block_lib, "_THREADED_FILL_BYTES", 1)
+    monkeypatch.setattr(block_lib, "_FILL_TASK_BYTES", 1000)
+    wrong = []
+
+    def caller(i):
+        blk, ref = blocks[i % len(blocks)]
+        for _ in range(3):
+            try:
+                _assert_same_bytes(blk.to_numpy(), ref)
+            except AssertionError as e:
+                wrong.append((i, e))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=caller, args=(i,))
+                   for i in range(3 * block_lib._FILL_THREADS)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+        for pool in made:
+            pool.shutdown()
+    assert not any(t.is_alive() for t in threads)
+    assert wrong == []
+    assert len(made) == 1
+
+
 def test_dense_int64_key_reduce_group_parity(dctx):
     keys, vals = _i64_fixture(1)
     d = dctx.dense_from_numpy(keys, vals)

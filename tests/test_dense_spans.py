@@ -128,6 +128,32 @@ def test_collect_arrays_is_one_fetch_across_shards(dctx, on_devices, session):
     assert "launch" not in tally and spans.nested() == 0
 
 
+def test_decode_counts_the_bytes_of_each_path(dctx, session):
+    """A collect_arrays() of at least block._THREADED_FILL_BYTES adds its
+    bytes to `decode_threaded_bytes` and a small one to
+    `decode_inline_bytes`; the pool's threads open no span, so the spans stay
+    flat and each fill is one `decode` on the caller's thread."""
+    from vega_tpu.tpu import block as block_lib
+    from vega_tpu.tpu import spans
+
+    rows = block_lib._THREADED_FILL_BYTES // (8 + 4) + 1
+    keys = (np.arange(rows, dtype=np.int64) * 2654435761) % (2**40)
+    vals = np.arange(rows, dtype=np.float64) % 1009
+    large = dctx.dense_from_numpy(keys, vals)
+    small = dctx.dense_from_numpy(keys[:N], vals[:N])
+    with session:
+        big, little = large.collect_arrays(), small.collect_arrays()
+    assert np.array_equal(big["k"], keys) and np.array_equal(little["k"],
+                                                               keys[:N])
+    assert np.array_equal(big["v"], vals.astype(np.float32))
+    tally = spans.session()
+    assert tally["decode_threaded_bytes"]["count"] == rows * (8 + 4)
+    assert tally["decode_inline_bytes"]["count"] == N * (8 + 4)
+    assert tally["decode"]["count"] == 2
+    assert tally["decode"]["bytes"] == (rows + N) * (8 + 4)
+    assert spans.nested() == 0
+
+
 @pytest.mark.parametrize("name", sorted(LINEAGES))
 def test_spans_are_flat_and_change_no_result(dctx, session, name):
     from vega_tpu.tpu import spans

@@ -28,7 +28,9 @@ padded column and the copy into it, where a shard is not full; closed before its
 its own, and `encode wait` is the caller's wait for it), `decode` (each shard's
 valid rows written once into a result column allocated at its full length,
 an int64's two words joined in that pass, the dictionary decode of a fetched
-block), `pivot` (columns -> Python row objects), `fingerprint` (pickling a closure
+block; a result of `block._THREADED_FILL_BYTES` or more is written on up to 8
+worker threads, which open no span, while the caller waits inside this one),
+`pivot` (columns -> Python row objects), `fingerprint` (pickling a closure
 for a program-cache key), `frame_plan` (`planner.compile_plan`, a frame's
 logical plan to its lineage; it yields to the spans inside it, below),
 `dict_build` (a source's sorted dictionary of one string column, bytes the
@@ -59,7 +61,10 @@ columns it remapped, read with its overflow flag, and the entries of the
 tables it staged); `pivot_native_rows` and `pivot_python_rows` (the rows a
 `collect()` pivot of tuples made with the native builder, and those it made
 with tolist() and zip because the module was absent or a column's dtype is
-not a number's: `dense_rdd._pivot_rows`).
+not a number's: `dense_rdd._pivot_rows`); `decode_threaded_bytes` and
+`decode_inline_bytes` (the bytes a `Block.to_numpy` fill wrote on the decode
+pool's threads, a result at or over `block._THREADED_FILL_BYTES`, and those
+it wrote on the calling thread).
 
 Programs are rare (2-3 a run), so `programs()` is always recorded:
 {kind: {"mints", "first_call_s"}}, the second the host seconds of each minted
